@@ -1,0 +1,351 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the two normal entry points once, in ONE process, on every local TPU
+chip, at the full width of the paper's configuration (VGG-11, global batch
+256, f32; random weights from a seed, 45 batches instead of an epoch):
+
+  1. train:  ``cs744_ddp_tpu.cli.main`` with ``--strategy ddp --lr 0.01`` —
+     the default windowed path (two 20-iteration windows + a ragged 5,
+     metrics ring and donation on), then eval;
+  2. serve:  ``cs744_ddp_tpu.cli.main`` with ``--serve-frontend`` — one
+     replica per chip behind the router and the real socket, 60 requests at
+     30 rps over the default bucket ladder;
+  3. placement: the same replica set rebuilt from the executable cache the
+     serve phase wrote (a server restart), one request sent straight to each
+     replica and compared with an unpadded direct forward; per-device peak
+     memory, batch shards and parameter residency after training.
+
+It checks results from the telemetry files and the returned objects, never
+by scraping prints.  Any failed check or uncaught exception ends the run
+with a non-zero exit code and no result line.  Without a TPU (this sandbox:
+``JAX_PLATFORMS=cpu``) it exits 4 before any phase: there is no CPU
+fallback.  A chip belongs to one process, so nothing here starts a child
+that needs it.  Timings in the result are observations from a smoke run,
+NOT a benchmark.
+
+Run from the checkout root:  python3 chip_smoke.py
+The last stdout line is one JSON object with exactly these keys (the driver
+parses it strictly):
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+The per-phase record (losses, replies, placement, cache traffic, observed
+timings) is the ``chip_smoke: result {...}`` line before it and
+``chiprun_out/chip_smoke/result.json``.
+"""
+
+import importlib.metadata
+import json
+import math
+import os
+import shutil
+import sys
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+OUT = os.path.join(ROOT, "chiprun_out", "chip_smoke")
+
+MODEL = "vgg11"
+GLOBAL_BATCH = 256
+# bench.py's ``stable_lr`` control, not the reference's 0.1: on the chip, 45
+# steps at 0.1 spike the first window (mean loss 10.8) and then sit at chance
+# (2.305 = ln 10, eval 10.6%), so "last window below first" passes for the
+# wrong reason; at 0.01 the loss falls 2.64 -> 1.69 and eval reaches 36%
+# (PR 21 chip runs, one v5e).
+LR = 0.01
+TRAIN_BATCHES = 45          # 20 + 20 + ragged 5 at the 20-iteration window
+EVAL_BATCHES = 5
+SERVE_REQUESTS = 60
+SERVE_RPS = 30
+NO_TPU_EXIT = 4             # clear of the chip tool's own 2 / 3
+
+
+def check(cond, message: str) -> None:
+    """A failed check ends the run: exit code 1, message on stderr."""
+    if not cond:
+        raise SystemExit(f"chip_smoke: FAILED: {message}")
+
+
+def _read_json(path: str):
+    with open(path) as f:
+        return json.load(f)
+
+
+def verdict_line(device: dict) -> str:
+    """The last stdout line: ``ok`` and the device as JAX reported it,
+    nothing else — details go on the ``chip_smoke: result`` line."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
+
+
+def _version(dist: str):
+    try:
+        return importlib.metadata.version(dist)
+    except importlib.metadata.PackageNotFoundError:
+        return None
+
+
+def train_phase(cli, devices, *, model=MODEL,
+                global_batch=GLOBAL_BATCH) -> tuple:
+    """``cli.main`` training run; returns (result record, Trainer)."""
+    from cs744_ddp_tpu.utils.metrics import WINDOW
+
+    out = os.path.join(OUT, "train")
+    trainer = cli.main(["--strategy", "ddp", "--model", model,
+                        "--batch-size", str(global_batch), "--lr", str(LR),
+                        "--limit-train-batches", str(TRAIN_BATCHES),
+                        "--limit-eval-batches", str(EVAL_BATCHES),
+                        "--telemetry-out", out])
+
+    manifest = _read_json(os.path.join(out, "manifest.json"))
+    summary = _read_json(os.path.join(out, "summary.json"))
+    with open(os.path.join(out, "events.jsonl")) as f:
+        events = [json.loads(line) for line in f if line.strip()]
+    losses = [e["loss"] for e in events if e.get("kind") == "step"]
+
+    check(summary["num_steps"] == TRAIN_BATCHES == len(losses),
+          f"{TRAIN_BATCHES} train steps expected, summary has "
+          f"{summary['num_steps']}, events {len(losses)}")
+    check(all(math.isfinite(l) for l in losses),
+          f"non-finite train loss in {losses}")
+    first = sum(losses[:WINDOW]) / WINDOW
+    last_n = TRAIN_BATCHES % WINDOW or WINDOW
+    last = sum(losses[-last_n:]) / last_n
+    check(last < first,
+          f"mean loss of the last window {last:.4f} is not below the "
+          f"first window's {first:.4f}")
+    ev = summary["gauges"].get("eval")
+    check(ev is not None and 0.0 <= ev["accuracy"] <= 1.0
+          and math.isfinite(ev["avg_loss"])
+          and ev["total"] == EVAL_BATCHES * global_batch,
+          f"eval gauge missing or out of range: {ev}")
+    check(manifest["backend"] == devices[0].platform,
+          f"manifest backend {manifest['backend']!r} != device platform")
+    check(manifest["world_size"] == len(devices),
+          f"manifest world_size {manifest['world_size']} != "
+          f"{len(devices)} local devices")
+    check(manifest["compilation_cache"]["enabled"],
+          "compilation cache not enabled in the train manifest")
+    steady = summary.get("steady_step_time_s", {})
+    return {
+        "model": model, "strategy": "ddp", "global_batch": global_batch,
+        "lr": manifest["lr"], "steps": len(losses),
+        "first_window_mean_loss": round(first, 4),
+        "last_window_mean_loss": round(last, 4),
+        "eval_accuracy": round(ev["accuracy"], 4),
+        "eval_avg_loss": round(ev["avg_loss"], 4),
+        "observed_steady_ms_per_iter_p50_not_a_benchmark": (
+            round(steady["p50"] * 1e3, 3) if steady else None),
+        "host_round_trips": summary["counters"].get("host_round_trips"),
+    }, trainer
+
+
+def placement_after_train(trainer, devices) -> dict:
+    """"N chips" must not be "the first chip N times": every device did
+    work, holds one shard of a staged batch, and holds the parameters."""
+    import jax
+
+    want = set(devices)
+    peaks = {}
+    for d in devices:
+        stats = d.memory_stats()
+        check(stats and stats.get("peak_bytes_in_use", 0) > 0,
+              f"device {d.id} reports no peak_bytes_in_use after training: "
+              f"{stats}")
+        peaks[str(d.id)] = int(stats["peak_bytes_in_use"])
+    epoch_images = trainer._stage_train_epoch(0)[0]   # cached staging
+    shard_devices = [s.device for s in epoch_images.addressable_shards]
+    check(len(shard_devices) == len(devices) and set(shard_devices) == want,
+          f"staged batch shards live on {shard_devices}, want one per "
+          f"device of {devices}")
+    check(all(s.data.shape[1] * len(devices) == epoch_images.shape[1]
+              for s in epoch_images.addressable_shards),
+          "staged batch is not split evenly over the data axis")
+    leaf = jax.tree.leaves(trainer.state.params)[0]
+    check(leaf.sharding.device_set == want
+          and len(leaf.addressable_shards) == len(devices)
+          and all(s.data.shape == leaf.shape
+                  for s in leaf.addressable_shards),
+          f"params leaf is not replicated on every device: {leaf.sharding}")
+    return {"peak_bytes_in_use": peaks,
+            "staged_batch_shards": len(shard_devices),
+            "params_replicas": len(leaf.addressable_shards)}
+
+
+def _serve_argv(n_replicas: int, model: str) -> list:
+    return ["--serve-frontend", "--model", model,
+            "--serve-replicas", str(n_replicas),
+            "--serve-requests", str(SERVE_REQUESTS),
+            "--serve-load", str(SERVE_RPS),
+            "--serve-cache-dir", os.path.join(OUT, "exec_cache"),
+            "--telemetry-out", os.path.join(OUT, "serve")]
+
+
+def serve_phase(cli, devices, *, model=MODEL) -> dict:
+    """``cli.main --serve-frontend`` over the real socket, default ladder,
+    pipeline and shed settings; one replica per local chip."""
+    n = len(devices)
+    out = cli.main(_serve_argv(n, model))
+    load = out["load"][f"{SERVE_RPS:g}rps"]
+    check(load["replies"] == load["n_requests"] == SERVE_REQUESTS,
+          f"replies {load['replies']} != n_requests {load['n_requests']}")
+    check(load["unresolved"] == 0, f"{load['unresolved']} unresolved")
+    n_ok = sum(t["ok"] for t in load["by_tier"].values())
+    # 30 rps is far below one chip's capacity inside the 75/200/600 ms
+    # tiers: an all-shed run means something is wrong, not a tight SLO.
+    check(n_ok >= 1, f"no request answered ok: {load['by_tier']}")
+    startup = [out["startup"][f"replica{i}"] for i in range(n)]
+    ids = [st["device_id"] for st in startup]
+    check(ids == [d.id for d in devices],
+          f"replica engines on devices {ids}, want {[d.id for d in devices]}")
+    # One cache entry serves every replica: replica 0 compiled the ladder,
+    # the others loaded ITS entries onto their own devices.
+    check(not startup[0]["warm"] and all(st["warm"] for st in startup[1:]),
+          f"replicas 1.. did not load replica 0's cache entries: "
+          f"{[st['per_bucket'] for st in startup]}")
+    check(out["router"]["failovers"] == 0,
+          f"a replica died mid-run: {out['router']}")
+    manifest = _read_json(os.path.join(OUT, "serve", "manifest.json"))
+    check(manifest["compilation_cache"]["enabled"],
+          "compilation cache not enabled in the serve manifest")
+    return {
+        "model": model, "replicas": n, "n_requests": load["n_requests"],
+        "replies": load["replies"], "unresolved": load["unresolved"],
+        "ok": n_ok, "shed": load["shed"], "overload": load["overload"],
+        "attainment": load["attainment"],
+        "routed": out["router"]["routed"],
+        "observed_goodput_rps_not_a_benchmark": load["goodput_rps"],
+        "observed_queue_wait_ms_not_a_benchmark": load.get("queue_wait_ms"),
+        "driver_lag_ms_max": load["driver_lag_ms_max"],
+        "startup_s": {k: v["startup_s"] for k, v in out["startup"].items()},
+    }
+
+
+def serve_placement(cli, devices, *, model=MODEL) -> dict:
+    """A server restart on the cache the serve phase wrote: the SAME replica
+    set (``cli.build_replicas``) must load every rung warm, each replica
+    must answer a request sent straight to it, on its own device, with the
+    logits of an unpadded direct forward.  The router breaks ties by replica
+    index, so at smoke load it may rightly send everything to replica 0 —
+    the check here is on placement, not on routing.  The last replica's
+    engine is the warm-loaded executable on the last local device."""
+    import jax
+    import numpy as np
+
+    from cs744_ddp_tpu.data import augment as aug
+    from cs744_ddp_tpu.ft import NULL_CHAOS
+    from cs744_ddp_tpu.models import get_model
+    from cs744_ddp_tpu.obs import NULL
+    from cs744_ddp_tpu.serve import demo
+    from cs744_ddp_tpu.serve.scheduler import make_request
+    from cs744_ddp_tpu.train.step import init_train_state
+
+    args = cli.build_parser().parse_args(_serve_argv(len(devices), model))
+    images = demo.request_pool().images[:5]       # ragged fill of bucket 8
+
+    init_fn, apply_fn = get_model(model)
+    ref_state = init_train_state(init_fn, jax.random.PRNGKey(args.serve_seed))
+    want = np.asarray(jax.jit(
+        lambda p, s, x: apply_fn(p, s, aug.normalize(x), train=False)[0])(
+            ref_state.params, ref_state.bn_state, images))
+    check(want.shape == (5, 10) and np.all(np.isfinite(want)),
+          f"reference forward is not finite [5, 10]: {want.shape}")
+
+    def agrees(got, who: str) -> float:
+        got = np.asarray(got)
+        check(got.shape == want.shape and got.dtype == np.float32
+              and np.all(np.isfinite(got)),
+              f"{who}: logits not finite f32 {want.shape}")
+        diff = float(np.max(np.abs(got - want)))
+        check(np.allclose(got, want, rtol=1e-3, atol=1e-3),
+              f"{who}: logits differ from the direct forward, "
+              f"max|diff|={diff:.3e}")
+        return diff
+
+    replicas = cli.build_replicas(args, NULL, NULL_CHAOS)
+    diffs = {}
+    for r, d in zip(replicas, devices):
+        st = r.startup()
+        check(st["warm"] is True,
+              f"replica {r.index}: restart did not load its ladder from "
+              f"the executable cache: {st['per_bucket']}")
+        leaf = jax.tree.leaves(r.engine.params)[0]
+        check(r.engine.device == d and leaf.devices() == {d},
+              f"replica {r.index}: engine weights on {leaf.devices()}, "
+              f"want {d}")
+        with r:
+            rep = r.enqueue(make_request(
+                images, max_batch=r.engine.max_batch)).result(timeout=300)
+        check(rep.status == "ok" and rep.replica == r.index,
+              f"replica {r.index}: direct request came back "
+              f"{rep.status!r} from replica {rep.replica} ({rep.reason})")
+        diffs[str(r.index)] = agrees(rep.logits, f"replica {r.index}")
+    last = replicas[-1].engine
+    ex = last._executable(8, args.serve_precision)
+    dev_logits = ex(last.params, last.bn_state,
+                    np.zeros((8, 32, 32, 3), np.uint8),
+                    np.full((8,), -1, np.int32))[0]
+    check(dev_logits.devices() == {devices[-1]},
+          f"warm-loaded executable ran on {dev_logits.devices()}, "
+          f"want {devices[-1]}")
+    warm_diff = agrees(last.infer(images), "warm engine on the last device")
+    return {"replicas_warm_from_cache": len(replicas),
+            "direct_request_max_abs_diff_vs_forward": diffs,
+            "warm_last_device": {"device_id": devices[-1].id,
+                                 "max_abs_diff_vs_forward": warm_diff}}
+
+
+def main() -> int:
+    import jax
+
+    from cs744_ddp_tpu import cli
+    from cs744_ddp_tpu.data import native
+    from cs744_ddp_tpu.utils import compcache
+
+    compcache.enable_persistent_compilation_cache()
+    devices = jax.local_devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(jax.devices())}
+    versions = {"jax": jax.__version__, "jaxlib": _version("jaxlib"),
+                "libtpu": _version("libtpu"),
+                "python": sys.version.split()[0]}
+    print(f"chip_smoke: device {json.dumps(device)} versions "
+          f"{json.dumps(versions)} compile_cache_dir="
+          f"{compcache.cache_stats()['dir']}", flush=True)
+    if device["platform"] != "tpu":
+        print(f"chip_smoke: no TPU (platform {device['platform']!r}); this "
+              "check has no CPU fallback", file=sys.stderr)
+        return NO_TPU_EXIT
+
+    shutil.rmtree(OUT, ignore_errors=True)
+    os.makedirs(OUT)
+    train, trainer = train_phase(cli, devices)
+    placement = placement_after_train(trainer, devices)
+    after_train = compcache.cache_stats()
+    del trainer
+    serve = serve_phase(cli, devices)
+    placement["serve"] = serve_placement(cli, devices)
+    # Serialized VGG-11 ladders are tens of MiB per replica and only matter
+    # inside this run; the telemetry directories stay for inspection.
+    shutil.rmtree(os.path.join(OUT, "exec_cache"))
+    cache = compcache.cache_stats()
+    result = {
+        "ok": True, "device": device, "versions": versions,
+        "compile_cache": {
+            "dir": cache["dir"], "hits": cache["hits"],
+            "misses": cache["misses"],
+            "train_phase": {k: after_train[k] for k in ("hits", "misses")}},
+        "native_loader": {"available": native.available(),
+                          "error": native.load_error()},
+        "train": train, "serve": serve, "placement": placement,
+        "note": "timings are observations from one smoke run, "
+                "not a benchmark",
+    }
+    with open(os.path.join(OUT, "result.json"), "w") as f:
+        json.dump(result, f, indent=1)
+    print(f"chip_smoke: result {json.dumps(result)}")
+    print(verdict_line(device), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -25,7 +25,7 @@ so a :class:`CostReport` over such a program is per-device; multiply by
 the mesh size for machine totals.
 
 This module is the single source of truth for the repo's analytic
-FLOP/MFU arithmetic: ``bench._mfu_fields``, ``utils/metrics.mfu_fields``,
+FLOP/MFU arithmetic: ``bench._mfu_fields``, ``obs/attribution.py``,
 ``tools/perf_attribution.py`` and ``tools/perf_stage_roofline.py`` all
 delegate here (ISSUE 8 consolidation).
 """
@@ -47,6 +47,14 @@ V5E_BF16_PEAK_FLOPS = 197e12     # bf16 peak, per chip
 V5E_HBM_BYTES_PER_S = 819e9     # HBM bandwidth, per chip
 V5E_ICI_BYTES_PER_S = 200e9     # 1600 Gbit/s ICI, per chip per direction
 V5E_HBM_CAPACITY_BYTES = 16 * 2**30   # HBM capacity, per chip
+
+# MEASURED paths divide a rate only by the peak of the device that produced
+# it: the table is keyed by jax's ``device_kind`` string, and a device that
+# is not in it gets no utilization field (a CPU-mesh run once filed
+# ``mfu_vs_bf16_peak: 0.0`` against the v5e peak).  The static certificates
+# (memlife, megaplan, the audit budget) name their v5e target outright and
+# keep using the constants above.
+PEAK_BF16_FLOPS_BY_DEVICE_KIND = {"TPU v5 lite": V5E_BF16_PEAK_FLOPS}
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _INT_DTYPES = ("pred", "s8", "u8", "s16", "u16", "s32", "u32", "s64", "u64")
@@ -73,17 +81,20 @@ _FREE_OPS = frozenset(("parameter", "constant", "tuple",
 
 
 def mfu_fields(ips_per_chip: float, flops_per_image: Optional[float],
-               peak_flops: float = V5E_BF16_PEAK_FLOPS) -> Dict:
-    """Achieved TFLOP/s + model-flops-utilization fields for a measured
-    per-chip image rate.  Returns ``{}`` when the analytic flop count is
-    unavailable — absent keys, never null values (bench head contract)."""
+               device_kind: str) -> Dict:
+    """Achieved TFLOP/s + model-flops-utilization fields for a per-chip
+    image rate measured on a ``device_kind`` device.  Returns ``{}`` when
+    the analytic flop count is unavailable, and no ``mfu_vs_bf16_peak``
+    for a device outside the peak table — absent keys, never null values
+    (bench head contract)."""
     if not flops_per_image:
         return {}
     tflops = ips_per_chip * flops_per_image / 1e12
-    return {
-        "tflops_per_sec": round(tflops, 2),
-        "mfu_vs_bf16_peak": round(tflops * 1e12 / peak_flops, 4),
-    }
+    out = {"tflops_per_sec": round(tflops, 2)}
+    peak_flops = PEAK_BF16_FLOPS_BY_DEVICE_KIND.get(device_kind)
+    if peak_flops is not None:
+        out["mfu_vs_bf16_peak"] = round(tflops * 1e12 / peak_flops, 4)
+    return out
 
 
 def _dims(type_str: Optional[str]) -> Optional[List[int]]:

@@ -88,10 +88,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--profile-phases", action="store_true",
                    help="additionally time a forward-only program to report "
                         "the reference's fwd/bwd split. NOTE: this per-step "
-                        "mode pays per-call dispatch latency (large on "
-                        "remote/tunneled TPU backends), so phase times can "
-                        "dwarf the fused windowed step time the default "
-                        "mode reports; use --profile-dir for a real trace")
+                        "mode pays one host dispatch + fetch per call, so "
+                        "phase times can dwarf the fused windowed step time "
+                        "the default mode reports; use --profile-dir for a "
+                        "real trace")
     p.add_argument("--limit-train-batches", type=int, default=None,
                    help="cap train iterations per epoch (smoke runs/benches)")
     p.add_argument("--limit-eval-batches", type=int, default=None,
@@ -486,20 +486,38 @@ def elastic_main(args, telemetry) -> None:
     print("elastic report: " + json.dumps(report))
 
 
-def serve_frontend_main(args, telemetry) -> None:
+def build_replicas(args, telemetry, chaos) -> list:
+    """The --serve-frontend replica set: ``--serve-replicas`` engines,
+    replica i pinned to local device i (round-robin past the device
+    count), all on one executable-cache dir."""
+    import jax
+
+    from .serve import demo
+    from .serve.replica import EngineReplica
+
+    devices = jax.devices()
+    return [
+        EngineReplica(i, args.model, device=devices[i % len(devices)],
+                      buckets=demo.parse_buckets(args.serve_buckets),
+                      precision=args.serve_precision,
+                      seed=args.serve_seed, telemetry=telemetry,
+                      cache_dir=args.serve_cache_dir, chaos=chaos,
+                      shed=args.serve_shed == "on",
+                      pipeline=args.serve_pipeline == "on")
+        for i in range(max(1, args.serve_replicas))]
+
+
+def serve_frontend_main(args, telemetry) -> dict:
     """--serve-frontend: replicated serving tier end-to-end — N
     device-pinned engine replicas behind the least-loaded router and the
     socket front-end; replay the seeded tiered trace over a REAL socket
     at each offered load, print ONE JSON line (startup + per-load
-    goodput/attainment stats)."""
+    goodput/attainment stats) and return the same record."""
     import json
-
-    import jax
 
     from .ft import NULL_CHAOS
     from .serve import demo
     from .serve.frontend import FrontendClient, ServingFrontend
-    from .serve.replica import EngineReplica
     from .serve.router import ReplicaRouter
 
     ft = ft_config_from_args(args)
@@ -516,14 +534,7 @@ def serve_frontend_main(args, telemetry) -> None:
     if args.serve_trace_client is not None:
         client_tel = Telemetry(args.serve_trace_client)
         client_tel.write_manifest({"mode": "serve-frontend-client"})
-    devices = jax.devices()
-    replicas = [
-        EngineReplica(i, args.model, device=devices[i % len(devices)],
-                      buckets=buckets, precision=args.serve_precision,
-                      seed=args.serve_seed, telemetry=telemetry,
-                      cache_dir=args.serve_cache_dir, chaos=chaos,
-                      shed=shed, pipeline=pipeline)
-        for i in range(max(1, args.serve_replicas))]
+    replicas = build_replicas(args, telemetry, chaos)
     telemetry.write_manifest({
         "mode": "serve-frontend", "model": args.model,
         "buckets": list(buckets), "precision": args.serve_precision,
@@ -581,6 +592,7 @@ def serve_frontend_main(args, telemetry) -> None:
         if alerts is not None:
             telemetry.update_manifest({"alerts": alerts.summary()})
     print(json.dumps(out))
+    return out
 
 
 def serve_main(args, telemetry) -> None:
@@ -618,12 +630,16 @@ def serve_main(args, telemetry) -> None:
     print(json.dumps({"startup": startup, "demo": stats}))
 
 
-def main(argv=None) -> None:
+def main(argv=None):
+    """Parse ``argv`` and run the selected mode.  Returns what an
+    in-process caller (chip_smoke.py) needs to inspect the run beyond its
+    telemetry files: the ``Trainer`` after a training run, the output
+    record after ``--serve-frontend``; None for the other modes."""
     args = build_parser().parse_args(argv)
     # Persistent XLA compilation cache, unconditionally (previously only
     # bench/tests opted in): repeated CLI runs of the same config skip
     # multi-second XLA compiles; hit/miss counts land in the manifest.
-    compcache.enable_persistent_compilation_cache(compcache.repo_root())
+    compcache.enable_persistent_compilation_cache()
     if args.require_real_data:
         from .data import cifar10
         if not cifar10.has_real_data(args.data_dir):
@@ -654,12 +670,11 @@ def main(argv=None) -> None:
         return
     if args.serve_frontend:
         try:
-            serve_frontend_main(args, telemetry)
+            return serve_frontend_main(args, telemetry)
         finally:
             telemetry.update_manifest(
                 {"compilation_cache": compcache.cache_stats()})
             telemetry.finalize()
-        return
     if args.serve_demo:
         try:
             serve_main(args, telemetry)
@@ -726,6 +741,7 @@ def main(argv=None) -> None:
         telemetry.update_manifest(
             {"compilation_cache": compcache.cache_stats()})
         telemetry.finalize(global_batch=args.batch_size)
+    return trainer
 
 
 if __name__ == "__main__":

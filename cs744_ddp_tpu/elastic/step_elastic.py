@@ -51,8 +51,7 @@ from ..data import augment as aug
 from ..ops import sgd
 from ..ops.loss import cross_entropy
 from ..parallel.mesh import DATA_AXIS
-from ..train.step import (_SHARD_MAP_KW, TrainState, maybe_cast, pvary,
-                          shard_map)
+from ..train.step import TrainState, maybe_cast, shard_map
 
 
 def tree_combine_mean(x: jax.Array) -> jax.Array:
@@ -120,17 +119,11 @@ def make_elastic_train_window(apply_fn: Callable, mesh: Mesh,
             mb = images.shape[0] // k
             imgs_k = images.reshape((k, mb) + images.shape[1:])
             labs_k = labels.reshape((k, mb))
-            # Differentiate w.r.t. a device-varying view so the explicit
-            # combine below is the ONLY gradient reduction (see
-            # train/step.py on the invariant-cotangent auto-psum).
-            params_var = jax.tree.map(pvary, params)
-            bn_var = jax.tree.map(pvary, bn_state)
-
             losses0 = jnp.zeros((k,), jnp.float32)
             grads0 = jax.tree.map(
-                lambda a: jnp.zeros((k,) + a.shape, a.dtype), params_var)
+                lambda a: jnp.zeros((k,) + a.shape, a.dtype), params)
             bns0 = jax.tree.map(
-                lambda a: jnp.zeros((k,) + a.shape, a.dtype), bn_var)
+                lambda a: jnp.zeros((k,) + a.shape, a.dtype), bn_state)
 
             def micro(j, acc):
                 losses_k, grads_k, bns_k = acc
@@ -148,11 +141,11 @@ def make_elastic_train_window(apply_fn: Callable, mesh: Mesh,
                 x = maybe_cast(x, compute_dtype)
 
                 def loss_fn(p):
-                    logits, new_bn = apply_fn(p, bn_var, x, train=True)
+                    logits, new_bn = apply_fn(p, bn_state, x, train=True)
                     return cross_entropy(logits, mlabs), new_bn
 
                 (loss, new_bn), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params_var)
+                    loss_fn, has_aux=True)(params)
                 loss, grads, new_bn = lax.optimization_barrier(
                     (loss, grads, new_bn))
                 upd = lambda buf, v: lax.dynamic_update_index_in_dim(
@@ -186,12 +179,19 @@ def make_elastic_train_window(apply_fn: Callable, mesh: Mesh,
             one, (params, bn_state, opt_state, key), (imgs, labs, idxs))
         return p, bn, opt, losses
 
+    # check_vma=False, unlike every program in train/step.py: this
+    # window's reduction is a tiled all_gather + fixed-order tree, whose
+    # result is replicated by construction but device-varying by TYPE (the
+    # public all_gather has no invariant form), so the P() out_specs cannot
+    # pass the varying-axes check.  Without the check, in-body jax.grad of
+    # the replicated params is shard-local (no auto-psum), which is what
+    # the explicit combine needs; the bitwise world 1/2/4 test is the proof.
     mapped = shard_map(
         window_body, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(None, DATA_AXIS), P(None, DATA_AXIS),
                   P(), P(), P()),
         out_specs=(P(), P(), P(), P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
 
     @partial(jax.jit, donate_argnums=(0,))

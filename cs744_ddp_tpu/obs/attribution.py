@@ -4,8 +4,10 @@ with measured wall-clock (ISSUE 8 tentpole b).
 The cost model says what a program MUST do (flops, HBM bytes, wire
 bytes); a measured per-dispatch time says what it DID.  The join yields:
 
-- **MFU** — achieved flops/s over the bf16 peak (per chip: shard_map
-  reports are per-device, so ``flops / measured_s`` is already per-chip).
+- **MFU** — achieved flops/s over the bf16 peak of the device that was
+  measured (per chip: shard_map reports are per-device, so ``flops /
+  measured_s`` is already per-chip); absent for a device outside the
+  peak table.
 - **Roofline side** — whether the analytic compute time or the analytic
   HBM time dominates, plus the utilization ceiling that side imposes.
 - **Comm/compute ratio** — serial wire seconds per compute second, the
@@ -25,7 +27,9 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
-from ..analysis.costmodel import (CostReport, V5E_BF16_PEAK_FLOPS,
+from ..analysis.costmodel import (CostReport,
+                                  PEAK_BF16_FLOPS_BY_DEVICE_KIND,
+                                  V5E_BF16_PEAK_FLOPS,
                                   V5E_HBM_BYTES_PER_S,
                                   V5E_HBM_CAPACITY_BYTES,
                                   V5E_ICI_BYTES_PER_S, mfu_fields)
@@ -34,16 +38,17 @@ __all__ = ["attribute", "overlap_vs_ddp", "mfu_fields"]
 
 
 def attribute(report: CostReport, *, measured_s: Optional[float] = None,
-              mem_report=None,
+              device_kind: Optional[str] = None, mem_report=None,
               peak_flops: float = V5E_BF16_PEAK_FLOPS,
               hbm_bytes_per_s: float = V5E_HBM_BYTES_PER_S,
               hbm_capacity_bytes: int = V5E_HBM_CAPACITY_BYTES,
               ici_bytes_per_s: float = V5E_ICI_BYTES_PER_S) -> Dict:
-    """Attribution record for one program; ``measured_s`` (per-dispatch
-    seconds, same per-device scope as the report) adds the measured-join
-    fields, otherwise the record is purely analytic.  ``mem_report``
-    (an :class:`analysis.memlife.MemReport` for the SAME program) adds
-    the certified peak-residency fields."""
+    """Attribution record for one program: the analytic fields are about
+    the named v5e target; ``measured_s`` (per-dispatch seconds, same
+    per-device scope as the report) adds the measured-join fields, with
+    the MFU only when ``device_kind`` — the device that was timed — is in
+    the peak table.  ``mem_report`` (an :class:`analysis.memlife.MemReport`
+    for the SAME program) adds the certified peak-residency fields."""
     compute_s = report.flops / peak_flops
     hbm_s = report.hbm_bytes / hbm_bytes_per_s
     comm_s = report.wire_bytes / ici_bytes_per_s
@@ -69,7 +74,9 @@ def attribute(report: CostReport, *, measured_s: Optional[float] = None,
         achieved = report.flops / measured_s
         out["measured_s"] = round(measured_s, 6)
         out["achieved_tflops_per_sec"] = round(achieved / 1e12, 4)
-        out["mfu_vs_bf16_peak"] = round(achieved / peak_flops, 6)
+        measured_peak = PEAK_BF16_FLOPS_BY_DEVICE_KIND.get(device_kind)
+        if measured_peak is not None:
+            out["mfu_vs_bf16_peak"] = round(achieved / measured_peak, 6)
     if mem_report is not None:
         peak = int(mem_report.peak_bytes)
         out["peak_hbm_mib"] = round(peak / 2**20, 3)

@@ -260,7 +260,7 @@ def _pallas_backward(xhat, dp, gamma, beta, inv, out_dtype):
         in_specs=[xh_spec, dp_spec, ch_spec, ch_spec],
         out_specs=sums_spec,
         out_shape=jax.ShapeDtypeStruct((2, c), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(xh5, dp, gamma2, beta2)
 

@@ -45,16 +45,7 @@ def initialize_distributed(coordinator: Optional[str] = None,
     # Cross-process collectives on the CPU backend need an implementation;
     # gloo — the reference's own backend (Part 2a/main.py:148) — is the
     # fitting choice.  Inert for TPU meshes (collectives ride ICI/DCN).
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except AttributeError as e:
-        # Config renamed/absent on this JAX version: a CPU multi-process run
-        # would fail at the first collective, so say why NOW; TPU meshes
-        # don't consult it and proceed fine.
-        import warnings
-        warnings.warn(f"could not enable gloo CPU collectives ({e}); "
-                      "multi-process CPU runs will fail at the first "
-                      "collective, TPU runs are unaffected")
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     addr = coordinator if ":" in coordinator else f"{coordinator}:{port}"
     jax.distributed.initialize(coordinator_address=addr,
                                num_processes=num_processes,

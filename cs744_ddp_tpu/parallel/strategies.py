@@ -93,16 +93,6 @@ Strategy = Callable[[Any, str], Any]
 DEFAULT_COMPRESS_RANK = 4
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static mesh-axis size (``lax.axis_size`` where it exists; jax 0.4.x
-    spells it ``jax.core.axis_frame``).  Static on purpose: a ``psum(1)``
-    spelling would add a collective and distort the strategy spectrum."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    size = jax.core.axis_frame(axis_name)
-    return getattr(size, "size", size)
-
-
 def _after(x, dep):
     """Order ``x``'s consumers after ``dep`` (sequential-collective chains).
 
@@ -123,8 +113,11 @@ def local(grads: Any, axis_name: str) -> Any:
 
 
 def per_param_psum(grads: Any, axis_name: str) -> Any:
-    """One all-reduce per leaf, sequentially; sum / world (Part 2b parity)."""
-    world = _axis_size(axis_name)
+    """One all-reduce per leaf, sequentially; sum / world (Part 2b parity).
+
+    ``world`` is the STATIC axis size: a ``psum(1)`` spelling would add a
+    collective and distort the strategy spectrum."""
+    world = lax.axis_size(axis_name)
     leaves, treedef = jax.tree.flatten(grads)
     out: List[Any] = []
     prev = None
@@ -170,7 +163,7 @@ def bucketed_psum(grads: Any, axis_name: str, *,
     the wire transfer itself."""
     if plan is None:
         plan = make_plan(grads, bucket_bytes)
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     leaves = jax.tree.leaves(grads)
     out: List[Any] = [None] * len(leaves)
     prev = ()
@@ -209,7 +202,7 @@ def overlapped_ddp(grads: Any, axis_name: str, *,
     if plan is None:
         plan = make_plan(grads, bucket_bytes)
     sched = make_schedule(plan)
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     leaves = jax.tree.leaves(grads)
     out: List[Any] = [None] * len(leaves)
     for b in sched.order:
@@ -282,7 +275,7 @@ class CompressedPsum:
         return {"residual": _stack_zeros_like(params_like, world)}
 
     def __call__(self, grads: Any, axis_name: str, comm: Any = None):
-        world = _axis_size(axis_name)
+        world = lax.axis_size(axis_name)
         leaves, treedef = jax.tree.flatten(grads)
         if comm is None:
             vs = [g.astype(jnp.float32) for g in leaves]
@@ -401,7 +394,7 @@ class PowerSGD:
         return {"residual": _stack_zeros_like(params_like, world), "q": qs}
 
     def __call__(self, grads: Any, axis_name: str, comm: Any = None):
-        world = _axis_size(axis_name)
+        world = lax.axis_size(axis_name)
         leaves, treedef = jax.tree.flatten(grads)
         rs = (jax.tree.leaves(comm["residual"])
               if comm is not None else [None] * len(leaves))
@@ -427,7 +420,11 @@ class PowerSGD:
                 approx = p @ new_q.T
                 out[i] = approx.reshape(g.shape).astype(g.dtype)
                 new_rs[i] = (mat - approx).reshape(g.shape)[None]
-                new_qs[f"{i:03d}"] = new_q[None]
+                # Both factors come out of psums (replicated), but the
+                # stored Q is per-worker stacked state like the residuals:
+                # typed device-varying so a scan carry leaves as it entered.
+                new_qs[f"{i:03d}"] = lax.pcast(
+                    new_q, axis_name, to="varying")[None]
                 prev = new_q
             else:
                 # compressed_psum bf16 fallback, inline and chained.

@@ -24,7 +24,7 @@ and the device never idles between buckets.
 """
 
 from .batcher import MicroBatcher, QueueFull, coalesce, plan_batches
-from .cache import ExecutableCache, executable_serialization_supported
+from .cache import ExecutableCache
 from .engine import BUCKETS, DispatchHandle, InferenceEngine
 from .frontend import FrontendClient, LoopbackClient, ServingFrontend
 from .ingest import StagedIngest
@@ -40,7 +40,6 @@ __all__ = [
     "FrontendClient", "InferenceEngine", "LoopbackClient", "MicroBatcher",
     "PIPELINE_SLOTS", "QueueFull", "Reply", "ReplicaRouter", "SLOScheduler",
     "SchedRequest", "ServiceModel", "ServingFrontend", "StagedIngest",
-    "admit", "coalesce", "cost_model_weights",
-    "executable_serialization_supported", "make_request", "plan_batches",
+    "admit", "coalesce", "cost_model_weights", "make_request", "plan_batches",
     "plan_continuous", "plan_drain", "virtual_requests",
 ]

@@ -92,11 +92,10 @@ class InferenceEngine:
             if p not in ("f32", "bf16"):
                 raise ValueError(f"unknown precision {p!r}")
         if enable_compilation_cache:
-            # The repo-wide persistent XLA cache (satellite of the same
-            # PR wires it into cli.py startup): dedupes ladder compiles
-            # across server restarts even where executable serialization
-            # is unsupported.
-            compcache.enable_persistent_compilation_cache(compcache.repo_root())
+            # The repo-wide persistent XLA cache: dedupes ladder compiles
+            # across server restarts for engines built without a
+            # ``cache_dir`` (no serialized-executable warm start).
+            compcache.enable_persistent_compilation_cache()
         self.model_name = model
         self.buckets: Tuple[int, ...] = tuple(buckets)
         self.precisions: Tuple[str, ...] = tuple(precisions)
@@ -128,8 +127,10 @@ class InferenceEngine:
         # Everything an executable's identity depends on beyond the bucket
         # and dtype: the abstract model signature (param/bn shapes+dtypes,
         # not values), the fused-ingest scheme, and the toolchain/device
-        # identity.
+        # KIND.  Not the device itself: the cache loads an entry onto
+        # whichever device asks (serve/cache.py), so replicas share entries.
         d0 = device if device is not None else jax.devices()[0]
+        self._exec_device = d0
         leaves, treedef = jax.tree_util.tree_flatten(
             (self.params, self.bn_state))
         self._key_fields = {
@@ -139,8 +140,7 @@ class InferenceEngine:
                          tuple((l.shape, str(l.dtype)) for l in leaves)),
             "jax": jax.__version__,
             "backend": jax.default_backend(),
-            "device_kind": getattr(d0, "device_kind", str(d0)),
-            "device_id": int(getattr(d0, "id", 0)),
+            "device_kind": d0.device_kind,
         }
 
     # -- weight hot-swap ----------------------------------------------------
@@ -232,20 +232,7 @@ class InferenceEngine:
         for prec in self.precisions:
             for b in self.buckets:
                 t1 = time.time()
-                key = cache_key(bucket=b, precision=prec,
-                                **self._key_fields)
-                compiled = self._cache.load(key)
-                source = "cache"
-                if compiled is None:
-                    source = "compile"
-                    if self.telemetry.enabled:
-                        with self.telemetry.span("serve_compile", bucket=b,
-                                                 precision=prec):
-                            compiled = self._compile(prec, b)
-                    else:
-                        compiled = self._compile(prec, b)
-                    self._cache.save(key, compiled)
-                self._exec[(b, prec)] = compiled
+                source = self._build(b, prec)
                 name = f"{b}/{prec}" if len(self.precisions) > 1 else str(b)
                 per[name] = {"seconds": round(time.time() - t1, 4),
                              "source": source}
@@ -255,6 +242,7 @@ class InferenceEngine:
             "warm": all(v["source"] == "cache" for v in per.values()),
             "executable_cache": self._cache.stats(),
             "backend": jax.default_backend(),
+            "device_id": int(self._exec_device.id),
         }
         if self.telemetry.enabled:
             self.telemetry.gauge("serve_startup_s", report["startup_s"],
@@ -272,20 +260,29 @@ class InferenceEngine:
         return self.lowered(bucket, precision) \
             .compiler_ir(dialect="hlo").as_hlo_text()
 
-    def _compile(self, precision: str, bucket: int):
-        return self.lowered(bucket, precision).compile()
+    def _build(self, bucket: int, precision: str) -> str:
+        """Cache-load or AOT-compile (and save) one ladder rung; returns
+        which it was (``"cache"`` / ``"compile"``)."""
+        key = cache_key(bucket=bucket, precision=precision,
+                        **self._key_fields)
+        compiled = self._cache.load(key, self._exec_device)
+        source = "cache"
+        if compiled is None:
+            source = "compile"
+            if self.telemetry.enabled:
+                with self.telemetry.span("serve_compile", bucket=bucket,
+                                         precision=precision):
+                    compiled = self.lowered(bucket, precision).compile()
+            else:
+                compiled = self.lowered(bucket, precision).compile()
+            self._cache.save(key, compiled)
+        self._exec[(bucket, precision)] = compiled
+        return source
 
     def _executable(self, bucket: int, precision: str):
-        ex = self._exec.get((bucket, precision))
-        if ex is None:   # lazy build for direct-use paths without startup()
-            key = cache_key(bucket=bucket, precision=precision,
-                            **self._key_fields)
-            ex = self._cache.load(key)
-            if ex is None:
-                ex = self._compile(precision, bucket)
-                self._cache.save(key, ex)
-            self._exec[(bucket, precision)] = ex
-        return ex
+        if (bucket, precision) not in self._exec:
+            self._build(bucket, precision)   # direct use without startup()
+        return self._exec[(bucket, precision)]
 
     # -- dispatch -----------------------------------------------------------
 

@@ -191,7 +191,8 @@ def test_bench_matrix_and_sweep_wellformed(tmp_path, monkeypatch):
     eff = sc["efficiency_vs_1chip"]
     assert eff["1"] == 1.0
     assert all(v > 0 for v in eff.values())
-    assert set(sc["mfu_vs_bf16_peak"]) == {"1", "2", "4", "8"}
+    # The CPU mesh is not in the peak table: a measured path emits no MFU.
+    assert "mfu_vs_bf16_peak" not in sc
 
     # Strong scaling: the reference's own protocol (global batch fixed),
     # reported alongside weak (ADVICE r3 item 4).
@@ -223,7 +224,7 @@ def test_bench_matrix_and_sweep_wellformed(tmp_path, monkeypatch):
     # Emission contract: full payload (stdout line + sidecar) first, the
     # compact head LAST — the driver JSON-parses the final line of a
     # ~2000-byte stdout tail, which the full payload overflowed in rounds
-    # 4/5 ("parsed": null in BENCH_r04/r05.json).
+    # 4/5 (the driver recorded "parsed": null).
     import json
     sidecar = tmp_path / "BENCH_FULL.json"
     lines = []
@@ -508,39 +509,6 @@ def test_step_flops_per_image_is_world_invariant(tmp_path, mesh1, mesh8):
     # Collectives/layout differ slightly between the programs; the bug this
     # pins was a factor-of-world (8x) error, far outside this band.
     assert 0.5 < f8 / f1 < 2.0, (f1, f8)
-
-
-# -- CI artifact guard: committed BENCH_r*.json heads stay parseable ----------
-#
-# The driver captures bench.py's final stdout line as "parsed"; rounds 4/5
-# shipped oversized heads the driver recorded as parsed:null (the failure
-# emit_result now prevents).  Round 7 backfilled those two heads from the
-# artifacts' own truncated tails + the round commits' BASELINE/VERDICT
-# prose (the backfill is labeled in a "reconstructed" field), so the guard
-# now holds unconditionally: EVERY committed round artifact must carry a
-# parsed head with a non-null headline.
-
-
-def test_committed_bench_artifacts_parse_with_headline():
-    import glob
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    arts = sorted(glob.glob(os.path.join(repo, "BENCH_r*.json")))
-    assert arts, "no committed BENCH_r*.json artifacts found"
-    for path in arts:
-        name = os.path.basename(path)
-        with open(path) as f:
-            art = json.load(f)                     # every artifact is JSON
-        assert art["rc"] == 0, f"{name}: bench run failed"
-        parsed = art.get("parsed")
-        assert isinstance(parsed, dict), f"{name}: head did not parse"
-        assert parsed.get("value"), f"{name}: null/zero headline value"
-        assert parsed.get("metric"), f"{name}: missing headline metric"
-    # The round-4/5 backfills carry their provenance.
-    for name in ("BENCH_r04.json", "BENCH_r05.json"):
-        with open(os.path.join(repo, name)) as f:
-            head = json.load(f)["parsed"]
-        assert "backfilled" in head["reconstructed"]
-        assert head["headline_stats"]["best"] == head["value"]
 
 
 def test_bench_full_sidecar_carries_elastic_section_slot():

@@ -21,15 +21,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from cs744_ddp_tpu.ops import bnpool_pallas as bp
 
-# The interpret-mode context manager these tests run the kernels under is
-# not present on every jax in the support window (absent on this
-# container's build); without it there is no way to execute a TPU Pallas
-# kernel on the CPU CI, so the numerics pin only runs where it exists.
-pytestmark = pytest.mark.skipif(
-    not hasattr(pltpu, "force_tpu_interpret_mode"),
-    reason="jax.experimental.pallas.tpu lacks force_tpu_interpret_mode "
-           "on this toolchain")
-
 
 def _ref_chain(x, gamma, beta):
     """Autodiff oracle mirroring _fwd_impl bit for bit."""

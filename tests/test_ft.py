@@ -575,7 +575,7 @@ sys.path.insert(0, tests_dir)
 import jax
 jax.config.update("jax_platforms", "cpu")
 from cs744_ddp_tpu.utils.compcache import enable_persistent_compilation_cache
-enable_persistent_compilation_cache(repo)
+enable_persistent_compilation_cache()
 import cs744_ddp_tpu.train.loop as looplib
 looplib.WINDOW = 3
 from cs744_ddp_tpu.data import cifar10

@@ -258,15 +258,22 @@ def test_costmodel_scan_trip_inference():
 
 
 def test_costmodel_mfu_fields_single_source():
-    f = costmodel.mfu_fields(1000.0, 2e9)
+    f = costmodel.mfu_fields(1000.0, 2e9, "TPU v5 lite")
     assert f == {"tflops_per_sec": 2.0,
                  "mfu_vs_bf16_peak": round(2e12 / 197e12, 4)}
-    assert costmodel.mfu_fields(1000.0, None) == {}     # absent, not null
-    # Every consumer delegates here: same numbers from the metrics shim.
-    from cs744_ddp_tpu.utils import metrics
-    assert metrics.mfu_fields(1000.0, 2e9) == f
+    # absent, not null: no flop count, or a device outside the peak table
+    assert costmodel.mfu_fields(1000.0, None, "TPU v5 lite") == {}
+    assert costmodel.mfu_fields(1000.0, 2e9, "cpu") == {"tflops_per_sec": 2.0}
+    # bench delegates here with the device it measured on (the CPU mesh).
     import bench
-    assert bench._mfu_fields(1000.0, 2e9) == f
+    assert bench._mfu_fields(1000.0, 2e9) == {"tflops_per_sec": 2.0}
+    # Same rule on the attribution join.
+    from cs744_ddp_tpu.obs import attribution
+    rep = costmodel.CostReport("p", flops=2e9)
+    assert "mfu_vs_bf16_peak" in attribution.attribute(
+        rep, measured_s=1e-3, device_kind="TPU v5 lite")
+    assert "mfu_vs_bf16_peak" not in attribution.attribute(
+        rep, measured_s=1e-3, device_kind="cpu")
 
 
 # ---------------------------------------------------------------------------

@@ -21,9 +21,7 @@ from cs744_ddp_tpu import models as model_zoo
 from cs744_ddp_tpu.data import cifar10
 from cs744_ddp_tpu.obs import NULL
 from cs744_ddp_tpu.serve import (InferenceEngine, MicroBatcher, QueueFull,
-                                 StagedIngest, coalesce,
-                                 executable_serialization_supported,
-                                 plan_batches)
+                                 StagedIngest, coalesce, plan_batches)
 from cs744_ddp_tpu.serve.batcher import smallest_bucket
 from cs744_ddp_tpu.serve.demo import parse_buckets, synthetic_trace
 
@@ -298,20 +296,23 @@ def test_microbatcher_propagates_engine_failure():
 
 # -- warm-start executable cache ----------------------------------------------
 
-@pytest.mark.skipif(not executable_serialization_supported(),
-                    reason="jax lacks serialize_executable")
-def test_executable_cache_roundtrip(tmp_path, pool):
+@pytest.mark.parametrize("device_index", [None, 3])
+def test_executable_cache_roundtrip(tmp_path, pool, device_index):
     """Cold startup compiles + saves; a fresh engine on the same dir loads
-    every rung from cache and serves bitwise-identical logits."""
-    cold = InferenceEngine("tiny", buckets=(2, 4), seed=0,
-                           cache_dir=str(tmp_path))
+    every rung from cache and serves bitwise-identical logits ON ITS OWN
+    DEVICE — unpinned (default device) and as a replica pinned to device
+    index >= 1 of the 8-device mesh, where an executable loaded for "all
+    local devices" dies at its first call."""
+    import jax
+    device = None if device_index is None else jax.devices()[device_index]
+    kw = dict(buckets=(2, 4), seed=0, cache_dir=str(tmp_path), device=device)
+    cold = InferenceEngine("tiny", **kw)
     r_cold = cold.startup()
     assert not r_cold["warm"]
     assert all(v["source"] == "compile"
                for v in r_cold["per_bucket"].values())
 
-    warm = InferenceEngine("tiny", buckets=(2, 4), seed=0,
-                           cache_dir=str(tmp_path))
+    warm = InferenceEngine("tiny", **kw)
     r_warm = warm.startup()
     assert r_warm["warm"]
     assert all(v["source"] == "cache"
@@ -321,17 +322,40 @@ def test_executable_cache_roundtrip(tmp_path, pool):
     for n in (1, 3):
         assert np.array_equal(cold.infer(pool.images[:n]),
                               warm.infer(pool.images[:n]))
+    want = jax.devices()[device_index or 0]
+    ex = warm._executable(2, "f32")
+    logits, _, _ = ex(warm.params, warm.bn_state,
+                      np.zeros((2, 32, 32, 3), np.uint8),
+                      np.full((2,), -1, np.int32))
+    assert logits.devices() == {want}
 
 
-@pytest.mark.skipif(not executable_serialization_supported(),
-                    reason="jax lacks serialize_executable")
+def test_replica_warm_starts_from_another_devices_entry(tmp_path, pool):
+    """One entry serves every replica: a ladder compiled (and saved) by the
+    replica on device 0 loads for the replica on device 2, runs THERE, and
+    answers with the same logits."""
+    import jax
+    kw = dict(buckets=(2,), seed=0, cache_dir=str(tmp_path))
+    first = InferenceEngine("tiny", device=jax.devices()[0], **kw)
+    assert not first.startup()["warm"]
+    other = InferenceEngine("tiny", device=jax.devices()[2], **kw)
+    report = other.startup()
+    assert report["warm"] and report["device_id"] == jax.devices()[2].id
+    logits = other._executable(2, "f32")(
+        other.params, other.bn_state, pool.images[:2],
+        np.full((2,), -1, np.int32))[0]
+    assert logits.devices() == {jax.devices()[2]}
+    assert np.array_equal(np.asarray(logits), first.infer(pool.images[:2]))
+
+
 def test_executable_cache_treats_garbage_as_miss(tmp_path):
+    import jax
     from cs744_ddp_tpu.serve.cache import ExecutableCache, cache_key
     cache = ExecutableCache(str(tmp_path))
     key = cache_key(bucket=2, model="x")
     with open(cache._path(key), "wb") as f:
         f.write(b"not a pickle")
-    assert cache.load(key) is None
+    assert cache.load(key, jax.devices()[0]) is None
     assert cache.stats()["misses"] == 1
 
 

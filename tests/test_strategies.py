@@ -12,16 +12,11 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:
-    from jax import shard_map
-except ImportError:                      # jax < 0.6: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from cs744_ddp_tpu.parallel import bucketing, strategies
 from cs744_ddp_tpu.parallel.mesh import DATA_AXIS
-from cs744_ddp_tpu.train.step import _SHARD_MAP_KW
 
 
 def tree_of_grads(key, scale=1.0):
@@ -39,8 +34,7 @@ def run_strategy(mesh, strategy, grads_per_device):
     (replicated) result.  grads leaves have a leading device axis."""
     f = shard_map(lambda g: strategy(
         jax.tree.map(lambda a: a[0], g), DATA_AXIS),
-        mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P(),
-        **_SHARD_MAP_KW)
+        mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P())
     return jax.jit(f)(grads_per_device)
 
 
@@ -106,8 +100,7 @@ def test_strategy_collective_patterns_in_stablehlo(mesh8):
     def counts(strategy):
         f = shard_map(lambda g: strategy(
             jax.tree.map(lambda a: a[0], g), DATA_AXIS),
-            mesh=mesh8, in_specs=(P(DATA_AXIS),), out_specs=P(),
-            **_SHARD_MAP_KW)
+            mesh=mesh8, in_specs=(P(DATA_AXIS),), out_specs=P())
         hlo = jax.jit(f).lower(stacked).as_text()  # StableHLO MLIR
         return (len(re.findall(r"stablehlo\.all_reduce", hlo)),
                 len(re.findall(r"stablehlo\.optimization_barrier", hlo)))
@@ -125,8 +118,7 @@ def test_strategy_collective_patterns_in_stablehlo(mesh8):
     # gather_scatter: all-gather + all-reduce per leaf, chained.
     f = shard_map(lambda g: strategies.gather_scatter(
         jax.tree.map(lambda a: a[0], g), DATA_AXIS),
-        mesh=mesh8, in_specs=(P(DATA_AXIS),), out_specs=P(),
-        **_SHARD_MAP_KW)
+        mesh=mesh8, in_specs=(P(DATA_AXIS),), out_specs=P())
     hlo = jax.jit(f).lower(stacked).as_text()
     assert len(re.findall(r"stablehlo\.all_gather", hlo)) == 4
     assert len(re.findall(r"stablehlo\.all_reduce", hlo)) == 4
@@ -243,7 +235,7 @@ def run_stateful(mesh, strategy, grads_per_device, comm):
         lambda g, c: strategy(jax.tree.map(lambda a: a[0], g), DATA_AXIS,
                               comm=c),
         mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
-        out_specs=(P(), P(DATA_AXIS)), **_SHARD_MAP_KW)
+        out_specs=(P(), P(DATA_AXIS)))
     return jax.jit(f)(grads_per_device, comm)
 
 
@@ -268,8 +260,7 @@ def test_overlapped_ddp_drops_the_barrier_chain(mesh8):
     def counts(strategy):
         f = shard_map(lambda g: strategy(
             jax.tree.map(lambda a: a[0], g), DATA_AXIS),
-            mesh=mesh8, in_specs=(P(DATA_AXIS),), out_specs=P(),
-            **_SHARD_MAP_KW)
+            mesh=mesh8, in_specs=(P(DATA_AXIS),), out_specs=P())
         hlo = jax.jit(f).lower(stacked).as_text()  # StableHLO MLIR
         return (len(re.findall(r"stablehlo\.all_reduce", hlo)),
                 len(re.findall(r"stablehlo\.optimization_barrier", hlo)))

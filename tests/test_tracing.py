@@ -541,6 +541,11 @@ def test_two_process_waterfall_acceptance(tmp_path):
                  "--port", str(fe.address[1]), "--rps", "40",
                  "--requests", "12", "--max-size", "4",
                  "--telemetry-out", cli_dir, "--timeout", "60"],
+                # The load client must never initialise a jax backend: on
+                # the chip the server process holds the device and a client
+                # that touched it would fail or hang.  An unknown platform
+                # name turns any backend initialisation into an error.
+                env={**os.environ, "JAX_PLATFORMS": "no_such_platform"},
                 capture_output=True, text=True, timeout=180)
     assert proc.returncode == 0, proc.stderr[-800:]
     stats = json.loads(proc.stdout.strip().splitlines()[-1])

@@ -63,7 +63,7 @@ def main(argv=None):
     args = p.parse_args(argv)
 
     import __graft_entry__ as ge
-    ge._ensure_devices(N_DEVICES)
+    ge._ensure_devices(N_DEVICES, "cpu")
 
     import numpy as np
     import jax

@@ -27,14 +27,14 @@ def throughput(**kw):
     defaults.update(kw)
     tr = Trainer(**defaults)
     _, ips = tr.steady_state_throughput(max_iters=100)
-    return ips, mfu_fields(ips, tr.step_flops_per_image())
+    return ips, mfu_fields(ips, tr.step_flops_per_image(),
+                           tr.mesh.devices.flat[0].device_kind)
 
 
 def main():
     from cs744_ddp_tpu.utils.compcache import \
         enable_persistent_compilation_cache
-    enable_persistent_compilation_cache(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    enable_persistent_compilation_cache()
 
     results = {}
     experiments = [

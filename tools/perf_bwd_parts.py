@@ -25,8 +25,7 @@ def main():
     from cs744_ddp_tpu.utils.compcache import \
         enable_persistent_compilation_cache
 
-    enable_persistent_compilation_cache(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    enable_persistent_compilation_cache()
 
     B = 256
     rng = np.random.default_rng(0)

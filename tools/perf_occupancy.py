@@ -227,8 +227,7 @@ def main():
 
     from cs744_ddp_tpu.utils.compcache import \
         enable_persistent_compilation_cache
-    enable_persistent_compilation_cache(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    enable_persistent_compilation_cache()
 
     mxu_map = build_mxu_map(args.model, args.global_batch, args.precision,
                             args.window)
