@@ -11,10 +11,10 @@ site guards on ``telemetry.enabled``.
 
 from .alerts import RULES as ALERT_RULES
 from .alerts import Alert, AlertEngine
-from .telemetry import (NULL, NullTelemetry, Telemetry, git_sha, percentile,
-                        read_run, summarize_events)
+from .telemetry import (NULL, NULL_SPAN, NullTelemetry, Telemetry, git_sha,
+                        percentile, read_run, span_log, summarize_events)
 from .tracing import TraceContext
 
-__all__ = ["ALERT_RULES", "Alert", "AlertEngine", "NULL", "NullTelemetry",
-           "Telemetry", "TraceContext", "git_sha", "percentile", "read_run",
-           "summarize_events"]
+__all__ = ["ALERT_RULES", "Alert", "AlertEngine", "NULL", "NULL_SPAN",
+           "NullTelemetry", "Telemetry", "TraceContext", "git_sha",
+           "percentile", "read_run", "span_log", "summarize_events"]

@@ -5,10 +5,20 @@ Three primitives, one file format:
 * **events**  — per-step records (``kind: "step"``): loss, step/forward wall
   time, the steady flag (first 20-iteration window and ragged-tail dispatches
   excluded, mirroring ``WindowedTimers``), epoch and iteration number.
-* **spans**   — named wall-clock regions (``kind: "span"``): host augment,
-  prefetch put, eval, compile/warmup, checkpoint save.  Spans nest; each
-  record carries its depth and parent name.  The span stack is thread-local
-  because the host-augment producer runs on its own thread.
+* **spans**   — named host intervals (``kind: "span"``): the dispatch
+  loop's phases (README "Observability" lists them), host augment, prefetch
+  put, compile/warmup, checkpoint save.  Spans nest; each record carries
+  ``id`` / ``parent_id`` (integers, per recorder), ``t_ns`` (the start in
+  Unix nanoseconds, ``time.time_ns()``: the clock the profiler stamps the
+  xplane with), ``dur_ns`` (from ``time.perf_counter_ns()``), the older
+  ``t`` / ``dur_s`` / ``depth`` / ``parent`` (name) derived from the same
+  readings, and its attributes.  The span stack is thread-local because
+  the host-augment producer runs on its own thread.  A ``span()`` also
+  opens a ``jax.profiler.TraceAnnotation`` of the same name (where jax is
+  already imported: this module never imports it), so a profiler session
+  shows the span under the device lines; outside a session that twin is a
+  flag test.  Every span an enabled recorder emits also goes to the
+  process-wide bounded ``span_log()``.
 * **gauges/counters** — point-in-time values (``kind: "gauge"``) and
   monotonic tallies (``kind: "counter"``): prefetch queue depth, native-
   loader status, device ``memory_stats()``, collective op counts/bytes.
@@ -28,14 +38,30 @@ argument dicts are never built).
 
 from __future__ import annotations
 
+import collections
+import itertools
 import json
 import os
 import subprocess
+import sys
 import threading
 import time
 from typing import Any, Dict, IO, List, Optional, Tuple
 
 _SCHEMA_VERSION = 1
+
+# The flight recorder: the newest spans of every enabled recorder in this
+# process, oldest first.  Bounded, so a run of any length keeps a fixed
+# footprint; it outlives the recorder that wrote it, so a reader called
+# after the trainer is gone (the benchmark's, an operator after a slow
+# epoch) still finds the spans.  ``NULL`` never appends.
+SPAN_LOG_MAX = 16384
+_SPAN_LOG: "collections.deque" = collections.deque(maxlen=SPAN_LOG_MAX)
+
+
+def span_log() -> List[Dict[str, Any]]:
+    """The span records in the process-wide log, oldest first (a copy)."""
+    return list(_SPAN_LOG)
 
 
 def atomic_write_json(path: str, obj, indent: Optional[int] = 2) -> None:
@@ -146,7 +172,7 @@ class _NullSpan:
         return False
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 class NullTelemetry:
@@ -170,7 +196,7 @@ class NullTelemetry:
         pass
 
     def span(self, name: str, **attrs):
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def span_event(self, name: str, t0: float, dur_s: float,
                    **attrs) -> None:
@@ -198,27 +224,43 @@ class NullTelemetry:
 NULL = NullTelemetry()
 
 
+def _open_twin(name: str, span_id: int):
+    """The span's twin in the profiler's trace: a ``TraceAnnotation`` of
+    the same name, entered.  None where jax is not imported (the report
+    tool); outside a profiler session the annotation is a flag test."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    twin = jax.profiler.TraceAnnotation(name, span_id=span_id)
+    twin.__enter__()
+    return twin
+
+
 class _Span:
-    __slots__ = ("_tel", "name", "attrs", "t0")
+    __slots__ = ("_tel", "name", "attrs", "id", "t_ns", "_p0", "_twin")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: Dict[str, Any]):
         self._tel = tel
         self.name = name
         self.attrs = attrs
-        self.t0 = 0.0
 
     def __enter__(self):
-        self._tel._push(self.name)
-        self.t0 = time.time()
+        self.id = self._tel._push(self.name)
+        self._twin = _open_twin(self.name, self.id)
+        self.t_ns = time.time_ns()
+        self._p0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, *exc):
-        dur = time.time() - self.t0
+        dur_ns = time.perf_counter_ns() - self._p0
+        if self._twin is not None:
+            self._twin.__exit__(exc_type, *exc)
         parent, depth = self._tel._pop()
-        rec = {"kind": "span", "name": self.name, "t": self.t0,
-               "dur_s": dur, "depth": depth}
+        rec = {"kind": "span", "name": self.name, "id": self.id,
+               "t": self.t_ns / 1e9, "dur_s": dur_ns / 1e9,
+               "t_ns": self.t_ns, "dur_ns": dur_ns, "depth": depth}
         if parent is not None:
-            rec["parent"] = parent
+            rec["parent"], rec["parent_id"] = parent
         if exc_type is not None:
             rec["error"] = exc_type.__name__
         if self.attrs:
@@ -248,6 +290,7 @@ class Telemetry:
         self._lock = threading.Lock()  # producer thread emits spans too
         self._tls = threading.local()
         self._counters: Dict[str, float] = {}
+        self._span_ids = itertools.count(1)
         self._taps: List = []   # live record observers (alert engine)
         if rotate_keep < 1:
             raise ValueError(f"rotate_keep must be >= 1, got {rotate_keep}")
@@ -264,16 +307,21 @@ class Telemetry:
 
     # -- span stack (per thread) -------------------------------------------
 
-    def _stack(self) -> List[str]:
+    def _stack(self) -> List[Tuple[str, int]]:
         st = getattr(self._tls, "stack", None)
         if st is None:
             st = self._tls.stack = []
         return st
 
-    def _push(self, name: str) -> None:
-        self._stack().append(name)
+    def _push(self, name: str) -> int:
+        """Open a span on this thread's stack; returns its id."""
+        span_id = next(self._span_ids)
+        self._stack().append((name, span_id))
+        return span_id
 
-    def _pop(self) -> Tuple[Optional[str], int]:
+    def _pop(self) -> Tuple[Optional[Tuple[str, int]], int]:
+        """Close the innermost span -> ((parent name, parent id) or None,
+        depth)."""
         st = self._stack()
         st.pop()
         return (st[-1] if st else None), len(st)
@@ -281,6 +329,8 @@ class Telemetry:
     # -- emission -----------------------------------------------------------
 
     def _emit(self, rec: Dict[str, Any]) -> None:
+        if rec["kind"] == "span":
+            _SPAN_LOG.append(rec)
         with self._lock:
             if self._fh is not None:
                 line = json.dumps(rec) + "\n"
@@ -360,9 +410,12 @@ class Telemetry:
         this suits asynchronous intervals whose endpoints live on
         different threads or came off the wire — a client round-trip, a
         queue wait — so depth is 0 and parenting comes from the caller's
-        trace attrs, not the thread-local stack."""
-        rec = {"kind": "span", "name": name, "t": float(t0),
-               "dur_s": float(dur_s), "depth": 0}
+        trace attrs, not the thread-local stack.  ``t0`` is Unix seconds
+        (``time.time()``); an interval that is over when it is recorded
+        gets no twin in the profiler's trace."""
+        rec = {"kind": "span", "name": name, "id": next(self._span_ids),
+               "t": float(t0), "dur_s": float(dur_s),
+               "t_ns": int(t0 * 1e9), "dur_ns": int(dur_s * 1e9), "depth": 0}
         if attrs:
             rec.update(attrs)
         self._emit(rec)

@@ -39,7 +39,7 @@ from ..ft import (FTConfig, ChaosError, NULL_CHAOS, NonFiniteError,
                   PreemptedError, PreemptionGuard, RankDeathError)
 from ..ft import guard as ftguard
 from ..ft import supervisor as ftsup
-from ..obs import NULL, git_sha, ringbuf
+from ..obs import NULL, NULL_SPAN, git_sha, ringbuf
 from ..ops import sgd
 from ..parallel import get_strategy, mesh as meshlib, strategies
 from ..utils.metrics import WINDOW, WindowedTimers
@@ -437,6 +437,7 @@ class Trainer:
         self._warmed_window_shapes = set()
         self.last_epoch_timers: Optional[WindowedTimers] = None
         self._collective_stats_emitted = False
+        self._span_epoch: Optional[int] = None  # of the last train_model
 
         if self._nf_policy == "restore":
             # "Last checkpoint" before any save is the initial state.
@@ -600,13 +601,14 @@ class Trainer:
                     sharding=rep),
                 jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
 
-    def _count_round_trip(self, site: str, **attrs) -> None:
+    def _count_round_trip(self, site: str) -> None:
         """Tally one device->host value fetch.  The windowed+ring epoch is
         pinned at <= windows + 2 of these (per-window drains, the ragged
         tail, the eval fetch); the per-step path honestly records one per
         iteration — the contrast the ring exists to remove."""
         if self.telemetry.enabled:
-            self.telemetry.counter("host_round_trips", 1, site=site, **attrs)
+            self.telemetry.counter("host_round_trips", 1, site=site,
+                                   epoch=self._span_epoch)
 
     def _consume_ring(self, buf_host, writes_total: int, w: int,
                       per_iter: float, timers: WindowedTimers,
@@ -908,8 +910,10 @@ class Trainer:
             cache_key = (w, tuple(epoch_images.shape), ring_on)
             if cache_key in self._warmed_window_shapes:
                 continue
-            with self.telemetry.span("compile_warmup",
-                                     program="train_window", window=w):
+            with (self.telemetry.span("compile_warmup",
+                                      epoch=self._span_epoch,
+                                      program="train_window", window=w)
+                  if self.telemetry.enabled else NULL_SPAN):
                 if ring_on:
                     self.train_window_ring.lower(
                         self.state, self._ring_sds(), key, epoch_images,
@@ -929,8 +933,10 @@ class Trainer:
         cache_key = (tail[0].shape[0], str(tail[0].dtype))
         if cache_key in self._warmed_tail_shapes:
             return
-        with self.telemetry.span("compile_warmup", program="train_step_tail",
-                                 batch=int(tail[0].shape[0])):
+        with (self.telemetry.span("compile_warmup", epoch=self._span_epoch,
+                                  program="train_step_tail",
+                                  batch=int(tail[0].shape[0]))
+              if self.telemetry.enabled else NULL_SPAN):
             self.train_step.lower(
                 self.state, jax.random.PRNGKey(self.seed), *tail).compile()
         self._warmed_tail_shapes.add(cache_key)
@@ -980,22 +986,58 @@ class Trainer:
         return timers
 
     def _train_model_impl(self, epoch: int, start_step: int) -> WindowedTimers:
+        self._span_epoch = epoch
         if self.profile_phases:
             return self._train_model_per_step(epoch, start_step)
         if self.host_augment:
             return self._train_model_host_windowed(epoch, start_step)
+        with self._loop_span("epoch_train"):
+            return self._train_model_windowed(epoch, start_step)
+
+    def _loop_span(self, name: str):
+        """A span of the dispatch loop, tagged with the epoch its unit
+        trains (``test_model`` carries the epoch of the ``train_model``
+        before it).  Through a disabled recorder: the shared no-op, and the
+        recorder is not touched."""
         if self.telemetry.enabled:
+            return self.telemetry.span(name, epoch=self._span_epoch)
+        return NULL_SPAN
+
+    def _count_dispatch(self, site: str) -> None:
+        """Tally one enqueued program whose result the host will fetch:
+        per epoch, dispatches == fetches (``host_round_trips``)."""
+        if self.telemetry.enabled:
+            self.telemetry.counter("dispatches", 1, site=site,
+                                   epoch=self._span_epoch)
+
+    def _train_model_windowed(self, epoch: int,
+                              start_step: int) -> WindowedTimers:
+        """The default path: device-resident epoch, one dispatch and one
+        drain per 20-iteration window, the ragged tail through its own
+        step.  Its spans (README "Observability") end where the host's
+        state changes: a ``*_dispatch`` span when the jitted call returns,
+        a ``*_drain`` / ``*_fetch`` span when the value is on the host;
+        between the end of a fetch and the end of the next dispatch nothing
+        is in flight and the device waits for the host."""
+        tel = self.telemetry
+        on = tel.enabled
+        span = self._loop_span
+        clock = time.perf_counter_ns    # the parity timers' own readings
+        if on:
             self._emit_collective_telemetry()
-        timers = WindowedTimers(self.log, telemetry=self.telemetry,
-                                epoch=epoch)
+        timers = WindowedTimers(self.log, telemetry=tel, epoch=epoch)
         key = jax.random.fold_in(jax.random.PRNGKey(self.seed), epoch)
-        staged = self._stage_train_epoch(epoch)
-        self._warm_train_windows(staged)
+        with span("stage_lookup"):
+            staged = self._stage_train_epoch(epoch)
+            self._warm_train_windows(staged)
         epoch_images, epoch_labels, tail = staged
         nbatches = epoch_images.shape[0]
         start = start_step
         use_ring = self.train_window_ring is not None
-        ring = self._make_ring_device() if use_ring else None
+        ring = None
+        if use_ring:
+            with span("ring_alloc"):
+                ring = self._make_ring_device()
         ring_writes = 0
         self._check_preempt(epoch, start)
         while start < nbatches:
@@ -1005,65 +1047,79 @@ class Trainer:
             # uninterrupted run would — the bitwise-resume invariant does
             # not depend on scan-length-invariance of the compiler.
             w = min(WINDOW - start % WINDOW, nbatches - start)
-            t0 = time.time()
+            t0 = clock()
             # The span is tagged with the gradient-sync strategy so the
             # telemetry timeline attributes window wall time per tier
             # (the compressed-collective bench reads these back).
-            with self.telemetry.span("train_window",
-                                     strategy=self.strategy_name,
-                                     start=int(start), batches=int(w)):
-                if use_ring:
-                    self.state, ring = self.train_window_ring(
-                        self.state, ring, key, epoch_images, epoch_labels,
-                        jnp.int32(start), jnp.zeros((w,), jnp.int8))
-                    ring_writes += w
-                    # The window's ONE device->host round-trip: the whole
-                    # ring buffer, doubling as the completion fence.
-                    buf_host = np.asarray(ring[0])
-                else:
-                    out = self.train_window(
-                        self.state, key, epoch_images, epoch_labels,
-                        jnp.int32(start), jnp.zeros((w,), jnp.int8))
-                    if self._guard_on:
-                        self.state, losses, oks = out
+            with (tel.span("train_window", epoch=epoch,
+                           strategy=self.strategy_name, start=int(start),
+                           batches=int(w)) if on else NULL_SPAN):
+                with span("window_dispatch"):
+                    if use_ring:
+                        self.state, ring = self.train_window_ring(
+                            self.state, ring, key, epoch_images,
+                            epoch_labels, jnp.int32(start),
+                            jnp.zeros((w,), jnp.int8))
+                        ring_writes += w
                     else:
-                        (self.state, losses), oks = out, None
-                    losses = np.asarray(losses)  # value fetch = fence
-            per_iter = (time.time() - t0) / w
-            self._count_round_trip("window_drain" if use_ring
-                                   else "window_fetch", epoch=epoch)
-            if use_ring:
-                oks = self._consume_ring(buf_host, ring_writes, w, per_iter,
-                                         timers, epoch)
-                if not self._guard_on:
-                    oks = None
-            else:
-                for loss in losses:
-                    timers.record(float(loss), per_iter)
-            if self._nf_chaos_steps and \
-                    self.chaos.fire_range("nonfinite_grad", start, start + w):
-                self._record_chaos("nonfinite_grad", next(
-                    s for s in self._nf_chaos_steps if start <= s < start + w))
-            start += w
-            if oks is not None:
-                self._handle_nonfinite(oks, epoch)
-            self._rank_boundary(epoch, start, per_iter)
-            emit_memory_gauges(self.telemetry, epoch=epoch, step=int(start))
-            self._check_preempt(epoch, start)
+                        out = self.train_window(
+                            self.state, key, epoch_images, epoch_labels,
+                            jnp.int32(start), jnp.zeros((w,), jnp.int8))
+                        if self._guard_on:
+                            self.state, losses, oks = out
+                        else:
+                            (self.state, losses), oks = out, None
+                self._count_dispatch("window")
+                with span("window_drain"):
+                    # The window's ONE device->host round-trip (with the
+                    # ring: the whole buffer), doubling as the completion
+                    # fence.
+                    if use_ring:
+                        buf_host = np.asarray(ring[0])
+                    else:
+                        losses = np.asarray(losses)
+            per_iter = (clock() - t0) / (1e9 * w)
+            with span("window_host"):
+                self._count_round_trip("window_drain" if use_ring
+                                       else "window_fetch")
+                if use_ring:
+                    oks = self._consume_ring(buf_host, ring_writes, w,
+                                             per_iter, timers, epoch)
+                    if not self._guard_on:
+                        oks = None
+                else:
+                    for loss in losses:
+                        timers.record(float(loss), per_iter)
+                if self._nf_chaos_steps and self.chaos.fire_range(
+                        "nonfinite_grad", start, start + w):
+                    self._record_chaos("nonfinite_grad", next(
+                        s for s in self._nf_chaos_steps
+                        if start <= s < start + w))
+                start += w
+                if oks is not None:
+                    self._handle_nonfinite(oks, epoch)
+                self._rank_boundary(epoch, start, per_iter)
+                with span("obs_emit"):  # work a disabled run does not do
+                    emit_memory_gauges(tel, epoch=epoch, step=int(start))
+                self._check_preempt(epoch, start)
         if tail is not None and start_step <= nbatches:
             # The ragged final batch (drop_last=False parity) through its
             # own compiled step; host-side fold of the batch index keeps the
             # canonical (index, position) key order of both other paths.
-            self._warm_tail_step(tail)  # keep the compile out of the timer
-            tail_key = jax.random.fold_in(key, nbatches)
-            t0 = time.time()
-            loss, ok = self._fetch_step(
-                self.train_step(self.state, tail_key, *tail))
-            # steady=False: this lone per-dispatch sample carries the fixed
-            # dispatch latency the amortized window samples do not.
-            timers.record(loss, time.time() - t0, steady=False)
-            if ok is not None:
-                self._handle_nonfinite(np.asarray([ok]), epoch)
+            with span("tail_step"):
+                with span("tail_dispatch"):
+                    self._warm_tail_step(tail)  # no compile in the timer
+                    tail_key = jax.random.fold_in(key, nbatches)
+                    t0 = clock()
+                    out = self.train_step(self.state, tail_key, *tail)
+                self._count_dispatch("tail")
+                with span("tail_fetch"):
+                    loss, ok = self._fetch_step(out)
+                # steady=False: this lone per-dispatch sample carries the
+                # fixed dispatch latency the amortized window samples do not.
+                timers.record(loss, (clock() - t0) / 1e9, steady=False)
+                if ok is not None:
+                    self._handle_nonfinite(np.asarray([ok]), epoch)
         self.last_epoch_timers = timers
         return timers
 
@@ -1814,7 +1870,7 @@ class Trainer:
                 losses = np.asarray(losses)  # value fetch = fence
             per_iter = (time.time() - t0) / w
             self._count_round_trip("window_drain" if host_ring
-                                   else "window_fetch", epoch=epoch)
+                                   else "window_fetch")
             if host_ring:
                 oks = self._consume_ring(buf_host, ring_writes, w, per_iter,
                                          timers, epoch)
@@ -1877,11 +1933,17 @@ class Trainer:
         """Full-test-set evaluation in one dispatch; prints the reference's
         line (``Part 1/main.py:74-76``): per-batch-averaged CE, correct/total,
         %."""
-        with self.telemetry.span("eval"):
-            images, labels = self._stage_eval()
-            loss_sum, corr = self.eval_window(self.state, images, labels)
-            # Value fetches inside the span so it covers real device work.
-            loss_sum, corr = float(loss_sum), int(corr)
+        span = self._loop_span
+        with span("eval"):
+            with span("eval_stage_lookup"):
+                images, labels = self._stage_eval()
+            with span("eval_dispatch"):
+                out = self.eval_window(self.state, images, labels)
+            self._count_dispatch("eval")
+            with span("eval_fetch"):
+                # Both values in ONE fetch, inside the span so it covers
+                # real device work: the evaluation's one round trip.
+                loss_sum, corr = jax.device_get(out)
             self._count_round_trip("eval")
         n = len(self.test_split.labels)
         if self.limit_eval_batches is not None:

@@ -30,9 +30,9 @@ import threading
 import pytest
 
 from cs744_ddp_tpu import cli
-from cs744_ddp_tpu.obs import (NULL, NullTelemetry, Telemetry, git_sha,
-                               percentile, read_run, summarize_events)
-from cs744_ddp_tpu.obs.telemetry import _NULL_SPAN
+from cs744_ddp_tpu.obs import (NULL, NULL_SPAN, NullTelemetry, Telemetry,
+                               git_sha, percentile, read_run,
+                               summarize_events)
 from cs744_ddp_tpu.train.loop import Trainer
 from cs744_ddp_tpu.utils.metrics import WindowedTimers
 
@@ -186,11 +186,11 @@ def test_null_recorder_makes_no_writes_and_holds_no_state(monkeypatch):
     assert NULL.finalize(global_batch=64) is None
     assert opened == []                            # zero file writes
     # The span context manager is a shared singleton — no per-call alloc.
-    assert NULL.span("a") is NULL.span("b") is _NULL_SPAN
+    assert NULL.span("a") is NULL.span("b") is NULL_SPAN
     # The chunked-staging spans ride the same path: attrs must not force
     # an allocation either (the producer thread calls these per chunk).
-    assert NULL.span("chunk_put", batches=3, last=True) is _NULL_SPAN
-    assert NULL.span("chunk_wait") is _NULL_SPAN
+    assert NULL.span("chunk_put", batches=3, last=True) is NULL_SPAN
+    assert NULL.span("chunk_wait") is NULL_SPAN
     NULL.gauge("window_chunks_pending", 2)         # still zero writes
     assert opened == []
 
@@ -422,3 +422,55 @@ def test_telemetry_report_renders_run_dir(tmp_path, monkeypatch, capsys):
     reparsed = json.loads(capsys.readouterr().out)
     assert reparsed["num_steady_steps"] == 3
     assert reparsed["global_batch"] == 64          # pulled from the manifest
+
+
+def test_telemetry_report_renders_loop_section(tmp_path, monkeypatch):
+    """``== loop ==``: per span name of the default windowed path its
+    count, median, longest and total per epoch, and the spans that ran 30 ms
+    or more over their name's median; absent where no such span was
+    recorded."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(os.path.join(repo, "tools"))
+    import telemetry_report
+
+    def record(name, dur_ms, epoch, parent=None):
+        rec = {"kind": "span", "name": name, "t_ns": 10 ** 18 + epoch,
+               "dur_ns": int(dur_ms * 1e6), "epoch": epoch}
+        if parent:
+            rec["parent"] = parent
+        return rec
+
+    events = []
+    for epoch in range(4):
+        events += [record("epoch_train", 700, epoch),
+                   record("window_dispatch", 2, epoch, "train_window"),
+                   record("window_drain", 600, epoch, "train_window"),
+                   record("window_host", 41.5 if epoch == 2 else 1.5, epoch,
+                          "epoch_train"),
+                   record("eval_fetch", 45, epoch, "eval")]
+    events.append({"kind": "span", "name": "host_augment", "t": 1.0,
+                   "dur_s": 9.0})          # not a span of this section
+    lines = telemetry_report._loop_lines(events)
+    assert lines[0] == "== loop (dispatch-loop spans, 4 epoch(s)) =="
+    rows = {l.split()[0]: l.split() for l in lines[2:] if l.strip()}
+    assert rows["window_host"][1:] == ["4", "1.500", "ms", "41.500", "ms",
+                                       "11.500", "ms"]
+    assert rows["window_drain"][1:] == ["4", "600.000", "ms", "600.000",
+                                        "ms", "600.000", "ms"]
+    assert "host_augment" not in rows
+    (slow,) = [l for l in lines if l.startswith("  slow:")]
+    assert slow == ("  slow: window_host 41.500 ms (+40.000 over its "
+                    "median) epoch 2 parent epoch_train")
+    # a run directory from a real recorder renders the section; one
+    # without these spans renders as before
+    d = str(tmp_path / "loop")
+    tel = Telemetry(d)
+    with tel.span("epoch_train", epoch=0):
+        with tel.span("stage_lookup", epoch=0):
+            pass
+    tel.finalize()
+    text = telemetry_report.render(d)
+    assert "== loop (dispatch-loop spans, 1 epoch(s)) ==" in text
+    assert "stage_lookup" in text.split("== loop")[1]
+    assert telemetry_report._loop_lines(events[-1:]) == []
+    assert telemetry_report._loop_lines([]) == []

@@ -457,6 +457,53 @@ def _waterfall_lines(out_dir: str, events) -> list:
     return lines
 
 
+# The dispatch loop's spans (train/loop.py default path, test_model), in the
+# order an epoch opens them.
+LOOP_SPANS = ("epoch_train", "stage_lookup", "ring_alloc", "train_window",
+              "window_dispatch", "window_drain", "window_host", "obs_emit",
+              "tail_step", "tail_dispatch", "tail_fetch", "eval",
+              "eval_stage_lookup", "eval_dispatch", "eval_fetch")
+SLOW_SPAN_MS = 30.0
+
+
+def _loop_lines(events) -> list:
+    """Dispatch-loop rendering: per span name of the default windowed path
+    its count, median, longest and total per epoch, then every span that
+    ran ``SLOW_SPAN_MS`` or more over its name's median (a host stall shows
+    as one slow ``window_host`` or ``*_fetch``, not as a slower median).
+    Returns [] for runs without these spans (host-fed, per-step, serving,
+    runs recorded before the spans existed)."""
+    by_name = {}
+    for e in events:
+        if e.get("kind") == "span" and e.get("name") in LOOP_SPANS \
+                and "dur_ns" in e:
+            by_name.setdefault(e["name"], []).append(e)
+    if not by_name:
+        return []
+    epochs = {e.get("epoch") for e in by_name.get("epoch_train", ())} \
+        or {e.get("epoch") for spans in by_name.values() for e in spans}
+    lines = [f"== loop (dispatch-loop spans, {len(epochs)} epoch(s)) ==",
+             f"  {'span':<18} {'count':>6} {'p50':>12} {'max':>12} "
+             f"{'total/epoch':>14}"]
+    slow = []
+    for name in LOOP_SPANS:
+        spans = by_name.get(name)
+        if not spans:
+            continue
+        ms = [e["dur_ns"] / 1e6 for e in spans]
+        p50 = percentile(ms, 50)
+        lines.append(f"  {name:<18} {len(ms):>6} {p50:>9.3f} ms "
+                     f"{max(ms):>9.3f} ms {sum(ms) / len(epochs):>11.3f} ms")
+        slow += [(e, p50) for e in spans
+                 if e["dur_ns"] / 1e6 - p50 >= SLOW_SPAN_MS]
+    for e, p50 in sorted(slow, key=lambda x: x[0].get("t_ns", 0)):
+        lines.append(f"  slow: {e['name']} {e['dur_ns'] / 1e6:.3f} ms "
+                     f"(+{e['dur_ns'] / 1e6 - p50:.3f} over its median) "
+                     f"epoch {e.get('epoch')} parent {e.get('parent')}")
+    lines.append("")
+    return lines
+
+
 def _alert_lines(events) -> list:
     """Alert-engine rendering (round 12, ``obs/alerts.py``): structured
     ``kind: alert`` events grouped by deterministic rule id.  Returns []
@@ -543,6 +590,7 @@ def render(out_dir: str) -> str:
             lines.append(f"  {name:<34} {total}")
         lines.append("")
 
+    lines.extend(_loop_lines(events))
     lines.extend(_wire_ext_lines(events))
 
     lines.extend(_serving_lines(events))
