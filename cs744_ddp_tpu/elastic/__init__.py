@@ -14,6 +14,12 @@ and pinned math.  Layers:
                      coordinator_loss);
 * ``straggler``    — EWMA-vs-peers step-time outlier detection over the
                      per-rank gauges the trainer emits.
+
+The re-exports below are eager and cheap: nothing here loads orbax.  The
+coordinator reads checkpoint metadata through the JSON readers of
+``train/checkpoint.py``; ``orbax.checkpoint`` is imported only where a
+``CheckpointManager`` is built, i.e. only when ``--checkpoint-dir`` is given
+(which ``--elastic`` requires).
 """
 
 from .coordinator import ElasticCoordinator                     # noqa: F401

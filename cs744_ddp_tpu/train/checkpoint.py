@@ -1,5 +1,11 @@
 """Checkpoint/resume for training state (orbax-backed).
 
+orbax is loaded only when ``--checkpoint-dir`` is given: this module imports
+without it, and ``CheckpointManager.__init__`` is the one place that imports
+``orbax.checkpoint`` (seconds of start-up, through ``google.cloud.logging``:
+PERF.md §6, PR 27).  The JSON helpers here (``publish_fingerprint``, the
+``read_*_meta`` readers) never need it.
+
 The reference has NO checkpointing (no torch.save/load anywhere — SURVEY.md
 §5: training state lives only in memory for the duration of a run), so this
 subsystem is beyond-parity: it exists because a framework, unlike coursework
@@ -22,8 +28,6 @@ import os
 from typing import Optional, Tuple
 
 import jax
-
-import orbax.checkpoint as ocp
 
 from .step import TrainState
 
@@ -130,7 +134,14 @@ class CheckpointManager:
     legitimately changes (``world``, ``global_batch``) from the equality
     check — every other mismatch still fails.  The on-disk config is NOT
     rewritten: it keeps recording the run's ORIGINAL topology, and the
-    elastic metadata sidecars carry the per-save truth."""
+    elastic metadata sidecars carry the per-save truth.
+
+    Constructing a manager is what imports ``orbax.checkpoint`` (kept as
+    ``self._ocp`` for every other method), and that is deliberate: the
+    import takes seconds, tens of them with a cold page cache, and
+    ``save_mid_epoch`` runs in the grace period after SIGTERM, so it must
+    never be the first importer.  ``Trainer.run`` builds its manager before
+    the first step; build yours before the work a save would protect."""
 
     def __init__(self, directory: str, max_to_keep: int = 3,
                  config: Optional[dict] = None, *, elastic: bool = False):
@@ -190,6 +201,8 @@ class CheckpointManager:
                 with open(tmp, "w") as f:
                     json.dump(existing, f)
                 os.replace(tmp, self._config_path)
+        import orbax.checkpoint as ocp
+        self._ocp = ocp
         self._mngr = ocp.CheckpointManager(
             directory,
             options=ocp.CheckpointManagerOptions(max_to_keep=max_to_keep,
@@ -248,7 +261,7 @@ class CheckpointManager:
         epoch save — world, global_batch, protocol, per-rank data-order
         keys — written atomically after the checkpoint is durable so the
         sidecar can never describe a save that doesn't exist."""
-        self._mngr.save(epoch, args=ocp.args.StandardSave(state))
+        self._mngr.save(epoch, args=self._ocp.args.StandardSave(state))
         self._mngr.wait_until_finished()
         if meta is not None:
             _atomic_write_json(self._epoch_meta_path(),
@@ -273,7 +286,7 @@ class CheckpointManager:
                                            sharding=a.sharding),
             state_like)
         restored = self._mngr.restore(
-            epoch, args=ocp.args.StandardRestore(abstract))
+            epoch, args=self._ocp.args.StandardRestore(abstract))
         return TrainState(*restored), epoch + 1
 
     # ------------------------------------------------------------------
@@ -296,10 +309,10 @@ class CheckpointManager:
 
     def _mid_mngr(self):
         if self._mid is None:
-            self._mid = ocp.CheckpointManager(
+            self._mid = self._ocp.CheckpointManager(
                 self._mid_dir(),
-                options=ocp.CheckpointManagerOptions(max_to_keep=1,
-                                                     create=True))
+                options=self._ocp.CheckpointManagerOptions(
+                    max_to_keep=1, create=True))
         return self._mid
 
     def save_mid_epoch(self, epoch: int, step: int, state: TrainState,
@@ -310,7 +323,7 @@ class CheckpointManager:
             raise ValueError(f"step {step} exceeds mid-epoch key space")
         m = self._mid_mngr()
         m.save(epoch * _MID_KEY_BASE + step,
-               args=ocp.args.StandardSave(state))
+               args=self._ocp.args.StandardSave(state))
         m.wait_until_finished()
         meta = {"epoch": epoch, "step": step}
         if data_order:
@@ -341,7 +354,7 @@ class CheckpointManager:
             state_like)
         restored = self._mid_mngr().restore(
             epoch * _MID_KEY_BASE + step,
-            args=ocp.args.StandardRestore(abstract))
+            args=self._ocp.args.StandardRestore(abstract))
         return TrainState(*restored), epoch, step
 
     def clear_mid_epoch(self) -> None:

@@ -2109,7 +2109,7 @@ class Trainer:
                 opt_state=self.state.opt_state._replace(comm=None))
             param_tree = jax.tree.map(
                 lambda a: f"{a.dtype}{list(a.shape)}", digest_state)
-            mngr = CheckpointManager(checkpoint_dir, config={
+            config = {
                 "model": self.model_name, "strategy": self.strategy_name,
                 "compress_rank": self.compress_rank,
                 "seed": self.seed, "precision": self.precision,
@@ -2120,8 +2120,13 @@ class Trainer:
                 "weight_decay": self.sgd_cfg.weight_decay,
                 "limit_train_batches": self.limit_train_batches,
                 "real_data": self.real_data,
-                "state_digest": str(param_tree)},
-                elastic=self.elastic is not None)
+                "state_digest": str(param_tree)}
+            # Building the manager is what imports orbax (seconds: the span
+            # says where a checkpointed start spent them), here and not in
+            # a save: the emergency save must find it loaded.
+            with self.telemetry.span("checkpoint_open"):
+                mngr = CheckpointManager(checkpoint_dir, config=config,
+                                         elastic=self.elastic is not None)
             # Mid-epoch (emergency) checkpoints outrank the epoch series
             # exactly when they are AHEAD of it: the emergency save for
             # epoch k is newer than the epoch k-1 save it coexists with,

@@ -8,12 +8,14 @@ key is fold_in(seed, epoch) and the sampler never reshuffles (C6), so
 """
 
 import numpy as np
+import pytest
 
 import jax
 
 from cs744_ddp_tpu.data import cifar10
 from cs744_ddp_tpu.train.loop import Trainer
 
+from test_import_graph import loaded_after
 from tinynet import tiny_cnn
 
 
@@ -24,10 +26,10 @@ def shrink(tr, n=256):
                                   tr.test_split.labels[:128])
 
 
-def make(tmp_path, mesh):
+def make(tmp_path, mesh, **kw):
     tr = Trainer(model=tiny_cnn(), strategy="ddp", mesh=mesh,
                  global_batch=64, data_dir=str(tmp_path), augment=True,
-                 limit_eval_batches=1, log=lambda s: None)
+                 limit_eval_batches=1, log=lambda s: None, **kw)
     shrink(tr)
     return tr
 
@@ -304,3 +306,37 @@ def test_elastic_config_guard_frees_world_nonelastic_still_rejects(
                   log=lambda s: None)
     with pytest.raises(ValueError, match="different training config"):
         tr3.run(2, checkpoint_dir=ck)
+
+
+_ORBAX_AT_CONSTRUCTION = """
+from cs744_ddp_tpu.train.checkpoint import CheckpointManager
+assert "orbax.checkpoint" not in sys.modules, "loaded by the module import"
+mngr = CheckpointManager(tmp + "/ck")
+assert "orbax.checkpoint" in sys.modules, "not loaded by __init__"
+assert mngr._mid is None and mngr.latest_epoch() is None    # nothing saved
+mngr.close()
+"""
+
+
+def test_orbax_is_imported_by_manager_construction_not_by_a_save(tmp_path):
+    """``save_mid_epoch`` runs in the grace period after SIGTERM: the
+    seconds-long orbax import must be paid when the manager is built, so a
+    save can never be its first importer.  A subprocess: this worker's
+    other tests have loaded orbax already.  Also the probe's own check:
+    it does see orbax once something imports it."""
+    assert "orbax.checkpoint" in loaded_after(_ORBAX_AT_CONSTRUCTION,
+                                              tmp_path)
+
+
+@pytest.mark.parametrize("checkpointed", [True, False])
+def test_checkpoint_open_span_only_when_a_manager_is_built(
+        tmp_path, mesh4, checkpointed):
+    from cs744_ddp_tpu.obs import Telemetry
+    tel = Telemetry()
+    tr = make(tmp_path, mesh4, telemetry=tel)
+    tr.run(1, checkpoint_dir=str(tmp_path / "ck") if checkpointed else None)
+    names = [r["name"] for r in tel.records if r["kind"] == "span"]
+    assert names.count("checkpoint_open") == int(checkpointed)
+    assert names.count("checkpoint_save") == int(checkpointed)
+    if checkpointed:    # opened at the start of run(), before the first step
+        assert names.index("checkpoint_open") < names.index("epoch_train")
