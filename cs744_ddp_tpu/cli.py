@@ -54,10 +54,33 @@ def build_parser() -> argparse.ArgumentParser:
                    help="PowerSGD approximation rank (default 4); only "
                         "meaningful with --strategy powersgd")
     p.add_argument("--model", default="vgg11",
-                   help="vgg11/13/16/19, resnet18/34, or any name "
+                   help="vgg11/13/16/19, resnet18/34, sdar-30b-a3b / sdar-tiny "
+                        "(a block-diffusion MoE decoder: see 'decoder "
+                        "share'), or any name "
                         "registered via models.register_model (validated "
                         "by the model zoo, not argparse, so plugged-in "
                         "models work everywhere the built-ins do)")
+    lm = p.add_argument_group(
+        "decoder share",
+        "what THIS chip holds of --model sdar-30b-a3b (one chip's share of "
+        "SDAR-30B-A3B-Chat at the published widths; sdar-tiny is the CPU "
+        "test size) and the sequences it trains on; defaults: 6 of 48 "
+        "layers, experts 0-15 of 128, 18,992 rows of the vocabulary, 4096 "
+        "tokens in blocks of 4.  Data: <data-dir>/tokens/train.npy + "
+        "heldout.npy ([N, L] int32) or a synthetic stream; an epoch is "
+        "--limit-train-batches steps of the stream")
+    lm.add_argument("--lm-layers", type=int, default=None)
+    lm.add_argument("--lm-experts-held", default=None, metavar="IDS",
+                    help="expert ids held here, e.g. 0-15 or 0,3,5")
+    lm.add_argument("--lm-vocab", type=int, default=None,
+                    help="rows of the embedding and the head held here; "
+                         "the last id is the mask id")
+    lm.add_argument("--lm-seq-len", type=int, default=None)
+    lm.add_argument("--lm-block", type=int, default=None,
+                    help="block length of the diffusion objective")
+    p.add_argument("--init-seed", type=int, default=None,
+                   help="seed of the initial weights where it is not the "
+                        "data's (default: the trainer's one seed)")
     p.add_argument("--batch-size", type=int, default=GLOBAL_BATCH,
                    help="GLOBAL batch (divided across workers, as in the "
                         "reference: Part 2a/main.py:22)")
@@ -316,6 +339,26 @@ def build_parser() -> argparse.ArgumentParser:
                          "certificate; prints a JSON summary, exits 2 "
                          "on any finding")
     return p
+
+
+def model_from_args(args):
+    """--model as the Trainer takes it: the name, or for a decoder given
+    any of its share's flags the (init_fn, apply_fn) pair of that share."""
+    share = {}
+    for flag, field in (("lm_layers", "layers"), ("lm_vocab", "vocab"),
+                        ("lm_seq_len", "seq_len"), ("lm_block", "block")):
+        if getattr(args, flag) is not None:
+            share[field] = getattr(args, flag)
+    if args.lm_experts_held is not None:
+        held = []
+        for part in args.lm_experts_held.split(","):
+            lo, _, hi = part.partition("-")
+            held += range(int(lo), int(hi or lo) + 1)
+        share["held"] = tuple(held)
+    if not share:
+        return args.model
+    from . import models as model_zoo
+    return model_zoo.get_model(args.model, **share)
 
 
 def ft_config_from_args(args) -> "FTConfig | None":
@@ -696,12 +739,13 @@ def main(argv=None):
             telemetry.finalize(global_batch=args.batch_size)
         return
     trainer = Trainer(
-        model=args.model,
+        model=model_from_args(args),
         strategy=args.strategy,
         num_devices=args.num_devices,
         compress_rank=args.compress_rank,
         global_batch=args.batch_size,
         data_dir=args.data_dir,
+        init_seed=args.init_seed,
         augment=not args.no_augment,
         precision=args.precision,
         sgd_cfg=sgd.SGDConfig(lr=args.lr, momentum=args.momentum,

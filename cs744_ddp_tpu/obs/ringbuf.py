@@ -40,28 +40,31 @@ DEFAULT_CAPACITY = 64          # >= WINDOW (20) with slack for ragged tails
 _MARKER_EXACT = float(2 ** 24)  # largest exactly-representable f32 int
 
 
-def make_ring(capacity: int = DEFAULT_CAPACITY):
+def make_ring(capacity: int = DEFAULT_CAPACITY, extras: int = 0):
     """Fresh (buffer, write-counter) pair.  Plain jnp arrays: the caller's
     jit placement (replicated specs in the shard_map builds) commits them;
-    imported lazily so host-only consumers never pull in jax."""
+    imported lazily so host-only consumers never pull in jax.  `extras`
+    widens the row by a model's own per-step scalars (a decoder's routed
+    rows and masked tokens), after the four columns every model has."""
     import jax.numpy as jnp
     if capacity < 1:
         raise ValueError(f"ring capacity must be >= 1, got {capacity}")
-    return (jnp.zeros((capacity, N_METRICS), jnp.float32),
+    return (jnp.zeros((capacity, N_METRICS + extras), jnp.float32),
             jnp.zeros((), jnp.int32))
 
 
 def ring_write(ring, values):
-    """Write one row (a length-``N_METRICS`` tuple of scalars, any real
-    dtype) at the current slot; returns the advanced ring.  Traced inside
-    the scan body — one dynamic-update-slice, no host sync."""
+    """Write one row (a tuple of scalars as wide as the ring's row, any
+    real dtype) at the current slot; returns the advanced ring.  Traced
+    inside the scan body — one dynamic-update-slice, no host sync."""
     import jax.numpy as jnp
     from jax import lax
     buf, count = ring
-    if len(values) != N_METRICS:
-        raise ValueError(f"expected {N_METRICS} metrics, got {len(values)}")
+    width = buf.shape[1]
+    if len(values) != width:
+        raise ValueError(f"expected {width} metrics, got {len(values)}")
     row = jnp.stack([jnp.asarray(v, jnp.float32).reshape(())
-                     for v in values]).reshape(1, N_METRICS)
+                     for v in values]).reshape(1, width)
     slot = lax.rem(count, jnp.int32(buf.shape[0]))
     return (lax.dynamic_update_slice(buf, row, (slot, jnp.int32(0))),
             count + jnp.int32(1))

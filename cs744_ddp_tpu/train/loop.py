@@ -101,7 +101,8 @@ def _eval_batches(split: cifar10.Split, global_batch: int
         labs = split.labels[start:start + global_batch]
         if len(labs) < global_batch:
             pad = global_batch - len(labs)
-            imgs = np.concatenate([imgs, np.zeros((pad, 32, 32, 3), np.uint8)])
+            imgs = np.concatenate(
+                [imgs, np.zeros((pad,) + imgs.shape[1:], imgs.dtype)])
             labs = np.concatenate([labs, np.full((pad,), -1, np.int32)])
         yield imgs, labs
 
@@ -135,7 +136,8 @@ class Trainer:
                  *, mesh=None, num_devices: Optional[int] = None,
                  compress_rank: Optional[int] = None,
                  global_batch: int = GLOBAL_BATCH, data_dir: str = "./data",
-                 seed: int = SEED, augment: bool = True,
+                 seed: int = SEED, init_seed: Optional[int] = None,
+                 augment: bool = True,
                  sgd_cfg: sgd.SGDConfig = sgd.SGDConfig(),
                  profile_phases: bool = False,
                  host_augment: bool = False,
@@ -195,6 +197,10 @@ class Trainer:
             jnp.bfloat16 if precision == "bf16" else None)
         self.augment = augment
         self.seed = seed
+        # The initial weights' seed, where it is not the data's: `seed`
+        # then draws the batches, the augmentation / noising and nothing
+        # else (a benchmark cell pins the weights and varies the data).
+        self.init_seed = seed if init_seed is None else init_seed
         # The reference never reshuffles across epochs (no sampler.set_epoch
         # call — SURVEY.md C6); opt in for proper per-epoch reshuffling.
         self.reshuffle_each_epoch = reshuffle_each_epoch
@@ -307,7 +313,36 @@ class Trainer:
         # stale device arrays).  Must exist before the property assignments.
         self._train_gen = 0
         self._test_gen = 0
-        self.train_split, self.test_split, self.real_data = cifar10.load(data_dir)
+        # `model` is a registry name ("vgg11", "resnet18", ...) or a custom
+        # (init_fn, apply_fn) pair (used by tests to keep compiles small).
+        if isinstance(model, str):
+            self.model_name = model
+            init_fn, self.apply_fn = model_zoo.get_model(model)
+        else:
+            self.model_name = "custom"
+            init_fn, self.apply_fn = model
+        # What the model trains on (train/step.py `ImageObjective`, or the
+        # model's own: a decoder's token ids and masked loss), through the
+        # same default path either way: staged epochs, scanned windows, the
+        # ring, the eval window.  A stream of tokens has only that path.
+        self.objective = steplib.objective_of(self.apply_fn)
+        if self.objective.stream:
+            unsupported = [name for name, on in (
+                ("host_augment", host_augment),
+                ("profile_phases", profile_phases),
+                ("elastic", elastic is not None),
+                ("nonfinite guard", self._guard_on)) if on]
+            if unsupported:
+                raise ValueError(
+                    f"model {self.model_name!r} trains on the default "
+                    f"windowed path only; not with {unsupported}")
+            from ..data import tokens
+            self.train_split, self.test_split, self.real_data = tokens.load(
+                data_dir, self.objective.seq_len, self.objective.vocab - 1,
+                seed)
+        else:
+            self.train_split, self.test_split, self.real_data = \
+                cifar10.load(data_dir)
         # Reference parity: these lines print len(train_loader) — the
         # per-rank BATCH count, not the example count (Part 2a/main.py:46,55).
         def ceil_div(a, b):
@@ -327,14 +362,6 @@ class Trainer:
         self.log(f"Size of test set is "
                  f"{ceil_div(len(self.test_split.labels), per_rank_batch)}")
 
-        # `model` is a registry name ("vgg11", "resnet18", ...) or a custom
-        # (init_fn, apply_fn) pair (used by tests to keep compiles small).
-        if isinstance(model, str):
-            self.model_name = model
-            init_fn, self.apply_fn = model_zoo.get_model(model)
-        else:
-            self.model_name = "custom"
-            init_fn, self.apply_fn = model
         self.strategy_name = strategy
         self.sgd_cfg = sgd_cfg
         # compress_rank only parameterizes the powersgd tier; None defers
@@ -344,7 +371,7 @@ class Trainer:
             strategy, **({} if compress_rank is None
                          else {"compress_rank": compress_rank}))
         self.state = steplib.init_train_state(
-            init_fn, jax.random.PRNGKey(seed), strat, self.world)
+            init_fn, jax.random.PRNGKey(self.init_seed), strat, self.world)
         # Commit the state to the mesh up front: otherwise the first
         # windowed call sees uncommitted arrays and the second call a
         # different sharding signature -> a full recompile.  Everything is
@@ -436,6 +463,7 @@ class Trainer:
         self._warmed_tail_shapes = set()
         self._warmed_window_shapes = set()
         self.last_epoch_timers: Optional[WindowedTimers] = None
+        self.last_epoch_extras: dict = {}   # an objective's totals, by name
         self._collective_stats_emitted = False
         self._span_epoch: Optional[int] = None  # of the last train_model
 
@@ -542,8 +570,9 @@ class Trainer:
         from ..analysis import stats as hlo_stats
         try:
             x = jax.ShapeDtypeStruct(
-                (self.global_batch, 32, 32, 3),
-                jnp.float32 if self.host_augment else jnp.uint8,
+                (self.global_batch,) + self.objective.example_shape,
+                jnp.float32 if self.host_augment
+                else self.objective.example_dtype,
                 sharding=self._batch_sharding)
             y = jax.ShapeDtypeStruct((self.global_batch,), jnp.int32,
                                      sharding=self._batch_sharding)
@@ -589,15 +618,19 @@ class Trainer:
         from call one."""
         rep = meshlib.replicated(self.mesh)
         return (meshlib.put_global(
-                    np.zeros((self.metrics_ring, ringbuf.N_METRICS),
+                    np.zeros((self.metrics_ring, self._ring_width()),
                              np.float32), rep),
                 meshlib.put_global(np.zeros((), np.int32), rep))
+
+    def _ring_width(self) -> int:
+        """The four columns every model writes, plus its objective's own."""
+        return ringbuf.N_METRICS + len(self.objective.extras)
 
     def _ring_sds(self):
         """ShapeDtypeStructs of the ring pair, for AOT warmup lowers."""
         rep = meshlib.replicated(self.mesh)
         return (jax.ShapeDtypeStruct(
-                    (self.metrics_ring, ringbuf.N_METRICS), jnp.float32,
+                    (self.metrics_ring, self._ring_width()), jnp.float32,
                     sharding=rep),
                 jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
 
@@ -620,15 +653,45 @@ class Trainer:
         buffer — the ONE round-trip happened inside the timed span."""
         rows = ringbuf.drain_rows(buf_host, writes_total, w)
         losses, gsq, oks, steps = ringbuf.split_columns(rows)
+        names = [name for name, _ in self.objective.extras]
+        extras = rows[:, ringbuf.N_METRICS:]
+        if names:
+            self._tally_extras(extras, epoch)
         if self.telemetry.enabled:
-            for l, g, s in zip(losses, gsq, steps):
+            for i, (l, g, s) in enumerate(zip(losses, gsq, steps)):
                 timers.record(float(l), per_iter,
                               extra={"grad_sqnorm": float(g),
-                                     "step_index": int(s)})
+                                     "step_index": int(s),
+                                     **dict(zip(names, map(float,
+                                                           extras[i])))})
         else:
             for l in losses:
                 timers.record(float(l), per_iter)
         return oks
+
+    def _tally_extras(self, extras: np.ndarray, epoch: int) -> None:
+        """A drained window's objective columns into the epoch's totals
+        (`last_epoch_extras`: each combined over the steps as the
+        objective says its shards combine, a sum or the largest) and, with
+        a recorder, the sums into counters beside `dispatches` and
+        `host_round_trips` (a decoder's `moe_rows_local`, `tokens_masked`),
+        as are the objective's constants an example (`per_example`: the
+        `moe_rows_expected` that even routing would have sent here)."""
+        tot = self.last_epoch_extras
+        for j, (name, how) in enumerate(self.objective.extras):
+            col = extras[:, j]
+            if how == "max":
+                tot[name] = max(tot.get(name, 0.0), float(col.max()))
+            else:
+                tot[name] = tot.get(name, 0.0) + float(col.sum())
+                if self.telemetry.enabled:
+                    self.telemetry.counter(name, float(col.sum()),
+                                           epoch=epoch)
+        for name, each in self.objective.per_example.items():
+            n = each * self.global_batch * len(extras)
+            tot[name] = tot.get(name, 0.0) + n
+            if self.telemetry.enabled:
+                self.telemetry.counter(name, n, epoch=epoch)
 
     # -- fault tolerance (ft/) ----------------------------------------------
 
@@ -829,6 +892,8 @@ class Trainer:
         (bumped by the train_split setter) and (when reshuffling) the epoch,
         so replacing ``train_split`` or enabling reshuffle restages.
         """
+        if self.objective.stream:           # every epoch its own sequences
+            return self._stage_token_epoch(epoch)
         cache_key = (self._train_gen,
                      epoch if self.reshuffle_each_epoch else 0)
         if self._staged_train is not None and \
@@ -864,6 +929,39 @@ class Trainer:
                         np.zeros((0, self.global_batch), np.int32),
                         self._epoch_sharding))
         staged = (full[0], full[1], tail)
+        self._staged_train = (cache_key, staged)
+        return staged
+
+    def _token_epoch_batches(self) -> int:
+        """Full batches in an epoch of the token stream: as many as
+        `limit_train_batches` says (a stream has no length of its own),
+        else the file's sequences, else the synthetic stand-in's 64."""
+        if self.limit_train_batches is not None:
+            return self.limit_train_batches
+        nb = len(self.train_split) // self.global_batch
+        if nb < 1:
+            raise ValueError(
+                f"{len(self.train_split)} sequences are less than one "
+                f"global batch of {self.global_batch}")
+        return nb
+
+    def _stage_token_epoch(self, epoch: int):
+        """Epoch `epoch` of the token stream on the device, [NB, B, L]
+        int32: its own sequences (`TokenSplit.epoch`), so nothing recurs
+        within a run and the staging is inside every epoch, as it is for a
+        reshuffled image epoch.  Full batches only: no ragged tail.  The
+        second array is the image path's labels' place: 0 per sequence."""
+        cache_key = (self._train_gen, epoch)
+        if self._staged_train is not None and \
+                self._staged_train[0] == cache_key:
+            return self._staged_train[1]
+        nb = self._token_epoch_batches()
+        toks = self.train_split.epoch(epoch, nb * self.global_batch).reshape(
+            nb, self.global_batch, self.objective.seq_len)
+        staged = (meshlib.put_global(toks, self._epoch_sharding),
+                  meshlib.put_global(
+                      np.zeros((nb, self.global_batch), np.int32),
+                      self._epoch_sharding), None)
         self._staged_train = (cache_key, staged)
         return staged
 
@@ -977,6 +1075,7 @@ class Trainer:
         """
         self._epoch_nf_skipped = 0
         self._epoch_nf_restored = 0
+        self.last_epoch_extras = {}
         timers = self._train_model_impl(epoch, start_step)
         if self._guard_on and (self._epoch_nf_skipped
                                or self._epoch_nf_restored):
@@ -1941,9 +2040,9 @@ class Trainer:
                 out = self.eval_window(self.state, images, labels)
             self._count_dispatch("eval")
             with span("eval_fetch"):
-                # Both values in ONE fetch, inside the span so it covers
+                # All values in ONE fetch, inside the span so it covers
                 # real device work: the evaluation's one round trip.
-                loss_sum, corr = jax.device_get(out)
+                loss_sum, corr, *counted = jax.device_get(out)
             self._count_round_trip("eval")
         n = len(self.test_split.labels)
         if self.limit_eval_batches is not None:
@@ -1953,13 +2052,16 @@ class Trainer:
         # (equal when batches are full; exact even on the ragged tail).
         avg_loss = float(loss_sum) / n
         correct = int(corr)
-        acc = 100.0 * correct / n
+        # A decoder's "correct" is out of the masked tokens it predicted,
+        # which its eval window counts; an image model's out of the images.
+        total = int(counted[0]) if counted else n
+        acc = 100.0 * correct / max(total, 1)
         if self.telemetry.enabled:
             self.telemetry.gauge("eval", {"avg_loss": avg_loss,
-                                          "correct": correct, "total": n,
-                                          "accuracy": correct / n})
+                                          "correct": correct, "total": total,
+                                          "accuracy": correct / max(total, 1)})
         self.log("Test set: Average loss: {:.4f}, Accuracy: {}/{} ({:.0f}%)\n"
-                 .format(avg_loss, correct, n, acc))
+                 .format(avg_loss, correct, total, acc))
         return avg_loss, correct, acc
 
     def _elastic_meta(self, epoch: int) -> dict:

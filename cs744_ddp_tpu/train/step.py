@@ -69,20 +69,83 @@ def _prepare(augment, key, images):
     return aug.augment(key, images) if augment else aug.normalize(images)
 
 
+class ImageObjective:
+    """What a model trains on, as the compiled steps below call it; this
+    one is every image model's: crop/flip + normalize, cross-entropy, argmax
+    counts.  A model that trains on something else brings its own
+    (`apply_fn.objective`, models/sdar.py: token ids in, the masked
+    block-diffusion loss), with the same methods and attributes.  What a
+    configuration IS decides; no option switches it."""
+
+    extras = ()             # per-step scalars besides the loss: (name, how
+                            # shards and then steps combine: "sum" / "max")
+    per_example = {}        # constants an example, tallied beside them
+    eval_key = 0            # the evaluation draws nothing
+    eval_dtypes = (jnp.float32, jnp.int32)      # loss sum, correct
+    example_shape, example_dtype = (32, 32, 3), jnp.uint8
+    stream = False          # one staged set, reused every epoch
+    # Does the shard_map of this model's programs run the varying-axes
+    # check?  (The note at the top.)
+    checks_vma = True
+
+    def prepare(self, key, images, augment, compute_dtype):
+        return maybe_cast(_prepare(augment, key, images), compute_dtype)
+
+    def loss(self, apply_fn, params, bn_state, x, labels, compute_dtype):
+        """(loss, (new_bn, extras))."""
+        logits, new_bn = apply_fn(params, bn_state, x, train=True)
+        return cross_entropy(logits, labels), (new_bn, ())
+
+    def eval_counts(self, apply_fn, params, bn_state, key, images, labels,
+                    compute_dtype):
+        x = maybe_cast(aug.normalize(images), compute_dtype)
+        logits, _ = apply_fn(params, bn_state, x, train=False)
+        return masked_eval_counts(logits, labels)
+
+
+IMAGES = ImageObjective()
+
+
+def objective_of(apply_fn):
+    return getattr(apply_fn, "objective", IMAGES)
+
+
 def fold_and_prepare(augment, compute_dtype, key, images, *, idx=None,
-                     fold_axis=True):
+                     fold_axis=True, objective=IMAGES):
     """The ONE definition of the train input path's PRNG fold order and
     transform: fold the batch index first (when the caller passes one —
     the per-step path folds it on the host instead), the mesh position
-    second, then prepare + cast.  Shared by the fused step, the train
-    window and the forward-only window so the streams cannot drift apart
-    (the phase split's validity depends on the forward window consuming
-    bit-identical inputs to the train window)."""
+    second, then the objective's own transform (`IMAGES`: prepare + cast;
+    a decoder's: its noising, drawn from the same key).  Shared by the
+    fused step, the train window and the forward-only window so the
+    streams cannot drift apart (the phase split's validity depends on the
+    forward window consuming bit-identical inputs to the train window)."""
     if idx is not None:
         key = jax.random.fold_in(key, idx)
     if fold_axis:
         key = jax.random.fold_in(key, lax.axis_index(DATA_AXIS))
-    return maybe_cast(_prepare(augment, key, images), compute_dtype)
+    return objective.prepare(key, images, augment, compute_dtype)
+
+
+def _vary(objective):
+    """`pvary` where the varying-axes check runs.  A decoder's programs opt
+    out of it (`checks_vma = False`): its Pallas kernels (ops/attention.py,
+    ops/moe.py) are library `pallas_call`s whose outputs carry no `vma`,
+    which the check refuses, and marking those outputs varying by hand
+    would be worse than no check: `pvary` transposes to a psum, so the
+    backward pass would all-reduce every kernel's cotangent.  Without the
+    check `pvary` is not needed: in-body jax.grad of the replicated params
+    is shard-local (no auto-psum), and the strategy stays the only gradient
+    reduction (tests/test_sdar.py trains on two devices against the plain
+    reference)."""
+    return pvary if objective.checks_vma else (lambda x: x)
+
+
+def reduce_extras(objective, extras):
+    """Combine the shards' extras over the data axis, each as it says."""
+    ops = {"sum": lax.psum, "max": lax.pmax}
+    return tuple(ops[how](e, DATA_AXIS)
+                 for (_, how), e in zip(objective.extras, extras))
 
 
 class TrainState(NamedTuple):
@@ -175,6 +238,7 @@ def make_train_step(apply_fn: Callable, strategy: parallel.strategies.Strategy,
     shard_map or any axis: a plain jitted step, the degenerate world-size-1
     case, exactly as Part 1 carries no torch.distributed code.
     """
+    objective = objective_of(apply_fn)
     if strategy is parallel.strategies.local:
         if mesh.devices.size != 1:
             raise ValueError("'single' strategy requires a 1-device mesh "
@@ -183,13 +247,13 @@ def make_train_step(apply_fn: Callable, strategy: parallel.strategies.Strategy,
         @jax.jit
         def single_step(state: TrainState, key, images, labels):
             x = fold_and_prepare(augment, compute_dtype, key, images,
-                                 fold_axis=False)
+                                 fold_axis=False, objective=objective)
 
             def loss_fn(p):
-                logits, new_bn = apply_fn(p, state.bn_state, x, train=True)
-                return cross_entropy(logits, labels), new_bn
+                return objective.loss(apply_fn, p, state.bn_state, x, labels,
+                                      compute_dtype)
 
-            (loss, new_bn), grads = jax.value_and_grad(
+            (loss, (new_bn, _)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params)
             if inject_nonfinite:
                 grads = ftguard.inject_nan(grads)
@@ -207,11 +271,12 @@ def make_train_step(apply_fn: Callable, strategy: parallel.strategies.Strategy,
     def shard_body(params, bn_state, opt_state, key, images, labels):
         # Distinct augmentation stream per shard, deterministic in (key, pos);
         # the batch index is folded on the host by the per-step caller.
-        x = fold_and_prepare(augment, compute_dtype, key, images)
+        x = fold_and_prepare(augment, compute_dtype, key, images,
+                             objective=objective)
 
         def loss_fn(p):
-            logits, new_bn = apply_fn(p, bn_state, x, train=True)
-            return cross_entropy(logits, labels), new_bn
+            return objective.loss(apply_fn, p, bn_state, x, labels,
+                                  compute_dtype)
 
         # Differentiate w.r.t. a device-VARYING view of the replicated
         # params: shard_map autodiff auto-psums the cotangent of an
@@ -220,8 +285,8 @@ def make_train_step(apply_fn: Callable, strategy: parallel.strategies.Strategy,
         # double-counting by a factor of world.  pcast-to-varying keeps the
         # grads genuinely shard-local so the strategy below is the ONLY
         # gradient reduction — its collective pattern, exactly once.
-        params_var = jax.tree.map(pvary, params)
-        (loss, new_bn), grads = jax.value_and_grad(
+        params_var = jax.tree.map(_vary(objective), params)
+        (loss, (new_bn, _)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params_var)
         if inject_nonfinite:
             # Poison BEFORE the gradient sync — a real overflow is born on
@@ -246,7 +311,7 @@ def make_train_step(apply_fn: Callable, strategy: parallel.strategies.Strategy,
     mapped = shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(), P(), opt_spec, P(), P(DATA_AXIS), P(DATA_AXIS)),
-        out_specs=out_specs,
+        out_specs=out_specs, check_vma=objective.checks_vma,
     )
 
     if nonfinite_guard:
@@ -268,16 +333,18 @@ def make_train_step(apply_fn: Callable, strategy: parallel.strategies.Strategy,
     return step
 
 
-def _ring_row(buf, cnt, loss, grads, ok, idx):
+def _ring_row(buf, cnt, loss, grads, ok, idx, extras=()):
     """Append one (loss, grad sqnorm, ok, step marker) row to the metric
     ring inside the scanned body (obs/ringbuf.py).  The sqnorm is computed
     on the POST-sync grads, so the write is replicated and the ring can
     carry a replicated out-spec; the loss value is the same tensor the
-    non-ring path stacks into ys — observation only, bitwise-inert."""
+    non-ring path stacks into ys — observation only, bitwise-inert.
+    `extras`: an objective's per-step scalars, in the wider row its
+    trainer allocates."""
     from ..obs import ringbuf
     gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
               for g in jax.tree.leaves(grads))
-    return ringbuf.ring_write((buf, cnt), (loss, gsq, ok, idx))
+    return ringbuf.ring_write((buf, cnt), (loss, gsq, ok, idx) + extras)
 
 
 def make_train_window(apply_fn: Callable,
@@ -322,6 +389,7 @@ def make_train_window(apply_fn: Callable,
     window instead of fetching stacked ys — same loss values, one fetch.
     """
     chaos_steps = tuple(int(s) for s in nonfinite_chaos_steps)
+    objective = objective_of(apply_fn)
 
     def scan_one(apply_fn, strategy_fn, axis_ok):
         def one(carry, xs):
@@ -336,18 +404,19 @@ def make_train_window(apply_fn: Callable,
             # and the position in make_train_step, so the windowed and
             # per-step paths consume identical augmentation streams.
             x = fold_and_prepare(augment, compute_dtype, key, images,
-                                 idx=idx, fold_axis=axis_ok)
+                                 idx=idx, fold_axis=axis_ok,
+                                 objective=objective)
 
             def loss_fn(p):
-                logits, new_bn = apply_fn(p, bn_state, x, train=True)
-                return cross_entropy(logits, labels), new_bn
+                return objective.loss(apply_fn, p, bn_state, x, labels,
+                                      compute_dtype)
 
             # See make_train_step: differentiate w.r.t. a varying view so
             # the strategy is the only gradient reduction (no autodiff
             # psum of invariant-param cotangents double-counting it).
             diff_params = params if not axis_ok else jax.tree.map(
-                pvary, params)
-            (loss, new_bn), grads = jax.value_and_grad(
+                _vary(objective), params)
+            (loss, (new_bn, extras)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(diff_params)
             if chaos_steps:
                 mask = (idx == chaos_steps[0])
@@ -360,18 +429,21 @@ def make_train_window(apply_fn: Callable,
                 new_bn = jax.tree.map(
                     lambda a: lax.pmean(a, DATA_AXIS), new_bn)
                 loss = lax.pmean(loss, DATA_AXIS)
+                if extras:
+                    extras = reduce_extras(objective, extras)
             if nonfinite_guard:
                 p, bn, opt, ok = _guarded_update(
                     params, bn_state, opt_state, grads, cfg, loss, new_bn,
                     staged_opt=staged_opt)
                 if metrics_ring:
-                    buf, cnt = _ring_row(buf, cnt, loss, grads, ok, idx)
+                    buf, cnt = _ring_row(buf, cnt, loss, grads, ok, idx,
+                                         extras)
                     return (p, bn, opt, key, buf, cnt), None
                 return (p, bn, opt, key), (loss, ok)
             new_params, new_opt = sgd.update(params, grads, staged_opt, cfg)
             if metrics_ring:
                 buf, cnt = _ring_row(buf, cnt, loss, grads,
-                                     jnp.float32(1.0), idx)
+                                     jnp.float32(1.0), idx, extras)
                 return (new_params, new_bn, new_opt, key, buf, cnt), None
             return (new_params, new_bn, new_opt, key), loss
         return one
@@ -446,6 +518,7 @@ def make_train_window(apply_fn: Callable,
             in_specs=(P(), P(), opt_spec, P(), P(), P(),
                       P(None, DATA_AXIS), P(None, DATA_AXIS), P(), P()),
             out_specs=(P(), P(), opt_spec, P(), P()),
+            check_vma=objective.checks_vma,
         )
 
         @partial(jax.jit, donate_argnums=(0, 1))
@@ -464,7 +537,7 @@ def make_train_window(apply_fn: Callable,
         window_body, mesh=mesh,
         in_specs=(P(), P(), opt_spec, P(), P(None, DATA_AXIS),
                   P(None, DATA_AXIS), P(), P()),
-        out_specs=out_specs,
+        out_specs=out_specs, check_vma=objective.checks_vma,
     )
 
     @partial(jax.jit, donate_argnums=(0,))
@@ -552,31 +625,38 @@ def masked_eval_counts(logits: jax.Array, labels: jax.Array):
 def make_eval_window(apply_fn: Callable, mesh: Mesh, *,
                      compute_dtype=None) -> Callable:
     """Whole-test-set evaluation in ONE dispatch: scan over [T,B,...] staged
-    batches, psum counts across the mesh.  Returns (loss_sum, correct)
-    over all valid (label >= 0) examples."""
+    batches, psum counts across the mesh.  Returns the objective's counts
+    over all valid (label >= 0) examples: (loss_sum, correct) for images; a
+    decoder, evaluated on draws from its fixed key, adds what `correct` is
+    out of (masked tokens)."""
+    objective = objective_of(apply_fn)
 
     def scan_eval(params, bn_state, images, labels):
         def one(carry, xs):
-            imgs, labs = xs
-            x = maybe_cast(aug.normalize(imgs), compute_dtype)
-            logits, _ = apply_fn(params, bn_state, x, train=False)
-            loss_sum, correct = masked_eval_counts(logits, labs)
-            l, c = carry
-            return (l + loss_sum, c + correct), None
+            imgs, labs, t = xs
+            key = jax.random.fold_in(jax.random.fold_in(
+                jax.random.PRNGKey(objective.eval_key), t),
+                lax.axis_index(DATA_AXIS))
+            counts = objective.eval_counts(apply_fn, params, bn_state, key,
+                                           imgs, labs, compute_dtype)
+            return tuple(a + b for a, b in zip(carry, counts)), None
         # Initial carry must already be marked device-varying (each shard
         # accumulates its own partial sums) for shard_map's VMA typing.
-        init = (pvary(jnp.float32(0.0)), pvary(jnp.int32(0)))
-        (loss_sum, correct), _ = lax.scan(one, init, (images, labels))
-        return loss_sum, correct
+        vary = _vary(objective)
+        init = tuple(vary(jnp.zeros((), dt)) for dt in objective.eval_dtypes)
+        steps = jnp.arange(images.shape[0], dtype=jnp.int32)
+        counts, _ = lax.scan(one, init, (images, labels, steps))
+        return counts
 
     def shard_body(params, bn_state, images, labels):
-        loss_sum, correct = scan_eval(params, bn_state, images, labels)
-        return (lax.psum(loss_sum, DATA_AXIS), lax.psum(correct, DATA_AXIS))
+        return tuple(lax.psum(c, DATA_AXIS)
+                     for c in scan_eval(params, bn_state, images, labels))
 
     mapped = shard_map(shard_body, mesh=mesh,
                        in_specs=(P(), P(), P(None, DATA_AXIS),
                                  P(None, DATA_AXIS)),
-                       out_specs=(P(), P()))
+                       out_specs=(P(),) * len(objective.eval_dtypes),
+                       check_vma=objective.checks_vma)
 
     @jax.jit
     def evaluate(state: TrainState, images, labels):

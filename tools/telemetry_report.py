@@ -466,6 +466,31 @@ LOOP_SPANS = ("epoch_train", "stage_lookup", "ring_alloc", "train_window",
 SLOW_SPAN_MS = 30.0
 
 
+def _moe_lines(events) -> list:
+    """A decoder's expert layer, per epoch: the rows this chip's experts
+    computed against what even routing would have sent here, and the
+    masked tokens its loss was taken on (train/loop.py `_tally_extras`)."""
+    per = {}
+    for e in events:
+        if e.get("kind") == "counter" and e.get("name") in (
+                "moe_rows_local", "moe_rows_expected", "tokens_masked"):
+            row = per.setdefault(e.get("epoch"), {})
+            row[e["name"]] = row.get(e["name"], 0) + e.get("inc", 0)
+    if not per:
+        return []
+    lines = ["== moe (per epoch) =="]
+    for epoch in sorted(per, key=lambda x: (x is None, x)):
+        row = per[epoch]
+        rows, exp = row.get("moe_rows_local", 0), \
+            row.get("moe_rows_expected", 0)
+        share = f"{rows / exp:.4f}" if exp else "n/a"
+        lines.append(f"  epoch {epoch}: rows here {rows:,.0f} of "
+                     f"{exp:,.0f} expected (share {share}), masked tokens "
+                     f"{row.get('tokens_masked', 0):,.0f}")
+    lines.append("")
+    return lines
+
+
 def _loop_lines(events) -> list:
     """Dispatch-loop rendering: per span name of the default windowed path
     its count, median, longest and total per epoch, then every span that
@@ -591,6 +616,7 @@ def render(out_dir: str) -> str:
         lines.append("")
 
     lines.extend(_loop_lines(events))
+    lines.extend(_moe_lines(events))
     lines.extend(_wire_ext_lines(events))
 
     lines.extend(_serving_lines(events))
