@@ -114,7 +114,8 @@ def init_params(key, shape: Shape):
 
 def _layer(shape: Shape, kernels: bool, x, p, positions):
     """One sequence through one layer: x [P, H] -> (x, rows computed by
-    this chip's experts, rows of its fullest expert), P = 2L."""
+    this chip's experts, rows of its fullest expert, rows of the dropless
+    buffer the expert layer touched), P = 2L."""
     s = shape
     P = x.shape[0]
     h = rmsnorm(x, p["ln1"], s.eps)
@@ -135,7 +136,7 @@ def _layer(shape: Shape, kernels: bool, x, p, positions):
     y, rows, fullest = moe.expert_layer(
         h, p, held=s.held, num_experts=s.num_experts, top_k=s.top_k,
         kernels=kernels)
-    return x + y, rows, fullest
+    return x + y, rows, fullest, moe.prefix_rows(rows, P * s.top_k, kernels)
 
 
 def make(shape: Shape = Shape(), kernels=None):
@@ -154,7 +155,7 @@ def make(shape: Shape = Shape(), kernels=None):
         """x: (xt [S, L], x0 [S, L]) token ids.  Returns (hidden [S, L, H]
         of the noisy half after the final norm, {}, (rows computed by this
         chip's experts summed over layers, rows of the fullest held expert
-        of any layer))."""
+        of any layer, rows of the dropless buffers touched for them))."""
         del train                       # no dropout, no batch statistics
         on_tpu = jax.default_backend() == "tpu" if kernels is None \
             else kernels
@@ -172,11 +173,12 @@ def make(shape: Shape = Shape(), kernels=None):
             # input) is one sequence's, not the step's.
             one = jax.checkpoint(
                 lambda x_seq: _layer(s, on_tpu, x_seq, p, positions))
-            x, rows, fullest = lax.map(one, x)
-            return x, (jnp.sum(rows), jnp.max(fullest))
-        h, (rows, fullest) = lax.scan(layer, h, params["layers"])
+            x, rows, fullest, touched = lax.map(one, x)
+            return x, (jnp.sum(rows), jnp.max(fullest), jnp.sum(touched))
+        h, (rows, fullest, touched) = lax.scan(layer, h, params["layers"])
         hidden = rmsnorm(h[:, :s.seq_len], params["final_norm"], s.eps)
-        return hidden, bn_state, (jnp.sum(rows), jnp.max(fullest))
+        return hidden, bn_state, (jnp.sum(rows), jnp.max(fullest),
+                                  jnp.sum(touched))
 
     apply_fn.objective = BlockDiffusion(s)
     return init_fn, apply_fn
@@ -194,7 +196,7 @@ class BlockDiffusion:
     steps of an epoch, combine."""
 
     extras = (("moe_rows_local", "sum"), ("moe_rows_max_expert", "max"),
-              ("tokens_masked", "sum"))
+              ("tokens_masked", "sum"), ("moe_rows_touched", "sum"))
     eval_key = EVAL_KEY
     eval_dtypes = (jnp.float32, jnp.int32, jnp.int32)   # + masked tokens
     example_dtype = jnp.int32
@@ -218,22 +220,23 @@ class BlockDiffusion:
         return {"xt": xt, "x0": tokens, "masked": masked, "weight": weight}
 
     def _counts(self, apply_fn, params, bn_state, x, compute_dtype):
-        hidden, new_bn, (rows, fullest) = apply_fn(
+        hidden, new_bn, routed = apply_fn(
             params, bn_state, (x["xt"], x["x0"]), train=True,
             compute_dtype=compute_dtype)
         per_seq = losslib.blockdiff_head_counts(
             hidden, params["head"], x["x0"], x["masked"], x["weight"])
-        return per_seq, new_bn, (rows, fullest)
+        return per_seq, new_bn, routed
 
     def loss(self, apply_fn, params, bn_state, x, labels=None,
              compute_dtype=None):
         """-> (mean over the sequences of the per-sequence loss,
         (new_bn, extras)).  `labels`: the image path's, unused (a
         sequence's targets are its own tokens)."""
-        (loss, _, count), new_bn, (rows, fullest) = self._counts(
+        (loss, _, count), new_bn, (rows, fullest, touched) = self._counts(
             apply_fn, params, bn_state, x, compute_dtype)
         extras = (rows.astype(jnp.float32), fullest.astype(jnp.float32),
-                  jnp.sum(count).astype(jnp.float32))
+                  jnp.sum(count).astype(jnp.float32),
+                  touched.astype(jnp.float32))
         return jnp.mean(loss), (new_bn, extras)
 
     def eval_counts(self, apply_fn, params, bn_state, key, tokens, labels,

@@ -189,6 +189,77 @@ def test_grouped_matmul_kernel_path_matches_the_plain_one_interpreted():
     assert all(close(a, b, 5e-2) for a, b in zip(flat1, flat0))
 
 
+@pytest.mark.parametrize("kernels", [False, True], ids=["plain", "pallas"])
+@pytest.mark.parametrize("where", ["none", "rung", "rung+1", "all"])
+def test_every_rung_of_the_ladder_gives_what_the_whole_buffer_gives(
+        monkeypatch, where, kernels):
+    """The layer on the prefix its routed count picks against the layer on
+    the whole buffer (a ladder of the top rung alone), for counts of 0 rows,
+    exactly a rung's, a rung's + 1 and all positions x top_k: output,
+    counts, every gradient, and the rung.  The kernel path runs
+    interpreted, so rows past the live ones hold NaN."""
+    positions, k = (256, 2) if kernels else (64, 2)
+    n = positions * k
+    if kernels:
+        monkeypatch.setattr(moe, "GMM_TILING", (128, 128, 128))
+    rungs = moe.ladder(n, kernels)
+    assert rungs[-1] == n and len(rungs) >= 3
+    live = {"none": 0, "rung": rungs[1], "rung+1": rungs[1] + 1, "all": n}[
+        where]
+    want = {"none": rungs[0], "rung": rungs[1], "rung+1": rungs[2],
+            "all": n}[where]
+    p = layer_params(jax.random.PRNGKey(6), hidden=128 if kernels else 64,
+                     width=128 if kernels else 32)
+    hidden = p["router"].shape[0]
+    h = jax.random.normal(jax.random.PRNGKey(7), (positions, hidden))
+    a, b = live - live // 2, live // 2
+    ids = np.stack([np.where(np.arange(positions) < a, 2, 0),
+                    np.where(np.arange(positions) < b, 5, 1)], 1)
+    real_route = moe.route
+
+    def planted_route(h, w_router, top_k):
+        """The router's weights on planted ids: the first `a` positions'
+        first choice is held expert 2, the first `b` positions' second is
+        held expert 5, every other choice an absent expert's."""
+        _, w = real_route(h, w_router, top_k)
+        return jnp.asarray(ids, jnp.int32), w
+    monkeypatch.setattr(moe, "route", planted_route)
+    sh = share_of(p, (2, 5))
+
+    def run():
+        def f(sh, h):
+            out, rows, fullest = moe.expert_layer(
+                h, sh, held=(2, 5), num_experts=8, top_k=k, kernels=kernels,
+                interpret=kernels)
+            return jnp.sum(jnp.sin(out)), (out, rows, fullest)
+        (_, aux), g = jax.value_and_grad(f, (0, 1), has_aux=True)(sh, h)
+        return aux, g
+    (out, rows, fullest), grads = run()
+    assert int(moe.prefix_rows(rows, n, kernels)) == want
+    monkeypatch.setattr(moe, "LADDER", (1,))
+    assert moe.ladder(n, kernels) == (n,)
+    (out_top, rows_top, fullest_top), grads_top = run()
+    assert int(rows) == int(rows_top) == live
+    assert int(fullest) == int(fullest_top) == a
+    # float32 rounding; on the kernel path a last bit of float32 becomes
+    # a last bit of the bfloat16 a cotangent is rounded to on its way
+    tol = 2.0 ** -8 if kernels else 1e-6
+    assert np.all(np.isfinite(out)) and close(out, out_top, 1e-6)
+    flat, flat_top = jax.tree.leaves(grads), jax.tree.leaves(grads_top)
+    assert len(flat) == 5       # h, router, w_gate, w_up, w_down
+    assert all(np.all(np.isfinite(g)) for g in flat)
+    assert all(close(g, t, tol) for g, t in zip(flat, flat_top))
+
+
+def test_expert_layer_refuses_a_held_expert_the_router_does_not_score():
+    p = layer_params(jax.random.PRNGKey(0))
+    h = jnp.ones((8, 64), jnp.float32)
+    for held, experts in (((8,), 8), ((0, 1), 16)):
+        with pytest.raises(ValueError, match="num_experts"):
+            moe.expert_layer(h, share_of(p, (0,)), held=held,
+                             num_experts=experts, top_k=2, kernels=False)
+
+
 # -- the model and its objective against the plain reference -------------------
 
 def program_and_reference(seed=0):
@@ -245,8 +316,8 @@ def test_loss_and_every_gradient_leaf_match_the_reference():
     rflat = dict(jax.tree_util.tree_flatten_with_path(rgrads)[0])
     for k, g in flat:
         assert close(g, rflat[k], 2e-4), jax.tree_util.keystr(k)
-    rows, fullest, count = (float(e) for e in extras)
-    assert count == float(jnp.sum(masked)) and 0 < fullest <= rows
+    rows, fullest, count, touched = (float(e) for e in extras)
+    assert count == float(jnp.sum(masked)) and 0 < fullest <= rows <= touched
 
 
 def write_tokens(root, train, heldout):
@@ -286,6 +357,12 @@ def test_three_sgd_steps_and_test_model_through_trainer_match_the_reference(
     assert totals["moe_rows_local"] == tr.last_epoch_extras["moe_rows_local"]
     assert totals["moe_rows_expected"] == 3 * b * 2 * 32 * 2 * 2 * 2 / 8
     assert totals["tokens_masked"] > 0
+    # the rungs the expert layers ran on: at least the live rows, at most
+    # positions x top_k rows a layer of a sequence
+    touched = tr.last_epoch_extras["moe_rows_touched"]
+    assert totals["moe_rows_touched"] == touched
+    assert totals["moe_rows_local"] <= touched <= 3 * b * 2 * 32 * 2 * 2
+    assert touched % (2 * 32 * 2 // 4) == 0          # whole rungs of N/4
     rows = [r for r in tel.records if r["kind"] == "counter"
             and r["name"] == "moe_rows_local"]
     assert rows and all(r["epoch"] == 0 for r in rows)

@@ -474,3 +474,28 @@ def test_telemetry_report_renders_loop_section(tmp_path, monkeypatch):
     assert "stage_lookup" in text.split("== loop")[1]
     assert telemetry_report._loop_lines(events[-1:]) == []
     assert telemetry_report._loop_lines([]) == []
+
+
+def test_telemetry_report_renders_moe_section(monkeypatch):
+    """``== moe ==``: per epoch the rows routed here against the even share,
+    and the rows of the dropless buffer touched over them; "n/a" for a run
+    recorded before the program counted the touched rows."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(os.path.join(repo, "tools"))
+    import telemetry_report
+    counter = lambda name, inc, epoch: {"kind": "counter", "name": name,
+                                        "inc": inc, "epoch": epoch}
+    events = [counter("moe_rows_local", 9000.0, 0),
+              counter("moe_rows_expected", 8192.0, 0),
+              counter("moe_rows_touched", 16384.0, 0),
+              counter("tokens_masked", 2560.0, 0),
+              counter("moe_rows_local", 8000.0, 1),
+              counter("moe_rows_expected", 8192.0, 1)]
+    lines = telemetry_report._moe_lines(events)
+    assert lines[0] == "== moe (per epoch) =="
+    assert lines[1] == ("  epoch 0: rows here 9,000 of 8,192 expected (share "
+                        "1.0986), buffer rows touched 16,384 (touched / live "
+                        "1.820), masked tokens 2,560")
+    assert "touched / live n/a" in lines[2]
+    assert telemetry_report._moe_lines(events[3:4]) != []
+    assert telemetry_report._moe_lines([]) == []

@@ -468,12 +468,15 @@ SLOW_SPAN_MS = 30.0
 
 def _moe_lines(events) -> list:
     """A decoder's expert layer, per epoch: the rows this chip's experts
-    computed against what even routing would have sent here, and the
-    masked tokens its loss was taken on (train/loop.py `_tally_extras`)."""
+    computed against what even routing would have sent here, the rows of
+    the dropless buffer they were computed on (the prefix ops/moe.py chose)
+    over them, and the masked tokens its loss was taken on (train/loop.py
+    `_tally_extras`)."""
     per = {}
     for e in events:
         if e.get("kind") == "counter" and e.get("name") in (
-                "moe_rows_local", "moe_rows_expected", "tokens_masked"):
+                "moe_rows_local", "moe_rows_expected", "moe_rows_touched",
+                "tokens_masked"):
             row = per.setdefault(e.get("epoch"), {})
             row[e["name"]] = row.get(e["name"], 0) + e.get("inc", 0)
     if not per:
@@ -484,9 +487,12 @@ def _moe_lines(events) -> list:
         rows, exp = row.get("moe_rows_local", 0), \
             row.get("moe_rows_expected", 0)
         share = f"{rows / exp:.4f}" if exp else "n/a"
+        touched = row.get("moe_rows_touched", 0)
+        over = f"{touched / rows:.3f}" if touched and rows else "n/a"
         lines.append(f"  epoch {epoch}: rows here {rows:,.0f} of "
-                     f"{exp:,.0f} expected (share {share}), masked tokens "
-                     f"{row.get('tokens_masked', 0):,.0f}")
+                     f"{exp:,.0f} expected (share {share}), buffer rows "
+                     f"touched {touched:,.0f} (touched / live {over}), "
+                     f"masked tokens {row.get('tokens_masked', 0):,.0f}")
     lines.append("")
     return lines
 
