@@ -45,7 +45,7 @@ OUT = os.path.join(ROOT, "chiprun_out", "chip_smoke")
 
 MODEL = "vgg11"
 GLOBAL_BATCH = 256
-# bench.py's ``stable_lr`` control, not the reference's 0.1: on the chip, 45
+# The benchmark configurations' lr, not the reference's 0.1: on the chip, 45
 # steps at 0.1 spike the first window (mean loss 10.8) and then sit at chance
 # (2.305 = ln 10, eval 10.6%), so "last window below first" passes for the
 # wrong reason; at 0.01 the loss falls 2.64 -> 1.69 and eval reaches 36%

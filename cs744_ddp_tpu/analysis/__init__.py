@@ -13,8 +13,8 @@ Three layers, all import-light (jax only where a rule needs a jaxpr):
 - ``audit``      — a rule engine certifying each shipped program's cost
                    shape (collective contract per strategy, dtype leaks,
                    donation misses, host syncs in loop bodies, oversized
-                   baked constants) wired into ``cli.py --audit``, bench's
-                   ``audit`` section and the telemetry manifest.
+                   baked constants) wired into ``cli.py --audit`` and the
+                   telemetry manifest.
 - ``pylint_rules`` — AST lint for repo invariants the runtime can't see
                    (un-fenced timing, jnp on producer threads, lock
                    ownership); ``tools/lint_graft.py`` is the CLI.

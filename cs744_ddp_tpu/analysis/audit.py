@@ -3,8 +3,9 @@
 The paper's subject IS the cost structure of each gradient-sync tier —
 gather→scatter pays two chained collectives per leaf with world-x traffic,
 per-param all-reduce one per leaf, bucketed DDP one per ~25 MB bucket —
-and until now that structure was only *reported* (bench ``spectrum``),
-never *checked*.  This module audits the pre-optimization HLO (via the
+and until now that structure was only *reported*
+(``tools/bench_strategy_spectrum.py``), never *checked*.  This module
+audits the pre-optimization HLO (via the
 :mod:`analysis.hlo_ir` graph IR) plus the jaxpr of each shipped program
 against a declared :class:`ProgramContract`, so a regression in comms
 shape, precision, buffer donation or host syncs fails CI before any
@@ -61,7 +62,7 @@ tests/test_analysis.py):
   full-size f32 copy per request), and a missing u8->float convert
   means the program isn't consuming the wire bytes it claims to.
 
-Waiver syntax (CLI ``--audit-waive``, bench, tests): ``RULE`` waives a
+Waiver syntax (CLI ``--audit-waive``, tests): ``RULE`` waives a
 rule everywhere, ``RULE@GLOB`` only for programs matching the fnmatch
 glob, e.g. ``baked-constants@serve/*``.  Waived findings are still
 reported and recorded in the telemetry manifest, they just don't fail
@@ -521,7 +522,7 @@ class AuditResult:
         return [f for r in self.reports for f in r.waived]
 
     def summary(self) -> Dict:
-        """Manifest/bench-ready record: per-program rule pass/fail +
+        """Manifest-ready record: per-program rule pass/fail +
         waivers, the strategy depth ladder, and every finding message."""
         return {
             "clean": self.clean,
@@ -837,7 +838,7 @@ def audit_serving(*, model: str = "vgg11",
     and fused-ingest certified (``ingest-edge``: uint8 images at the
     program edge, normalize in-program, no float image inputs).
     Pass ``engine`` to audit an already-built :class:`InferenceEngine`
-    (the bench serving section does); otherwise one is built without
+    (tests/test_publish.py does); otherwise one is built without
     staging or caches.  ``hlo_out`` (a dict) collects each rung's
     lowering text under its program name for cost-model attribution.
 

@@ -25,9 +25,8 @@ so a :class:`CostReport` over such a program is per-device; multiply by
 the mesh size for machine totals.
 
 This module is the single source of truth for the repo's analytic
-FLOP/MFU arithmetic: ``bench._mfu_fields``, ``obs/attribution.py``,
-``tools/perf_attribution.py`` and ``tools/perf_stage_roofline.py`` all
-delegate here (ISSUE 8 consolidation).
+FLOP/MFU arithmetic: ``obs/attribution.py`` delegates here (ISSUE 8
+consolidation).
 """
 
 from __future__ import annotations
@@ -40,9 +39,9 @@ from typing import Dict, List, Optional, Tuple
 from . import hlo_ir, stats
 
 # v5e datasheet numbers shared by every MFU/roofline consumer in the repo.
-# analysis/memlife (the peak-HBM certifier) and analysis/megaplan (the
-# K-epoch planner) read the capacity from HERE — tools/lint_graft.py's
-# path-less run fails if any of these literals grows a second copy.
+# analysis/memlife (the peak-HBM certifier) reads the capacity from HERE —
+# tools/lint_graft.py's path-less run fails if any of these literals grows
+# a second copy.
 V5E_BF16_PEAK_FLOPS = 197e12     # bf16 peak, per chip
 V5E_HBM_BYTES_PER_S = 819e9     # HBM bandwidth, per chip
 V5E_ICI_BYTES_PER_S = 200e9     # 1600 Gbit/s ICI, per chip per direction
@@ -52,7 +51,7 @@ V5E_HBM_CAPACITY_BYTES = 16 * 2**30   # HBM capacity, per chip
 # it: the table is keyed by jax's ``device_kind`` string, and a device that
 # is not in it gets no utilization field (a CPU-mesh run once filed
 # ``mfu_vs_bf16_peak: 0.0`` against the v5e peak).  The static certificates
-# (memlife, megaplan, the audit budget) name their v5e target outright and
+# (memlife, the audit budget) name their v5e target outright and
 # keep using the constants above.
 PEAK_BF16_FLOPS_BY_DEVICE_KIND = {"TPU v5 lite": V5E_BF16_PEAK_FLOPS}
 
@@ -85,8 +84,7 @@ def mfu_fields(ips_per_chip: float, flops_per_image: Optional[float],
     """Achieved TFLOP/s + model-flops-utilization fields for a per-chip
     image rate measured on a ``device_kind`` device.  Returns ``{}`` when
     the analytic flop count is unavailable, and no ``mfu_vs_bf16_peak``
-    for a device outside the peak table — absent keys, never null values
-    (bench head contract)."""
+    for a device outside the peak table — absent keys, never null values."""
     if not flops_per_image:
         return {}
     tflops = ips_per_chip * flops_per_image / 1e12

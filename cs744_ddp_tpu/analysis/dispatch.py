@@ -4,8 +4,7 @@ The ring buffer (round 8) made per-epoch host round-trips a COUNTED
 quantity (``host_round_trips`` telemetry counter, CI-pinned), but the
 pin is only as good as the run that produced it.  This module derives
 the same number STATICALLY — a closed form over the lowered programs'
-scan trip counts and the trainer's dispatch structure — so the K-epoch
-mega-program (ROADMAP item 3) can be designed against a compile-time
+scan trip counts and the trainer's dispatch structure — a compile-time
 certificate instead of a runtime observation.
 
 The dispatch structure being certified (train/loop.py):
@@ -96,19 +95,6 @@ def epoch_round_trip_bound(path: str, nbatches: int, window: int = 0, *,
     else:
         raise ValueError(f"unknown dispatch path {path!r}")
     return trips + (1 if tail_batch else 0) + (1 if include_eval else 0)
-
-
-def mega_round_trip_bound(k_epochs: int, *, include_eval: bool = True) -> int:
-    """Closed-form host round-trips for a K-epoch MEGA-program (ROADMAP
-    item 3): the whole run is ONE dispatch whose ring drain is the single
-    fetch, plus the final eval fetch when the run evals on device.  The
-    windowed baseline pays ``k_epochs x epoch_round_trip_bound(...)``;
-    this is the O(1) the mega-program buys, and
-    :func:`megaplan.plan_k_epochs` certifies how large K can grow before
-    HBM takes it back."""
-    if k_epochs <= 0:
-        return 0
-    return 1 + (1 if include_eval else 0)
 
 
 @dataclass

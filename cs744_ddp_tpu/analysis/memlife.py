@@ -415,6 +415,56 @@ def check_against_compiled(report: MemReport, mem_stats, *,
 
 
 # ---------------------------------------------------------------------------
+# The real train window, lowered for the certifier (needs jax)
+# ---------------------------------------------------------------------------
+
+def lower_window(model: str = "vgg11", *, world: int = 8,
+                 window: int = 4, global_batch: int = 256):
+    """Lower THE ``ddp`` train window with its metric ring, as the Trainer
+    builds it (the same recipe the audit zoo uses); returns
+    ``(lowered, name)`` so callers can take the HLO text for the static
+    certifier AND ``.compile()`` it for the differential check.  Requires
+    jax; lowering is abstract (eval_shape), no parameters materialize."""
+    import jax
+
+    from . import audit
+    from ..models import get_model
+    from ..obs import ringbuf
+    from ..ops import sgd
+    from ..parallel import get_strategy, mesh as meshlib
+    from ..train import step as steplib
+
+    mesh = meshlib.make_mesh(world)
+    w = mesh.devices.size
+    b = max(w, (global_batch // w) * w)
+    strat = get_strategy("ddp" if w > 1 else "single")
+    init_fn, apply_fn = get_model(model)
+    st_sds = jax.eval_shape(
+        lambda k: steplib.init_train_state(init_fn, k, strat, w),
+        jax.random.PRNGKey(0))
+    sds = audit._train_sds(mesh, st_sds, b, window,
+                           ring_capacity=ringbuf.DEFAULT_CAPACITY)
+    fn = steplib.make_train_window(
+        apply_fn, strat, mesh, sgd.SGDConfig(), augment=True,
+        metrics_ring=True)
+    lowered = fn.lower(sds["state"], sds["ring"], sds["key"],
+                       sds["epoch_images"], sds["epoch_labels"],
+                       sds["start"], sds["lengths"])
+    return lowered, f"train/window/ddp@w{w}/{model}"
+
+
+def window_mem_report(model: str = "vgg11", *, world: int = 8,
+                      window: int = 4, global_batch: int = 256
+                      ) -> MemReport:
+    """Lower the train window and run the liveness certifier over it."""
+    from . import audit
+
+    lowered, name = lower_window(
+        model, world=world, window=window, global_batch=global_batch)
+    return mem_report(audit._hlo_text(lowered), name)
+
+
+# ---------------------------------------------------------------------------
 # jax-free repo self-checks (tools/lint_graft.py path-less run)
 # ---------------------------------------------------------------------------
 
@@ -427,7 +477,6 @@ _HW_CHECKER = os.path.join("cs744_ddp_tpu", "analysis", "memlife.py")
 _CAPACITY_ASSIGN_RE = re.compile(r"^\s*V5E_HBM_CAPACITY_BYTES\s*=",
                                  re.MULTILINE)
 _SCAN_DIRS = ("cs744_ddp_tpu", "tools")
-_SCAN_FILES = ("bench.py",)
 
 #: Committed fixture pair proving the donation delta in bytes: identical
 #: windowed programs, one donating its carried state, one not.
@@ -443,10 +492,6 @@ def _py_files(repo_root: str):
             for fn in names:
                 if fn.endswith(".py"):
                     yield os.path.join(dirpath, fn)
-    for fn in _SCAN_FILES:
-        path = os.path.join(repo_root, fn)
-        if os.path.exists(path):
-            yield path
 
 
 def check_constants_single_source(repo_root: str) -> List[LintFinding]:

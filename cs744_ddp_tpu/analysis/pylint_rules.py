@@ -61,15 +61,14 @@ import re
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
-DEFAULT_TARGETS = ("cs744_ddp_tpu", "tools", "bench.py")
+DEFAULT_TARGETS = ("cs744_ddp_tpu", "tools")
 
 # Calls that put work on an accelerator queue and return before it runs.
 # ``infer_counts_async`` is the serving pipeline's explicit issue half:
 # timing it without its ``complete`` fence measures enqueue, not service.
 DISPATCH_NAMES = frozenset({
     "train_window", "train_step", "train_window_host", "train_step_host",
-    "eval_window", "fwd_window", "infer", "infer_counts",
-    "infer_counts_async"})
+    "eval_window", "infer", "infer_counts", "infer_counts_async"})
 # Calls/conversions that synchronize host and device.  ``complete`` is
 # the pipeline's completion fence (engine.complete(handle) blocks until
 # the dispatched program finished).

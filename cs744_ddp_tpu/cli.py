@@ -679,9 +679,9 @@ def main(argv=None):
     telemetry files: the ``Trainer`` after a training run, the output
     record after ``--serve-frontend``; None for the other modes."""
     args = build_parser().parse_args(argv)
-    # Persistent XLA compilation cache, unconditionally (previously only
-    # bench/tests opted in): repeated CLI runs of the same config skip
-    # multi-second XLA compiles; hit/miss counts land in the manifest.
+    # Persistent XLA compilation cache, unconditionally: repeated CLI runs
+    # of the same config skip multi-second XLA compiles; hit/miss counts
+    # land in the manifest.
     compcache.enable_persistent_compilation_cache()
     if args.require_real_data:
         from .data import cifar10

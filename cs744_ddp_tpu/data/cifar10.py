@@ -9,7 +9,7 @@ network egress, so:
     kept NHWC uint8 — the TPU-friendly layout);
   * otherwise a *deterministic, learnable* synthetic stand-in with the same
     shapes/dtypes/cardinalities (50k train / 10k test, 32x32x3 uint8,
-    10 classes) is generated, so every train/eval/bench path exercises the
+    10 classes) is generated, so every train/eval path exercises the
     real pipeline.
 
 Channel normalization stats match the reference exactly
@@ -102,8 +102,8 @@ def _synthetic_split(n: int, seed: int) -> Split:
     broken step (stuck at chance) and a degenerate task (instant 100%).
 
     Memoized: generating the full 50k split costs ~4 s of pure numpy, and
-    multi-trainer processes (bench sections, the elastic coordinator's
-    shrink/resume ladder) would otherwise pay it per Trainer.  The cached
+    multi-trainer processes (the elastic coordinator's shrink/resume
+    ladder, the test suite) would otherwise pay it per Trainer.  The cached
     arrays are shared across callers and therefore read-only; consumers
     that need to mutate must copy."""
     rng = np.random.default_rng(seed)
@@ -125,9 +125,8 @@ def _synthetic_split(n: int, seed: int) -> Split:
 
 def has_real_data(data_dir: str = "./data") -> bool:
     """Would ``load`` find the real python-pickle batches here?  The ONE
-    check both ``--require-real-data`` surfaces (cli.py, bench.py) share
-    with the loader, so the flag can never disagree with what ``load``
-    actually does."""
+    check ``cli.py --require-real-data`` shares with the loader, so the
+    flag can never disagree with what ``load`` actually does."""
     return os.path.isdir(os.path.join(data_dir, "cifar-10-batches-py"))
 
 

@@ -20,8 +20,8 @@ from .supervisor import (StagingStalled, Watchdog, batch_checksums,
 
 
 class FTConfig(NamedTuple):
-    """Fault-tolerance knobs (defaults are production-shaped; tests and the
-    bench robustness section shrink the timeouts).
+    """Fault-tolerance knobs (defaults are production-shaped; tests shrink
+    the timeouts).
 
     nonfinite         : "off" | "halt" | "skip" | "restore" step-guard policy.
     chaos             : ChaosPlan (or NULL_CHAOS) of deterministic injections.
@@ -37,7 +37,7 @@ class FTConfig(NamedTuple):
     verify_chunks     : crc32-verify staged rows right before each put
                         (auto-enabled when the chaos plan corrupts slots).
     degrade_staging   : start in the degraded synchronous staging mode
-                        (bench/testing knob — measures the fallback).
+                        (testing knob — exercises the fallback).
     slow_rank_stall_s : stall injected per ``slow_rank`` chaos entry and
                         attributed to the target rank's step-time gauge
                         (elastic/straggler.py must flag it).

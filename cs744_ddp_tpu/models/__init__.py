@@ -5,7 +5,7 @@ imported only when asked for)."""
 from . import resnet, vgg
 
 # User-registered factories (name -> () -> (init_fn, apply_fn)); lets tests
-# and downstream users plug models into the CLI/bench without editing here.
+# and downstream users plug models into the CLI without editing here.
 _CUSTOM = {}
 
 
@@ -15,7 +15,7 @@ def register_model(name: str, factory) -> None:
 
 
 def get_model(name: str, **share):
-    """Return (init_fn, apply_fn) for a model name used by the CLI/bench.
+    """Return (init_fn, apply_fn) for a model name used by the CLI.
 
     `share` (decoder models only): fields of ``sdar.Shape`` that say what
     this chip holds (layers, held, vocab) and the sequence (seq_len, block).

@@ -27,7 +27,8 @@ A run directory holds three files: ``manifest.json`` (the run header,
 written once at trainer construction), ``events.jsonl`` (one JSON object per
 line, append-only), and ``summary.json`` (steady-state percentiles, written
 by ``finalize()``).  Construct with ``out_dir=None`` for an in-memory
-recorder (bench sections) — same API, events kept in ``.records``.
+recorder (tests, the benchmark's traced run) — same API, events kept in
+``.records``.
 
 The DISABLED path is ``NULL``: a stateless singleton whose methods do
 nothing and whose ``span()`` returns a shared no-op context manager, so a

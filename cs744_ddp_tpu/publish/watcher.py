@@ -20,11 +20,8 @@ stage, and swap — between dispatches, never during one.
    when no dispatch is in flight, so a batch never sees torn weights
    and every reply's ``model_version`` tag is exact.
 
-Rolling vs all-at-once: with ``rolling=True`` (default) replicas are
-swapped one at a time, each install awaited before the next is queued,
-so serving capacity never drops to zero; ``rolling=False`` queues every
-replica's flip at once (each still lands at that replica's own dispatch
-boundary) — the bench's ``run_hotswap`` section measures both.
+Rolling: replicas are swapped one at a time, each install awaited
+before the next is queued, so serving capacity never drops to zero.
 
 The ``swap_mid_batch`` chaos site calls ``poll_once(wait=False)`` from
 INSIDE a dispatch hook (via ``EngineReplica.swap_probe``).  That path
@@ -55,7 +52,7 @@ class WeightWatcher:
                    "_swap_ms", "_thread", "_stop")
 
     def __init__(self, directory: str, replicas: Sequence, *,
-                 telemetry=None, chaos=NULL_CHAOS, rolling: bool = True,
+                 telemetry=None, chaos=NULL_CHAOS,
                  poll_interval_s: float = 0.05,
                  install_timeout_s: float = 30.0,
                  attach_probes: bool = True):
@@ -63,7 +60,6 @@ class WeightWatcher:
         self.replicas = list(replicas)
         self.telemetry = telemetry if telemetry is not None else NULL
         self.chaos = chaos
-        self.rolling = bool(rolling)
         self.poll_interval_s = float(poll_interval_s)
         self.install_timeout_s = float(install_timeout_s)
         self._lock = threading.Lock()
@@ -173,7 +169,6 @@ class WeightWatcher:
                      wait: bool) -> str:
         import jax
 
-        futures = []
         for r in self.replicas:
             eng = r.engine
             # Unflatten with the ENGINE's treedef object (the bundle's
@@ -192,12 +187,7 @@ class WeightWatcher:
 
             t0 = time.perf_counter()
             fut = r.scheduler.request_install(flip)
-            futures.append((r, t0, fut))
-            if wait and self.rolling:
-                self._await_locked(r, t0, fut)
-                futures.pop()
-        if wait:
-            for r, t0, fut in futures:
+            if wait:
                 self._await_locked(r, t0, fut)
         # The version is claimed as installed once every flip is queued:
         # each scheduler runs it at its next boundary (or inline at

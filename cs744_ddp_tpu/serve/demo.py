@@ -1,7 +1,7 @@
 """Seeded synthetic request traces + the open-loop serving demo driver.
 
-The serving numbers (bench.py ``serving`` section, ``cli.py
---serve-demo``) come from replaying a DETERMINISTIC trace: Poisson
+The serving numbers (``cli.py --serve-demo``, ``--serve-frontend``,
+``tools/serve_load.py``) come from replaying a DETERMINISTIC trace: Poisson
 arrivals at a configured offered load, request sizes drawn from a fixed
 mixture skewed toward small requests (the shape batched serving exists
 for), images sampled from the synthetic CIFAR stand-in.  Open loop:
@@ -10,26 +10,19 @@ completion (offered load is the independent variable; queueing shows up
 in latency, not in a throttled arrival rate).  The driver records
 client-side latency (submit -> result) plus its own scheduling lag so a
 saturated single-core host cannot silently masquerade as a fast server.
-
-``python -m cs744_ddp_tpu.serve.demo --startup-probe ...`` prints one
-JSON line with the engine startup report — bench.py runs it twice in
-fresh subprocesses (same cache dirs) to measure COLD vs WARM startup
-honestly, outside any in-process jit cache.
 """
 
 from __future__ import annotations
 
-import json
 import time
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..data import cifar10
-from ..obs import Telemetry
 from ..obs.telemetry import percentile
 from .batcher import MicroBatcher, QueueFull
-from .engine import BUCKETS, InferenceEngine
+from .engine import InferenceEngine
 
 # Request-size mixture: mostly singletons and small groups, occasional
 # bulk requests — uniform over this tuple (seeded), mean ~8 images.
@@ -237,56 +230,3 @@ def run_demo(engine: InferenceEngine, *, n_requests: int = 200,
 
 def parse_buckets(spec: str) -> Tuple[int, ...]:
     return tuple(sorted({int(b) for b in spec.split(",") if b.strip()}))
-
-
-def startup_probe(model: str, *, buckets=BUCKETS, precisions=("f32",),
-                  cache_dir: Optional[str] = None, seed: int = 0,
-                  telemetry=None) -> dict:
-    """Build the ladder once and report the startup timing sheet."""
-    engine = InferenceEngine(model, buckets=buckets, precisions=precisions,
-                             cache_dir=cache_dir, seed=seed,
-                             telemetry=telemetry or Telemetry())
-    return engine.startup()
-
-
-def main(argv=None) -> int:
-    import argparse
-    p = argparse.ArgumentParser("serve.demo")
-    p.add_argument("--startup-probe", action="store_true",
-                   help="build the executable ladder, print the startup "
-                        "timing report as one JSON line, exit (bench.py "
-                        "runs this twice in fresh subprocesses for the "
-                        "cold/warm startup metric)")
-    p.add_argument("--model", default="vgg11")
-    p.add_argument("--buckets", default=",".join(map(str, BUCKETS)))
-    p.add_argument("--precisions", default="f32",
-                   help="comma list from {f32, bf16}")
-    p.add_argument("--cache-dir", default=None,
-                   help="executable-cache directory (warm start)")
-    p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--requests", type=int, default=200)
-    p.add_argument("--load", type=float, default=20.0,
-                   help="offered load, requests/sec (open loop)")
-    p.add_argument("--max-wait-ms", type=float, default=5.0)
-    args = p.parse_args(argv)
-
-    buckets = parse_buckets(args.buckets)
-    precisions = tuple(args.precisions.split(","))
-    tel = Telemetry()
-    engine = InferenceEngine(args.model, buckets=buckets,
-                             precisions=precisions,
-                             cache_dir=args.cache_dir, seed=args.seed,
-                             telemetry=tel)
-    report = engine.startup()
-    if args.startup_probe:
-        print(json.dumps(report))
-        return 0
-    stats = run_demo(engine, n_requests=args.requests,
-                     offered_rps=args.load, seed=args.seed,
-                     max_wait_ms=args.max_wait_ms)
-    print(json.dumps({"startup": report, "demo": stats}))
-    return 0
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

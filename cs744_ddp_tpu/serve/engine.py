@@ -19,8 +19,9 @@ design: uint8 in, the normalize fused into the XLA program
 
 Warm start: executables are looked up in a ``serve.cache.ExecutableCache``
 before compiling (and saved after), on top of the repo-wide persistent XLA
-compilation cache — cold vs warm startup seconds are a reported metric
-(``bench.py`` serving section), not an anecdote.
+compilation cache — cold vs warm startup seconds are in every
+``startup()`` report (``--serve-frontend`` prints it per replica), not an
+anecdote.
 """
 
 from __future__ import annotations
@@ -65,7 +66,7 @@ class InferenceEngine:
 
     ``state`` is a ``TrainState`` (or any object with ``params`` /
     ``bn_state``) — typically restored from a training checkpoint; when
-    omitted the model is seed-initialized (the demo/bench mode, where
+    omitted the model is seed-initialized (the demo mode, where
     latency is the subject and weights are irrelevant).
     """
 
@@ -223,8 +224,9 @@ class InferenceEngine:
 
     def startup(self) -> dict:
         """Build the whole ladder (cache-load or AOT-compile every
-        (bucket, precision) executable); returns the timing report the
-        bench's cold/warm startup metric is made of."""
+        (bucket, precision) executable); returns the timing report
+        (seconds and source, ``cache`` or compiled, per rung; ``warm`` when
+        every rung came from the cache)."""
         import jax
 
         t0 = time.time()

@@ -31,7 +31,7 @@ can return OUT OF ORDER — clients match on ``req_id``.
 ``submit(images, labels=None, *, tier, slo_ms) -> Future[Reply]`` and
 raising ``QueueFull`` — an ``SLOScheduler``, a ``ReplicaRouter``, or a
 stub.  ``FrontendClient`` (socket) and ``LoopbackClient`` (in-process,
-same reply dicts) are the two client shapes tests/bench drive.
+same reply dicts) are the two client shapes.
 """
 
 from __future__ import annotations
@@ -540,8 +540,8 @@ class FrontendClient:
 
 class LoopbackClient:
     """In-process client with the same submit/reply-dict surface as
-    ``FrontendClient`` — what bench and the demo replay drive when no
-    socket is wanted.  Overload is returned as a reply dict (like the
+    ``FrontendClient`` — what the tests drive when no socket is
+    wanted.  Overload is returned as a reply dict (like the
     wire does), not raised."""
 
     def __init__(self, backend, *, telemetry=None):

@@ -23,7 +23,7 @@ clocks, no locks); ``SLOScheduler`` is the thin threaded shell that runs
 it against a real ``InferenceEngine``.  ``plan_continuous`` /
 ``plan_drain`` replay the same policy (and the old drain policy) in
 virtual time over a seeded arrival trace — the deterministic substrate
-for the continuous-vs-drain comparison in bench and tests.
+for the continuous-vs-drain comparison in the tests.
 
 Dispatch pipeline (round 14): with ``pipeline=True`` (the default for
 engines exposing ``infer_counts_async``/``complete``) the worker keeps up
@@ -461,7 +461,7 @@ class SLOScheduler:
     ``pipeline`` selects the double-buffered worker (module docstring):
     ``None`` auto-enables it when the engine exposes the async dispatch
     API (``infer_counts_async``/``complete``); ``False`` forces the
-    serial round-13 worker (the bench A/B baseline and the path engine
+    serial round-13 worker (``--serve-pipeline off``, and the path engine
     stubs exercise).  ``complete_hook(dispatch_no, bucket)`` runs at each
     dispatch's COMPLETION point; an exception it raises (the
     ``dispatch_fault`` chaos site) is isolated to that one batch —

@@ -178,11 +178,10 @@ class Trainer:
         # K sub-window chunks put_global'd individually by the producer, so
         # window w+1's transfers overlap window w's device compute (round 6;
         # the round-5 path shipped ONE blocking whole-window put and left
-        # the host->device link idle during compute — BASELINE.md pinned
-        # that 21% short of target).  K=1 degrades exactly to round 5's
+        # the host->device link idle during compute).  K=1 degrades exactly to round 5's
         # whole-window staging; default 4 keeps chunks ~5 batches (~3.8 MiB
         # at B=256) — deep enough to overlap, coarse enough that per-put
-        # fixed costs stay amortized (bench.py chunk_sweep measures K).
+        # fixed costs stay amortized.
         if host_chunks < 1:
             raise ValueError(f"host_chunks must be >= 1, got {host_chunks}")
         self.host_chunks = int(host_chunks)
@@ -397,9 +396,7 @@ class Trainer:
                 microshards=elastic.microshards, augment=augment,
                 compute_dtype=compute_dtype)
         # Ring variants of the windowed programs (built alongside, compiled
-        # lazily): same math, ys swapped for the donated device ring.  The
-        # non-ring train_window stays built either way — bench's phase
-        # split and throughput probes dispatch it directly.
+        # lazily): same math, ys swapped for the donated device ring.
         self.train_window_ring = None
         self.train_window_host_ring = None
         if self.metrics_ring:
@@ -459,7 +456,6 @@ class Trainer:
         self._staging_put_copies = None     # backend aliasing probe result
         self._staged_train = None   # (epoch_images, epoch_labels, tail)
         self._staged_eval = None
-        self._fwd_window = None     # built lazily by measure_phase_split
         self._warmed_tail_shapes = set()
         self._warmed_window_shapes = set()
         self.last_epoch_timers: Optional[WindowedTimers] = None
@@ -996,10 +992,7 @@ class Trainer:
         """AOT-compile the 20-iteration window shapes train_model will
         dispatch (full WINDOW and the ragged window) so mid-epoch compiles
         never pollute the timers — the windowed analogue of the reference's
-        first-window warmup exclusion.  Called from train_model, NOT from
-        staging: the bench path stages epochs but dispatches epoch-length
-        windows (whose compile lands in its own excluded warmup window), and
-        would pay these compiles dead.  Idempotent per shape."""
+        first-window warmup exclusion.  Idempotent per shape."""
         epoch_images, epoch_labels, _ = staged
         nbatches = epoch_images.shape[0]
         key = jax.random.PRNGKey(self.seed)
@@ -1025,9 +1018,7 @@ class Trainer:
 
     def _warm_tail_step(self, tail) -> None:
         """AOT-compile the tail-shape train step (idempotent per shape) so
-        the ragged batch's compile never lands inside a timed iteration.
-        Deliberately NOT done at staging time: the bench path stages epochs
-        but never trains the tail, and would pay a dead compile."""
+        the ragged batch's compile never lands inside a timed iteration."""
         cache_key = (tail[0].shape[0], str(tail[0].dtype))
         if cache_key in self._warmed_tail_shapes:
             return
@@ -1148,8 +1139,7 @@ class Trainer:
             w = min(WINDOW - start % WINDOW, nbatches - start)
             t0 = clock()
             # The span is tagged with the gradient-sync strategy so the
-            # telemetry timeline attributes window wall time per tier
-            # (the compressed-collective bench reads these back).
+            # telemetry timeline attributes window wall time per tier.
             with (tel.span("train_window", epoch=epoch,
                            strategy=self.strategy_name, start=int(start),
                            batches=int(w)) if on else NULL_SPAN):
@@ -1561,8 +1551,7 @@ class Trainer:
         """Chunked, double-buffered windowed host-augment pipeline (round
         6).  Round 5 staged each window as ONE blocking whole-window
         ``put_global``: the host->device link idled while the previous
-        window computed, and BASELINE.md pinned the path 21% short of its
-        target naming exactly this lever.  Here the producer thread fills
+        window computed.  Here the producer thread fills
         chunk-aligned arena rows via the FUSED C++ gather+augment
         (``native.gather_augment_u8`` — straight from the resident dataset
         into the staging row, collapsing the former gather -> augment ->
@@ -2366,222 +2355,3 @@ class Trainer:
                 self._preempt_guard = None
             if mngr is not None:
                 mngr.close()
-
-    # -- benchmarking -------------------------------------------------------
-
-    def step_flops_per_image(self, log: Optional[Callable[[str], None]] = None
-                             ) -> Optional[float]:
-        """FLOPs per trained image, from XLA's cost model of the compiled
-        per-batch train step (augment + fwd + bwd + sync + SGD — everything
-        the step really runs).  None when the backend offers no cost
-        analysis — the reason is logged (``log`` overrides the trainer's
-        logger, which bench.py suppresses for the print schedule).
-        Used by bench.py for tflops/MFU accounting.
-
-        ``cost_analysis()`` reports the PER-DEVICE SPMD partition, which
-        processes global_batch/world images — so the divisor is the
-        per-device batch, not the global batch (verified on the 8-virtual-
-        device mesh: per-device flops are ~world x smaller than the
-        1-device program's for the same global batch)."""
-        log = log or self.log
-        x = jax.ShapeDtypeStruct((self.global_batch, 32, 32, 3), jnp.uint8,
-                                 sharding=self._batch_sharding)
-        y = jax.ShapeDtypeStruct((self.global_batch,), jnp.int32,
-                                 sharding=self._batch_sharding)
-        # Compile errors propagate: this is the same program the trainer
-        # runs, so a failure here is a real bug, not a missing cost model.
-        comp = self.train_step.lower(
-            self.state, jax.random.PRNGKey(0), x, y).compile()
-        try:
-            ca = comp.cost_analysis()
-        except (NotImplementedError, RuntimeError) as e:
-            # RuntimeError covers XlaRuntimeError(UNIMPLEMENTED) — the
-            # backends-without-cost-analysis case.  Say why MFU is absent
-            # instead of silently dropping every MFU field from the bench.
-            log(f"MFU accounting unavailable: cost_analysis() failed "
-                f"on this backend: {e!r}")
-            return None
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-        flops = float(ca.get("flops", 0.0)) if ca else 0.0
-        if flops <= 0:
-            log("MFU accounting unavailable: cost model reported "
-                f"flops={flops} for the compiled train step")
-            return None
-        per_device_batch = self.global_batch // self.world
-        return flops / per_device_batch
-
-    def measure_phase_split(self, window_iters: int = 100,
-                            windows: int = 3) -> dict:
-        """The reference's fwd/bwd phase split
-        (``Part 1/main.py:33-43``), window-amortized so it measures the
-        chip, not the dispatch path: a forward-only scanned window and the
-        full train window are timed alternately over the same staged
-        batches, and backward+sync+step ≈ train − forward per iteration.
-
-        The per-step ``profile_phases`` mode keeps the reference's exact
-        per-iteration timer placement (and therefore charges every
-        sub-millisecond forward a full host dispatch + fetch, as its
-        docstring warns); THIS is the on-chip split.  Each program is
-        timed at TWO window sizes (w and w/2), and the per-iteration device
-        cost is the SLOPE between them — the per-dispatch fixed cost
-        (which differs between the two programs and would otherwise
-        contaminate the small forward) cancels exactly.  Each
-        total is the best (min) of ``windows`` interleaved timings:
-        contention on the shared host is one-sided, so min is the least-
-        contaminated estimate (BASELINE.md 'Headline statistic').
-
-        The defaults (W=100, 3 windows) are the configuration of the
-        committed BASELINE.md artifact; tools/perf_phase_split.py
-        reproduces it.
-
-        The train windows apply REAL optimizer updates while timing (the
-        timed program must be the training program); the pre-measurement
-        TrainState is snapshotted and restored on return, so measuring
-        mid-training does not perturb the trajectory."""
-        if self.host_augment:
-            raise ValueError(
-                "measure_phase_split times the compiled windowed path "
-                "(device-side transform); it does not support "
-                "host_augment=True — construct a separate Trainer for "
-                "the phase split")
-        key = jax.random.PRNGKey(self.seed)
-        epoch_images, epoch_labels, _ = self._stage_train_epoch(0)
-        nbatches = epoch_images.shape[0]
-        if nbatches == 0:
-            raise ValueError("measure_phase_split needs at least one full "
-                             "global batch")
-        w = min(window_iters, nbatches)
-        half = max(w // 2, 1)
-        if w == half:
-            raise ValueError("measure_phase_split needs window_iters >= 2 "
-                             "for the two-size slope")
-        if self._fwd_window is None:   # jit caches are per function object
-            self._fwd_window = steplib.make_fwd_window(
-                self.apply_fn, self.mesh,
-                single=self.strategy_name == "single",
-                augment=self.augment, compute_dtype=self.compute_dtype)
-        fwd_window = self._fwd_window
-        # Deep-copy the state: train_window DONATES its input buffers, so
-        # the original arrays are consumed during measurement — the copy is
-        # what lets the trajectory be restored afterwards.
-        state_snapshot = jax.tree.map(jnp.copy, self.state)
-        lengths = {n: jnp.zeros((n,), jnp.int8) for n in (w, half)}
-        # Warm both programs at both sizes (compiles excluded from timers).
-        for n in (w, half):
-            np.asarray(fwd_window(self.state, key, epoch_images,
-                                  epoch_labels, jnp.int32(0), lengths[n]))
-            out = self.train_window(
-                self.state, key, epoch_images, epoch_labels, jnp.int32(0),
-                lengths[n])
-            self.state, losses = out[0], out[1]  # tolerate guarded arity
-            np.asarray(losses)
-        totals = {("fwd", w): [], ("fwd", half): [],
-                  ("step", w): [], ("step", half): []}
-        for i in range(windows):
-            start = jnp.int32((i % max(nbatches // w, 1)) * w)
-            for n in (w, half):
-                t0 = time.time()
-                np.asarray(fwd_window(self.state, key, epoch_images,
-                                      epoch_labels, start, lengths[n]))
-                totals[("fwd", n)].append(time.time() - t0)
-                t0 = time.time()
-                out = self.train_window(
-                    self.state, key, epoch_images, epoch_labels, start,
-                    lengths[n])
-                self.state, losses = out[0], out[1]
-                np.asarray(losses)  # value fetch = completion fence
-                totals[("step", n)].append(time.time() - t0)
-        self.state = state_snapshot   # measurement leaves no training trace
-        span = w - half
-        mins_ms = {f"{prog}_{n}": min(ts) * 1e3
-                   for (prog, n), ts in totals.items()}
-        fwd_ms = (mins_ms[f"fwd_{w}"] - mins_ms[f"fwd_{half}"]) / span
-        step_ms = (mins_ms[f"step_{w}"] - mins_ms[f"step_{half}"]) / span
-        return {"window_iters": w, "windows": windows,
-                "forward_ms_per_iter": fwd_ms,
-                "step_ms_per_iter": step_ms,
-                "backward_ms_per_iter": step_ms - fwd_ms,
-                "dispatch_ms_fwd_window": mins_ms[f"fwd_{w}"] - fwd_ms * w,
-                "dispatch_ms_step_window": (
-                    mins_ms[f"step_{w}"] - step_ms * w),
-                # Raw min totals (ms) so callers can aggregate mins ACROSS
-                # calls — a single contended half-window min makes the
-                # within-call slope misleading (even negative); the
-                # across-trials slope is the robust estimate
-                # (tools/perf_phase_split.py).
-                "window_totals_ms": mins_ms}
-
-    def steady_state_throughput(self, max_iters: int = 3 * WINDOW,
-                                window_iters=None) -> Tuple[float, float]:
-        """(images/sec, images/sec/chip) over steady-state iterations,
-        using the reference's measurement design: windowed dispatches, the
-        first window (compile+warmup) excluded.
-
-        ``window_iters`` sets the iterations per compiled dispatch:
-        ``"epoch"`` = the whole epoch per dispatch (what bench.py uses on
-        TPU), an int = that many, None = min(epoch, max(max_iters, WINDOW)).
-        Windows LARGER than the reference's 20-iteration reporting window
-        are deliberate: each dispatch carries a fixed host-side cost
-        (launch + the fencing fetch) regardless of its size, and a
-        steady-state device rate must amortize it away.  How large that
-        cost is on the current host has not been re-measured (ROADMAP S3
-        records epoch wall-clock next to this rate).  The reference-parity
-        path (train_model) keeps the 20-iteration granularity for its
-        print schedule; documented in BASELINE.md."""
-        if self.host_augment:
-            raise ValueError(
-                "steady_state_throughput measures the compiled windowed "
-                "path (device-side transform); it does not support "
-                "host_augment=True — construct a separate Trainer for "
-                "throughput measurement")
-        key = jax.random.PRNGKey(self.seed)
-        epoch_images, epoch_labels, _ = self._stage_train_epoch(0)
-        nbatches = epoch_images.shape[0]
-        if nbatches == 0:
-            raise ValueError(
-                "steady_state_throughput needs at least one full global "
-                f"batch ({self.global_batch}); the dataset holds only a "
-                "ragged tail")
-        if window_iters == "epoch":
-            w = nbatches
-        else:
-            w = min(window_iters or max(max_iters, WINDOW), nbatches)
-        length_arr = jnp.zeros((w,), jnp.int8)
-        nwin = max(2, -(-max_iters // w))
-        starts = [i * w for i in range(max(nbatches // w, 1))] or [0]
-
-        # Per-window keys, FOLDED AHEAD OF the timed region: when the start
-        # offsets wrap around a small epoch, the same batches get fresh
-        # augmentation randomness instead of replaying the previous pass's
-        # stream — but a host-side fold_in between dispatches would break
-        # the back-to-back window chain with a tiny interleaved program
-        # (~6% throughput on v5e), so all keys are materialized up front.
-        keys = [jax.device_put(k) for k in
-                jax.random.split(key, nwin + 1)]
-        for k in keys:
-            np.asarray(k)  # value fetch: keep transfers out of timed region
-
-        def dispatch(start, wi):
-            out = self.train_window(
-                self.state, keys[wi], epoch_images,
-                epoch_labels, jnp.int32(start), length_arr)
-            self.state, losses = out[0], out[1]  # tolerate guarded arity
-            return losses
-
-        # Window 0: compile + warmup (excluded, as the reference excludes its
-        # first 20-iteration window).  Fetching the losses is the fence.
-        _ = np.asarray(dispatch(0, 0))
-        # Steady state: windows dispatch back-to-back — the state pytree
-        # chains every step sequentially on device — and all losses are
-        # fetched after the last window, which transitively fences the whole
-        # chain.  (train_model, the reference-parity path, syncs per window
-        # to print; the bench measures device throughput.)
-        t0 = time.time()
-        pending = []
-        for i in range(nwin):
-            pending.append(dispatch(starts[(1 + i) % len(starts)], 1 + i))
-        for losses in pending:
-            _ = np.asarray(losses)
-        elapsed = time.time() - t0
-        ips = self.global_batch * w * nwin / elapsed
-        return ips, ips / self.world

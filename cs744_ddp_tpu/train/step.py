@@ -550,63 +550,6 @@ def make_train_window(apply_fn: Callable,
     return window
 
 
-def make_fwd_window(apply_fn: Callable, mesh: Mesh, *, single: bool = False,
-                    augment: bool = True, compute_dtype=None) -> Callable:
-    """Forward-only analogue of ``make_train_window``: W augment+forward+
-    loss iterations per dispatch via ``lax.scan``, same PRNG fold order and
-    train=True BN semantics as the fused step, no backward/update.
-
-    Exists for the reference's fwd/bwd phase split
-    (``/root/reference/src/Part 1/main.py:33-43``): a per-dispatch timer
-    charges every forward one host dispatch + fetch, which is not small
-    next to a sub-millisecond forward, so the split is window-amortized
-    (``Trainer.measure_phase_split``) — backward ≈ train-window − fwd-window
-    per iteration, with the dispatch cost shared by W iterations."""
-
-    def fwd_body(params, bn_state, key, epoch_images, epoch_labels, start,
-                 length_arr):
-        w = length_arr.shape[0]
-        imgs = lax.dynamic_slice_in_dim(epoch_images, start, w, axis=0)
-        labs = lax.dynamic_slice_in_dim(epoch_labels, start, w, axis=0)
-        idxs = start + jnp.arange(w, dtype=jnp.int32)
-
-        def one(carry, xs):
-            images, labels, idx = xs
-            x = fold_and_prepare(augment, compute_dtype, key, images,
-                                 idx=idx, fold_axis=not single)
-            logits, _ = apply_fn(params, bn_state, x, train=True)
-            loss = cross_entropy(logits, labels)
-            if not single:
-                loss = lax.pmean(loss, DATA_AXIS)
-            return carry, loss
-
-        _, losses = lax.scan(one, jnp.int32(0), (imgs, labs, idxs))
-        return losses
-
-    if single:
-        @jax.jit
-        def fwd_window(state: TrainState, key, epoch_images, epoch_labels,
-                       start, length_arr):
-            return fwd_body(state.params, state.bn_state, key, epoch_images,
-                            epoch_labels, start, length_arr)
-
-        return fwd_window
-
-    mapped = shard_map(
-        fwd_body, mesh=mesh,
-        in_specs=(P(), P(), P(), P(None, DATA_AXIS), P(None, DATA_AXIS),
-                  P(), P()),
-        out_specs=P())
-
-    @jax.jit
-    def fwd_window(state: TrainState, key, epoch_images, epoch_labels,
-                   start, length_arr):
-        return mapped(state.params, state.bn_state, key, epoch_images,
-                      epoch_labels, start, length_arr)
-
-    return fwd_window
-
-
 def masked_eval_counts(logits: jax.Array, labels: jax.Array):
     """(loss_sum, correct) over valid examples; label -1 marks padding.
 

@@ -1,5 +1,5 @@
 """Persistent XLA compilation cache shared by every entry point (cli.py,
-serve/, bench.py, chip_smoke.py, tools/ and the test suite).
+serve/, chip_smoke.py, benchmark/, tools/ and the test suite).
 
 One function, no arguments.  Where the cache lives is decided OUTSIDE the
 program when ``JAX_COMPILATION_CACHE_DIR`` is set: jax reads that variable

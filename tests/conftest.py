@@ -23,7 +23,7 @@ jax.config.update("jax_platforms", "cpu")
 assert jax.devices()[0].platform == "cpu", (
     "tests must run on the virtual-device CPU backend")
 
-# Persist XLA compilations (same cache bench.py uses): saves ~4 min of
+# Persist XLA compilations (the cache every entry point uses): saves ~4 min of
 # repeated CPU-backend compiles across suite runs.  The deviceless TPU AOT
 # client cannot DESERIALIZE cache entries (jax warns and recompiles — hence
 # the filter); everything else hits.

@@ -694,8 +694,8 @@ def test_lint_unfenced_timing():
     # Round-7 overlap scheduling: timing a PER-BUCKET dispatch loop is the
     # same hazard — the loop queues every bucket's collective and the
     # timer stops before any of them ran.  The rule must see through the
-    # loop nesting (bench.run_compression and the overlap tier's bucket
-    # walk are in the default lint targets).
+    # loop nesting (the overlap tier's bucket walk is in the default lint
+    # targets).
     bucketed = _SRC_UNFENCED.replace(
         "loss = self.train_window(x)",
         "for b in x:\n            loss = self.train_step(b)")

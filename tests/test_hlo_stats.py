@@ -1,5 +1,5 @@
-"""Unit tests for the HLO collective-stats parser behind bench.py's
-``spectrum`` section (utils/hlo_stats.py)."""
+"""Unit tests for the HLO collective-stats parser (utils/hlo_stats.py)
+behind the trainer's ``collective_stats`` event and the audit rules."""
 
 from cs744_ddp_tpu.utils.hlo_stats import bytes_of_type, collective_stats
 
@@ -207,7 +207,7 @@ ENTRY %main.1 (p0: f32[8]) -> f32[8] {
 # structure rendered in BOTH print forms XLA emits — the optimized print
 # (%-sigils, typed operands, layout/tiling annotations, metadata) and the
 # pre-optimization print (bare names, no operand types).  The parsers feed
-# bench.py's spectrum section, where a silent format mismatch reads as
+# the audit's collective contracts, where a silent format mismatch reads as
 # "zero collectives"; these pin absolute values AND sigil/bare agreement.
 #
 # Module structure (see the .hlo files):

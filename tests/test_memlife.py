@@ -1,4 +1,4 @@
-"""Static HBM liveness certifier + K-epoch feasibility planner (round 20).
+"""Static HBM liveness certifier (round 20).
 
 * ``hlo_ir.type_bytes`` / ``result_bytes`` — structural byte sizes,
   tuple-recursive and layout/tiling-tolerant, pinned on a committed
@@ -17,9 +17,6 @@
   compiled window) and the synthetic unsound/unmoored paths fire.
 * Runtime cross-check — a real windowed train run's ``memory`` gauge
   (live device bytes) stays under the window's static certificate.
-* ``megaplan`` — the closed form unit-pinned against hand-computed
-  slab/ring/state bytes; concrete vgg11 max-K at worlds 1/2/8 @ 16 GiB;
-  monotone in budget, non-increasing in window padding.
 * Repo self-checks — v5e literals single-sourced, fixture invariants
   hold, and both produce ``lint_graft --json``-shaped findings on
   seeded violations.
@@ -36,8 +33,7 @@ import pytest
 
 from cs744_ddp_tpu import models as model_zoo
 from cs744_ddp_tpu.analysis import audit as auditlib
-from cs744_ddp_tpu.analysis import (costmodel, dispatch, hlo_ir, megaplan,
-                                    memlife, stats)
+from cs744_ddp_tpu.analysis import hlo_ir, memlife, stats
 from cs744_ddp_tpu.obs import Telemetry
 from cs744_ddp_tpu.train.loop import Trainer
 
@@ -213,7 +209,7 @@ def test_static_bound_covers_real_compiled_window():
     declared band — the certifier's soundness contract on a living
     executable, not just fixtures."""
     model_zoo.register_model("tiny", tiny_cnn)
-    lowered, name = megaplan.lower_window(
+    lowered, name = memlife.lower_window(
         "tiny", world=4, window=3, global_batch=64)
     rep = memlife.mem_report(auditlib._hlo_text(lowered), name)
     ms = lowered.compile().memory_analysis()
@@ -243,69 +239,10 @@ def test_runtime_memory_gauge_under_certificate(tmp_path, mesh4):
     assert gauges, "windowed path emitted no memory gauge"
     assert all("host_rss_peak_mib" in g for g in gauges)
     measured = max(g.get("device_live_mib", 0.0) for g in gauges)
-    rep = megaplan.window_mem_report(
+    rep = memlife.window_mem_report(
         "tiny", world=4, window=3, global_batch=64)
     assert 0 < measured <= rep.peak_bytes / 2**20, \
         f"measured {measured} MiB vs certified {rep.peak_mib} MiB"
-
-
-# ---------------------------------------------------------------------------
-# megaplan: closed form unit-pinned, concrete vgg11 K, monotone
-# ---------------------------------------------------------------------------
-
-def test_plan_k_epochs_hand_computed():
-    """Every byte in the closed form pinned by hand: 1000 batches of 16
-    per-chip CIFAR samples (3072 u8 + 4 label = 3076 B) -> 49,216,000 B
-    slab; 1000 ring rows x 16 B + 4 B counter; 1 GiB budget."""
-    assert megaplan.RING_ROW_BYTES == 16
-    assert megaplan.ring_bytes_for_steps(1000) == 16_000
-    assert megaplan.slab_bytes_per_epoch(1000, 4, 64, 4) == 49_216_000
-    # Window padding: 999 batches pad up to 1000 at window 4.
-    assert megaplan.slab_bytes_per_epoch(999, 4, 64, 4) == 49_216_000
-    plan = megaplan.plan_k_epochs(
-        model="tiny", world=4, window=4, global_batch=64, nbatches=1000,
-        state_bytes=1_000_000, transient_bytes=500_000,
-        hbm_budget_bytes=2**30)
-    assert plan.fixed_bytes == 1_500_004
-    assert plan.per_epoch_bytes == 49_232_000
-    assert plan.max_k == (2**30 - 1_500_004) // 49_232_000 == 21
-    assert plan.windowed_round_trips_per_epoch == \
-        dispatch.epoch_round_trip_bound("window", 1000, 4,
-                                        include_eval=True) == 251
-    assert plan.mega_round_trips == 2
-    assert plan.round_trips_saved == 21 * 251 - 2
-    # Infeasible budgets report 0 with a reason, never negative K.
-    broke = megaplan.plan_k_epochs(
-        model="tiny", world=4, window=4, global_batch=64, nbatches=1000,
-        state_bytes=2**31, hbm_budget_bytes=2**30)
-    assert broke.max_k == 0 and broke.round_trips_saved == 0
-    assert any("infeasible" in n for n in broke.notes)
-
-
-def test_max_feasible_k_vgg11_concrete():
-    """The acceptance numbers: vgg11 @ 16 GiB, window 4, global batch
-    256 — concrete K per world, rising with the mesh (per-chip slab and
-    transient shrink as the batch shards)."""
-    ks = {w: megaplan.max_feasible_K("vgg11", w, 4, global_batch=256)
-          for w in (1, 2, 8)}
-    assert ks == {1: 105, 2: 215, 8: 873}
-
-
-def test_max_feasible_k_monotone_in_budget_and_window():
-    rep = megaplan.window_mem_report(
-        "vgg11", world=8, window=4, global_batch=256)
-    by_budget = [megaplan.max_feasible_K(
-        "vgg11", 8, 4, gib * 2**30, global_batch=256, window_report=rep)
-        for gib in (2, 4, 8, 16)]
-    assert by_budget == sorted(by_budget)
-    assert by_budget[0] > 0
-    # Bigger windows pad the slab more: K never increases with window.
-    by_window = [megaplan.plan_k_epochs(
-        model="vgg11", world=8, window=w, global_batch=256,
-        state_bytes=rep.param_bytes,
-        transient_bytes=200 * 2**20).max_k
-        for w in (1, 3, 4, 7, 16)]
-    assert by_window == sorted(by_window, reverse=True)
 
 
 # ---------------------------------------------------------------------------

@@ -264,9 +264,6 @@ def test_costmodel_mfu_fields_single_source():
     # absent, not null: no flop count, or a device outside the peak table
     assert costmodel.mfu_fields(1000.0, None, "TPU v5 lite") == {}
     assert costmodel.mfu_fields(1000.0, 2e9, "cpu") == {"tflops_per_sec": 2.0}
-    # bench delegates here with the device it measured on (the CPU mesh).
-    import bench
-    assert bench._mfu_fields(1000.0, 2e9) == {"tflops_per_sec": 2.0}
     # Same rule on the attribution join.
     from cs744_ddp_tpu.obs import attribution
     rep = costmodel.CostReport("p", flops=2e9)
@@ -548,34 +545,3 @@ def test_report_renders_traces_section(tmp_path, monkeypatch):
     tel2.gauge("epoch_time_s", 1.0)
     tel2.finalize()
     assert "traces (request causality)" not in telemetry_report.render(d2)
-
-
-# ---------------------------------------------------------------------------
-# bench: the committed attribution section + the head budget
-# ---------------------------------------------------------------------------
-
-def test_committed_bench_full_carries_attribution(tmp_path):
-    """BENCH_FULL.json ships the round-8 attribution sheet: cost-model
-    records for every zoo program plus the measured join — and the
-    section stays in the sidecar, outside the driver's head budget."""
-    import bench
-    with open(os.path.join(REPO, "BENCH_FULL.json")) as f:
-        full = json.load(f)
-    attr = full["attribution"]
-    progs = attr["programs"]
-    assert len(progs) >= 20                      # the whole zoo, not a sample
-    assert "train/window/ddp" in progs and "eval/window" in progs
-    for rec in progs.values():
-        assert rec["roofline_bound"] in ("compute", "bandwidth")
-        assert rec["gflops"] >= 0
-    meas = attr["measured"]
-    assert meas["program"] == "train/window/ddp"
-    assert meas["measured_s"] > 0 and meas["mfu_vs_bf16_peak"] > 0
-    assert attr["overlap_vs_ddp"]["hiding_ratio_lower_bound"] is not None
-
-    lines = []
-    head = bench.emit_result(full, str(tmp_path / "FULL.json"),
-                             out=lines.append)
-    assert "attribution" not in head
-    assert len(lines[-1].encode()) <= bench.HEAD_LINE_BUDGET
-    assert json.loads(lines[-1]) == head

@@ -18,10 +18,10 @@ run is what BASELINE.md records (gather 3,110 > allreduce 2,068 > ddp
 Noise discipline — this host is ONE core timesliced across 8 virtual
 devices, so external load inflates steps by 2x+ in bursts: samples are
 single steps, rounds are INTERLEAVED across tiers, and the compared
-statistic is the MIN over rounds (contention is strictly one-sided, the
-same convention as the bench's best-of-N — an early median-based version
-of this test flaked twice under full-suite load, once even inverting the
-ordering when a burst landed on gather's quiet slot).
+statistic is the MIN over rounds (contention is strictly one-sided; an
+early median-based version of this test flaked twice under full-suite
+load, once even inverting the ordering when a burst landed on gather's
+quiet slot).
 
 Only gather > allreduce is asserted: the allreduce-vs-ddp separation does
 NOT survive the CPU backend reliably — it strips the optimization-barrier
@@ -29,7 +29,7 @@ chains, so the per-param and bucketed tiers' compiled forms converge
 there (strategies.py module docstring; observed inverted under full-suite
 load).  That ordering is pinned where it is real: structurally on the TPU
 lowering (tests/test_tpu_aot.py — per-leaf vs per-bucket collective
-counts) and in bench.py's static `spectrum` section.
+counts) and by the audit contracts (tests/test_analysis.py).
 """
 
 import os

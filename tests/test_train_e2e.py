@@ -6,7 +6,7 @@ fp-tolerance-equal parameters after N steps from identical init and shards.
 
 A tiny conv net stands in for VGG-11 to keep CPU compiles fast — the
 strategy/step/loop code under test is identical (full VGG runs in
-tests/test_models.py and on the TPU bench).
+tests/test_models.py and in the benchmark's cells on the TPU).
 """
 
 import numpy as np

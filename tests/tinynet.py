@@ -3,7 +3,7 @@
 conv(3->8) + BN + relu + pool(4x) + fc: exercises every layer kind the real
 models use, while keeping CPU compiles fast.  The strategy/step/loop code
 under test is identical to what VGG/ResNet run (full models are covered by
-tests/test_models.py and the TPU bench).
+tests/test_models.py and the benchmark's cells on the TPU).
 """
 
 import jax
