@@ -31,6 +31,7 @@ the backward pass.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -212,6 +213,13 @@ class BlockDiffusion:
         self.per_example = {"moe_rows_expected": (
             2 * shape.seq_len * shape.top_k * len(shape.held)
             * shape.layers / shape.num_experts)}
+
+    @functools.cached_property
+    def gauges(self):
+        """What the attention kernels' tiles cost at this shape (the TPU's
+        path; elsewhere the blocked formulation runs in their place): a
+        recorder's, worked out when one asks."""
+        return attention.kernel_tile_gauges(self.seq_len, self.shape.block)
 
     def prepare(self, key, tokens, augment=None, compute_dtype=None):
         s = self.shape

@@ -11,15 +11,19 @@ followed by the clean one, cut into blocks of ``block`` tokens.  With
   * clean -> noisy:  never
 
 which allows a quarter of the (2L)^2 scores.  ``blockdiff_allowed`` is that
-rule, written with operators only so it runs on numpy ids (tests, the
-host-side tile classification) and on traced ids inside a kernel alike.
+rule, in the form the kernels pay least for: a query's code ``u(i) =
+2 blk(i) + [i clean]`` on one side, two compares of ``2 blk(j)`` with it on
+the other.  It runs on numpy ids (tests, the host-side tile classification)
+and on traced ids inside a kernel alike.
 
 Two ways of computing it, one result:
 
   * on a TPU the splash-attention Pallas kernels (forward, dq, dkv) with
     the rule handed over as a computable mask: tiles the rule forbids are
-    never visited, partly allowed tiles evaluate the rule on iota ids in
-    the kernel, and no [2L, 2L] array exists on the device or the host;
+    never visited, every visited tile (a wholly allowed one too: the
+    library does not tell them apart) evaluates the key's side of the rule
+    on iota ids against its rows of precomputed query codes, and no
+    [2L, 2L] array exists on the device or the host;
   * elsewhere (the CPU tests) a blocked ``jax.numpy`` formulation over
     the same tiles: query tile ``t`` (its noisy and its clean rows) meets
     the clean keys of tiles ``0..t`` and the noisy keys of tile ``t``.
@@ -28,21 +32,65 @@ Two ways of computing it, one result:
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
-KERNEL_TILE = 512       # splash tile edge (q and kv) at the real sizes
+
+class Tiles(NamedTuple):
+    """Splash tile shapes, a kernel each: (block_q, block_kv,
+    block_kv_compute) of the forward and of the dkv kernel, (block_q,
+    block_kv) of the dq kernel."""
+    fwd: tuple
+    dkv: tuple
+    dq: tuple
+
+
+# At the real sizes (2L = 8192, head 128, 8 query heads a key/value head),
+# each the fastest of its kernel in the sweep on the chip (the table in
+# PERF.md section 6, PR 32; ms a call of 4 key/value heads, the kernel alone):
+KERNEL_TILES = Tiles(
+    fwd=(1024, 1024, 256),      # 3.22 of 65 shapes; (512, 512, 512) 3.96
+    dkv=(1024, 1024, 1024),     # 5.46 of 67; 5.47-5.59 at the other compute
+                                # edges of 1024 x 1024; (512, 512, 512) 6.23
+    dq=(512, 512))              # 4.29 of 24; (1024, 1024) 4.44
+
+
+def _twice_block(ids, seq_len: int, block: int):
+    """2 * ((ids mod L) // block).  Ids are never negative, so the floor
+    repairs of `%` and `//` on traced integers would buy nothing: a mask
+    and a shift where L and block are powers of two, else truncating
+    division (plain operators on numpy ids)."""
+    if not (seq_len & (seq_len - 1) or block & (block - 1)):
+        shift = block.bit_length() - 2          # log2(block) - 1
+        even = (seq_len // block - 1) << 1
+        return (ids >> shift if shift >= 0 else ids << 1) & even
+    if isinstance(ids, jax.Array):
+        return lax.div(lax.rem(ids, seq_len), block) * 2
+    return ids % seq_len // block * 2
+
+
+def _query_code(q, seq_len: int, block: int):
+    """u(q) = 2 blk(q) + [q is clean]: all the rule needs of a query."""
+    return _twice_block(q, seq_len, block) + (q >= seq_len)
+
+
+def _allowed(u, k, seq_len: int, block: int):
+    """The four cases in two compares: a clean key is allowed iff
+    2 blk(k) < u(q), a noisy one iff 2 blk(k) == u(q)."""
+    w = _twice_block(k, seq_len, block)
+    before, same = w < u, w == u
+    # select(k noisy, same, before) in mask operations (Mosaic has no
+    # select between masks)
+    return before ^ ((same ^ before) & (k < seq_len))
 
 
 def blockdiff_allowed(q, k, seq_len: int, block: int):
     """May position ``q`` attend to position ``k``?  Ids in [0, 2L)."""
-    q_noisy, k_noisy = q < seq_len, k < seq_len
-    bq, bk = (q % seq_len) // block, (k % seq_len) // block
-    return ((q_noisy & k_noisy & (bq == bk))
-            | (q_noisy & ~k_noisy & (bk < bq))
-            | (~q_noisy & ~k_noisy & (bk <= bq)))
+    return _allowed(_query_code(q, seq_len, block), k, seq_len, block)
 
 
 def _tile(seq_len: int, block: int, want: int) -> int:
@@ -51,6 +99,43 @@ def _tile(seq_len: int, block: int, want: int) -> int:
     while seq_len % t or t % block:
         t -= 1
     return t
+
+
+def _fit(seq_len: int, block: int, tiles: Tiles, cap: int | None) -> Tiles:
+    """Every edge cut to `cap`, to a divisor of L and to whole blocks, and
+    a compute edge to a divisor of its kernel's key/value edge."""
+    def cut(bq, bkv, *compute):
+        bq, bkv = (_tile(seq_len, block, min(e, cap or e)) for e in (bq, bkv))
+        return (bq, bkv, *(_tile(bkv, block, min(c, cap or c))
+                           for c in compute))
+    return Tiles(*(cut(*shape) for shape in tiles))
+
+
+def tile_tally(seq_len: int, block: int, bq: int, bkv: int):
+    """What tiles of bq x bkv cost a kernel, known when it is built: (tiles
+    it visits, those of them partly allowed, scores visited over scores
+    allowed).  The rule is constant on a block x block cell, so one id a
+    block classifies a tile of whole blocks."""
+    ids = np.arange(0, 2 * seq_len, block)
+    cells = blockdiff_allowed(ids[:, None], ids[None, :], seq_len, block)
+    n = len(ids)
+    per = cells.reshape(n * block // bq, bq // block,
+                        n * block // bkv, bkv // block)
+    some, every = per.any((1, 3)), per.all((1, 3))
+    visited = int(some.sum())
+    return (visited, int((some & ~every).sum()),
+            visited * bq * bkv / (int(cells.sum()) * block * block))
+
+
+def kernel_tile_gauges(seq_len: int, block: int):
+    """(name, value, {"kernel": ...}) of `tile_tally` for each of the
+    kernels as `blockdiff_attention` builds them at this shape."""
+    tiles = _fit(seq_len, block, KERNEL_TILES, None)
+    names = ("attn_tiles_visited", "attn_tiles_partial",
+             "attn_visited_over_allowed")
+    return [(name, value, {"kernel": kernel})
+            for kernel, (bq, bkv, *_) in tiles._asdict().items()
+            for name, value in zip(names, tile_tally(seq_len, block, bq, bkv))]
 
 
 def _blocked(q, k, v, seq_len: int, block: int, tile: int):
@@ -77,7 +162,7 @@ def _blocked(q, k, v, seq_len: int, block: int, tile: int):
 
 
 @functools.lru_cache(maxsize=8)
-def _splash_kernel(seq_len: int, block: int, group: int, tile: int,
+def _splash_kernel(seq_len: int, block: int, group: int, tiles: Tiles,
                    interpret: bool):
     """The splash MQA kernel (one key/value head, `group` query heads)
     under the block-diffusion rule.  Built once per shape, outside any
@@ -85,11 +170,20 @@ def _splash_kernel(seq_len: int, block: int, group: int, tile: int,
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
 
-    rule = functools.partial(blockdiff_allowed, seq_len=seq_len, block=block)
+    n = 2 * seq_len
 
     class BlockDiffusionMask(sm._ComputableMask):
+        """The library evaluates a computable mask's function on every
+        score of every tile it visits, wholly allowed ones too, on the
+        tile's rows of `q_sequence` and on key ids: so the rows carry the
+        queries' codes, worked out here once, and the function is what is
+        left of the rule, two compares on a key's masked and shifted id."""
+
         def __init__(self):
-            super().__init__((2 * seq_len, 2 * seq_len), rule)
+            super().__init__((n, n), functools.partial(
+                _allowed, seq_len=seq_len, block=block))
+            self.q_sequence = _query_code(
+                np.arange(n, dtype=np.int32), seq_len, block)
 
         def __eq__(self, other):
             return type(other) is type(self) and self.shape == other.shape
@@ -97,10 +191,12 @@ def _splash_kernel(seq_len: int, block: int, group: int, tile: int,
         def __hash__(self):
             return hash((type(self).__name__, self.shape, seq_len, block))
 
+    (bq, bkv, compute), (bq_dkv, bkv_dkv, compute_dkv), (bq_dq, bkv_dq) = tiles
     sizes = sk.BlockSizes(
-        block_q=tile, block_kv=tile, block_kv_compute=tile,
-        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=tile,
-        block_q_dq=tile, block_kv_dq=tile)
+        block_q=bq, block_kv=bkv, block_kv_compute=compute,
+        block_q_dkv=bq_dkv, block_kv_dkv=bkv_dkv,
+        block_kv_dkv_compute=compute_dkv,
+        block_q_dq=bq_dq, block_kv_dq=bkv_dq)
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mqa(
             sm.MultiHeadMask([BlockDiffusionMask() for _ in range(group)]),
@@ -108,8 +204,9 @@ def _splash_kernel(seq_len: int, block: int, group: int, tile: int,
             interpret=interpret)
 
 
-def _splash(q, k, v, seq_len: int, block: int, tile: int, interpret: bool):
-    kernel = _splash_kernel(seq_len, block, q.shape[2], tile, interpret)
+def _splash(q, k, v, seq_len: int, block: int, tiles: Tiles,
+            interpret: bool):
+    kernel = _splash_kernel(seq_len, block, q.shape[2], tiles, interpret)
     # The MXU rounds float32 operands to bfloat16 at the default matmul
     # precision anyway; handing the kernel bfloat16 saves it the f32 passes.
     cast = lambda a: a.astype(jnp.bfloat16)
@@ -120,23 +217,25 @@ def _splash(q, k, v, seq_len: int, block: int, tile: int, interpret: bool):
 
 def blockdiff_attention(q, k, v, *, seq_len: int, block: int,
                         kernels: bool, interpret: bool = False,
-                        tile: int | None = None):
+                        tile: int | Tiles | None = None):
     """softmax(q k^T + M) v over the 2L positions of each sequence.
 
     q [S, HQ, 2L, D] ALREADY scaled by 1/sqrt(D); k, v [S, HKV, 2L, D];
     each key/value head serves HQ/HKV query heads.  Returns [S, HQ, 2L, D].
     `kernels`: the Pallas kernels (TPU; D and the tile multiples of 128)
-    or the blocked jax.numpy formulation.  `tile`: the tile edge wanted
-    (default 512 for the kernels, 128 blocked), cut to a divisor of L.
+    or the blocked jax.numpy formulation.  `tile` (tests): a cap on every
+    tile edge (the default: `KERNEL_TILES` for the kernels, 128 blocked),
+    or the kernels' `Tiles`; an edge is cut to a divisor of L either way.
     """
     s, hq, n, d = q.shape
     hkv = k.shape[1]
     qg = q.reshape(s, hkv, hq // hkv, n, d)
     with jax.named_scope("attn_blockdiff"):
         if kernels:
+            tiles, cap = (tile, None) if isinstance(tile, Tiles) \
+                else (KERNEL_TILES, tile)
             out = _splash(qg, k, v, seq_len, block,
-                          _tile(seq_len, block, tile or KERNEL_TILE),
-                          interpret)
+                          _fit(seq_len, block, tiles, cap), interpret)
         else:
             out = _blocked(qg, k, v, seq_len, block,
                            _tile(seq_len, block, tile or 128))

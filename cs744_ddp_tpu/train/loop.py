@@ -512,6 +512,8 @@ class Trainer:
                                   "error": native.load_error()},
                 "git_sha": git_sha(),
             })
+            for name, value, attrs in self.objective.gauges:
+                telemetry.gauge(name, value, **attrs)
 
     def _commit_state(self, state) -> "steplib.TrainState":
         """Commit a (host or device) TrainState to the mesh: params/BN/

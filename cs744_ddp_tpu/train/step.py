@@ -80,6 +80,8 @@ class ImageObjective:
     extras = ()             # per-step scalars besides the loss: (name, how
                             # shards and then steps combine: "sum" / "max")
     per_example = {}        # constants an example, tallied beside them
+    gauges = ()             # (name, value, attrs) known when the model is
+                            # built; a recorder gets each once
     eval_key = 0            # the evaluation draws nothing
     eval_dtypes = (jnp.float32, jnp.int32)      # loss sum, correct
     example_shape, example_dtype = (32, 32, 3), jnp.uint8

@@ -41,24 +41,75 @@ def close(a, b, tol=2e-5):
 
 # -- the mask -----------------------------------------------------------------
 
-def test_mask_follows_the_four_rules_entry_by_entry():
-    L, B = 16, 4
-    ids = np.arange(2 * L)
-    got = attention.blockdiff_allowed(ids[:, None], ids[None, :], L, B)
-    blk = lambda i: (i % L) // B
-    for i in range(2 * L):
-        for j in range(2 * L):
-            if i < L and j < L:
-                want = blk(i) == blk(j)
-            elif i < L:
-                want = blk(j) < blk(i)
-            elif j >= L:
-                want = blk(j) <= blk(i)
-            else:
-                want = False
-            assert got[i, j] == want, (i, j)
-    assert got.sum() == L * B + L * L            # a quarter of (2L)^2, + L*B
-    assert np.array_equal(got, np.asarray(ref.dense_mask(L, B)))
+def four_rules(i, j, L, B):
+    blk = lambda x: (x % L) // B
+    if i < L and j < L:
+        return blk(i) == blk(j)
+    if i < L:
+        return blk(j) < blk(i)
+    if j >= L:
+        return blk(j) <= blk(i)
+    return False
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["numpy", "traced"])
+@pytest.mark.parametrize("L,B,stride", [(16, 4, 1), (256, 4, 1),
+                                        (4096, 4, 257), (24, 3, 1)])
+def test_mask_follows_the_four_rules_entry_by_entry(L, B, stride, traced):
+    """The shipped rule (a query's code against a key's masked and shifted
+    id, ops/attention.py) against the four cases spelled out: powers of two
+    (the cell's 4096 x 4 on every 257th row) and one pair that is not."""
+    rows, ids = np.arange(0, 2 * L, stride), np.arange(2 * L)
+    rule = lambda q, k: attention.blockdiff_allowed(q, k, L, B)
+    if traced:
+        got = np.asarray(jax.jit(rule)(jnp.asarray(rows)[:, None],
+                                       jnp.asarray(ids)[None, :]))
+    else:
+        got = rule(rows[:, None], ids[None, :])
+    assert got.dtype == np.bool_
+    want = np.array([[four_rules(i, j, L, B) for j in ids] for i in rows])
+    assert np.array_equal(got, want)
+    if stride == 1:
+        assert got.sum() == L * B + L * L        # a quarter of (2L)^2, + L*B
+        assert np.array_equal(got, np.asarray(ref.dense_mask(L, B)))
+
+
+def tile_shaped(jaxpr, shape, inside=False, found=None):
+    """Primitives inside a `pallas_call` whose result has `shape`, by
+    integer / boolean or floating result."""
+    found = {"int": [], "float": []} if found is None else found
+    for e in jaxpr.eqns:
+        kernel = inside or e.primitive.name == "pallas_call"
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    tile_shaped(sub, shape, kernel, found)
+        if inside and e.primitive.name not in ("jit", "pjit"):
+            for o in e.outvars:
+                if getattr(o.aval, "shape", None) == shape:
+                    kind = "float" if jnp.issubdtype(
+                        o.aval.dtype, jnp.floating) else "int"
+                    found[kind].append(e.primitive.name)
+    return found
+
+
+def test_forward_kernel_spends_no_division_on_the_mask():
+    """What PR 32 found, pinned: the library evaluates a computable mask's
+    rule on every score of every visited tile, and the rule as it was
+    written (`%`, `//` with their sign repairs) cost a tile 49 integer /
+    boolean operations, 4 `rem` and 2 `div` among them, beside 6 float
+    ones.  Traced at the cell's sizes on the CPU; nothing runs."""
+    L, B, G = 4096, 4, 8
+    tiles = attention._fit(L, B, attention.KERNEL_TILES, None)
+    kernel = attention._splash_kernel(L, B, G, tiles, False)
+    q = jax.ShapeDtypeStruct((G, 2 * L, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2 * L, 128), jnp.bfloat16)
+    ops = tile_shaped(jax.make_jaxpr(kernel)(q, k, k).jaxpr,
+                      (tiles.fwd[0], tiles.fwd[2]))
+    assert "dot_general" in ops["float"] and "exp" in ops["float"]
+    assert not {"rem", "div"} & set(ops["int"]), ops["int"]
+    assert 0 < len(ops["int"]) <= 20, ops["int"]
 
 
 def dense_attention(q, k, v, L, B):
@@ -93,19 +144,48 @@ def test_blocked_attention_matches_the_dense_mask_forward_and_backward():
     assert all(close(a, b, 1e-4) for a, b in zip(df, dg))
 
 
-def test_pallas_attention_kernels_match_the_dense_mask_interpreted():
+@pytest.mark.parametrize("tile", [
+    128, attention.Tiles(fwd=(256, 128, 128), dkv=(128, 256, 128),
+                         dq=(256, 128))], ids=["square", "rectangular"])
+def test_pallas_attention_kernels_match_the_dense_mask_interpreted(tile):
     """The TPU path's kernels (forward, dq, dkv) under the same rule, in
-    Pallas' interpreter: head size 128, tiles of 128 over 2L = 512, so tiles
-    are skipped, partly masked and wholly allowed."""
+    Pallas' interpreter: head size 128, tiles of 128 (then of 256 x 128 and
+    128 x 256) over 2L = 512, so tiles are skipped, partly masked and
+    wholly allowed."""
     L, B = 256, 4
     q, k, v = qkv(L, 128, hq=2, hkv=1, s=1)
     f = lambda *a: attention.blockdiff_attention(
-        *a, seq_len=L, block=B, kernels=True, interpret=True, tile=128)
+        *a, seq_len=L, block=B, kernels=True, interpret=True, tile=tile)
     g = lambda *a: dense_attention(*a, L, B)
     assert close(f(q, k, v), g(q, k, v), 5e-2)      # bfloat16 in and out
     df = jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))), (0, 1, 2))(q, k, v)
     dg = jax.grad(lambda *a: jnp.sum(jnp.sin(g(*a))), (0, 1, 2))(q, k, v)
     assert all(close(a, b, 1e-1) for a, b in zip(df, dg))
+
+
+@pytest.mark.parametrize("bq,bkv", [(128, 128), (256, 128), (64, 512),
+                                    (512, 512)])
+def test_tile_tally_is_the_brute_force_count(bq, bkv):
+    L, B = 256, 4
+    ids = np.arange(2 * L)
+    dense = np.array([[four_rules(i, j, L, B) for j in ids] for i in ids])
+    t = dense.reshape(2 * L // bq, bq, 2 * L // bkv, bkv)
+    visited = int(t.any((1, 3)).sum())
+    partial = visited - int(t.all((1, 3)).sum())
+    got = attention.tile_tally(L, B, bq, bkv)
+    assert got[:2] == (visited, partial)
+    assert got[2] == pytest.approx(visited * bq * bkv / dense.sum())
+
+
+def test_tile_gauges_name_each_kernel_at_the_cells_sizes():
+    rows = attention.kernel_tile_gauges(4096, 4)
+    assert {(name, attrs["kernel"]) for name, _, attrs in rows} == {
+        (n, k) for n in ("attn_tiles_visited", "attn_tiles_partial",
+                         "attn_visited_over_allowed")
+        for k in ("fwd", "dkv", "dq")}
+    over = {a["kernel"]: v for n, v, a in rows
+            if n == "attn_visited_over_allowed"}
+    assert all(1.0 < v < 2.01 for v in over.values()), over
 
 
 # -- the expert layer ---------------------------------------------------------
@@ -368,6 +448,18 @@ def test_three_sgd_steps_and_test_model_through_trainer_match_the_reference(
     assert rows and all(r["epoch"] == 0 for r in rows)
     steps = [r for r in tel.records if r["kind"] == "step"]
     assert len(steps) == 3 and all("moe_rows_max_expert" in s for s in steps)
+    # the attention kernels' tile tally, once a kernel: at L = 32 one tile
+    # an edge, so 4 tiles, the clean -> noisy one never visited
+    gauges = {(r["name"], r["kernel"]): r["value"] for r in tel.records
+              if r["kind"] == "gauge" and r["name"].startswith("attn_")}
+    assert len(gauges) == 9
+    assert all(gauges["attn_tiles_visited", k] == 3
+               and gauges["attn_tiles_partial", k] == 3
+               for k in ("fwd", "dkv", "dq"))
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    import telemetry_report
+    assert "fwd  visits 3 tiles, 3 partly allowed" in "\n".join(
+        telemetry_report._attn_lines(tel.records))
     # the host spans of the default path are this path's too: one window,
     # no ragged tail, the evaluation (tests/test_loop_spans.py's tree)
     names = [r["name"] for r in tel.records if r["kind"] == "span"

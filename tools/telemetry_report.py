@@ -497,6 +497,29 @@ def _moe_lines(events) -> list:
     return lines
 
 
+def _attn_lines(events) -> list:
+    """A decoder's attention kernels, as built (ops/attention.py
+    `tile_tally`): the tiles each visits, those of them partly allowed, and
+    the scores it computes over the scores the mask allows."""
+    per = {}
+    for e in events:
+        if e.get("kind") == "gauge" and e.get("name") in (
+                "attn_tiles_visited", "attn_tiles_partial",
+                "attn_visited_over_allowed"):
+            per.setdefault(e.get("kernel"), {})[e["name"]] = e["value"]
+    if not per:
+        return []
+    lines = ["== attention tiles (per kernel) =="]
+    for kernel, row in per.items():
+        lines.append(
+            f"  {kernel:<4} visits {row.get('attn_tiles_visited', 0):,} "
+            f"tiles, {row.get('attn_tiles_partial', 0):,} partly allowed; "
+            f"visited / allowed scores "
+            f"{row.get('attn_visited_over_allowed', 0.0):.3f}")
+    lines.append("")
+    return lines
+
+
 def _loop_lines(events) -> list:
     """Dispatch-loop rendering: per span name of the default windowed path
     its count, median, longest and total per epoch, then every span that
@@ -623,6 +646,7 @@ def render(out_dir: str) -> str:
 
     lines.extend(_loop_lines(events))
     lines.extend(_moe_lines(events))
+    lines.extend(_attn_lines(events))
     lines.extend(_wire_ext_lines(events))
 
     lines.extend(_serving_lines(events))
