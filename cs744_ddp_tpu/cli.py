@@ -54,30 +54,38 @@ def build_parser() -> argparse.ArgumentParser:
                    help="PowerSGD approximation rank (default 4); only "
                         "meaningful with --strategy powersgd")
     p.add_argument("--model", default="vgg11",
-                   help="vgg11/13/16/19, resnet18/34, sdar-30b-a3b / sdar-tiny "
-                        "(a block-diffusion MoE decoder: see 'decoder "
-                        "share'), or any name "
+                   help="vgg11/13/16/19, resnet18/34, a decoder of "
+                        "models.DECODERS (sdar-30b-a3b: block diffusion, "
+                        "MoE; qwen3-next-80b-a3b: linear + full attention, "
+                        "MoE with a shared expert; sdar-tiny / "
+                        "qwen3-next-tiny: their CPU test sizes; see "
+                        "'decoder share'), or any name "
                         "registered via models.register_model (validated "
                         "by the model zoo, not argparse, so plugged-in "
                         "models work everywhere the built-ins do)")
     lm = p.add_argument_group(
         "decoder share",
-        "what THIS chip holds of --model sdar-30b-a3b (one chip's share of "
-        "SDAR-30B-A3B-Chat at the published widths; sdar-tiny is the CPU "
-        "test size) and the sequences it trains on; defaults: 6 of 48 "
-        "layers, experts 0-15 of 128, 18,992 rows of the vocabulary, 4096 "
-        "tokens in blocks of 4.  Data: <data-dir>/tokens/train.npy + "
-        "heldout.npy ([N, L] int32) or a synthetic stream; an epoch is "
-        "--limit-train-batches steps of the stream")
+        "what THIS chip holds of a decoder (one chip's share of the "
+        "published model at its published widths) and the sequences it "
+        "trains on; defaults, sdar-30b-a3b: 6 of 48 layers, experts 0-15 of "
+        "128, 4096 tokens in blocks of 4; qwen3-next-80b-a3b: 4 of 48 "
+        "layers (whole periods of linear, linear, linear, full: the kind "
+        "of a layer comes from the configuration), experts 0-31 of 512, "
+        "8192 tokens; both 18,992 rows of the vocabulary.  Data: "
+        "<data-dir>/tokens/train.npy + heldout.npy ([N, L] int32) or a "
+        "synthetic stream; an epoch is --limit-train-batches steps of the "
+        "stream")
     lm.add_argument("--lm-layers", type=int, default=None)
     lm.add_argument("--lm-experts-held", default=None, metavar="IDS",
                     help="expert ids held here, e.g. 0-15 or 0,3,5")
     lm.add_argument("--lm-vocab", type=int, default=None,
                     help="rows of the embedding and the head held here; "
-                         "the last id is the mask id")
+                         "the last id is never data (a block-diffusion "
+                         "decoder's mask id)")
     lm.add_argument("--lm-seq-len", type=int, default=None)
     lm.add_argument("--lm-block", type=int, default=None,
-                    help="block length of the diffusion objective")
+                    help="block length of the diffusion objective (a "
+                         "block-diffusion decoder only)")
     p.add_argument("--init-seed", type=int, default=None,
                    help="seed of the initial weights where it is not the "
                         "data's (default: the trainer's one seed)")

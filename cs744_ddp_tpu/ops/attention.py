@@ -1,4 +1,10 @@
-"""Attention under the block-diffusion training mask.
+"""Softmax attention under the two rules the decoders train with: the
+block-diffusion training mask (`blockdiff_attention`, models/sdar.py) and
+the plain causal rule at any head size (`causal_attention`,
+models/qwen3next.py: a query sees the keys at and before its own position,
+P (P + 1) / 2 of the P^2 scores; the library's own `CausalMask`).  One tile
+table (`KERNEL_TILES`) and, a rule each, a tally of what the tiles cost,
+emitted as the same gauges.
 
 A block-diffusion decoder (Arriola et al. 2025, arXiv:2503.09573) trains on
 ONE pass over 2L positions ``[xt ; x0]``: the noisy copy of a sequence
@@ -16,7 +22,7 @@ rule, in the form the kernels pay least for: a query's code ``u(i) =
 the other.  It runs on numpy ids (tests, the host-side tile classification)
 and on traced ids inside a kernel alike.
 
-Two ways of computing it, one result:
+Two ways of computing either, one result:
 
   * on a TPU the splash-attention Pallas kernels (forward, dq, dkv) with
     the rule handed over as a computable mask: tiles the rule forbids are
@@ -26,7 +32,8 @@ Two ways of computing it, one result:
     [2L, 2L] array exists on the device or the host;
   * elsewhere (the CPU tests) a blocked ``jax.numpy`` formulation over
     the same tiles: query tile ``t`` (its noisy and its clean rows) meets
-    the clean keys of tiles ``0..t`` and the noisy keys of tile ``t``.
+    the clean keys of tiles ``0..t`` and the noisy keys of tile ``t``;
+    under the causal rule, the keys of tiles ``0..t``.
 """
 
 from __future__ import annotations
@@ -127,15 +134,20 @@ def tile_tally(seq_len: int, block: int, bq: int, bkv: int):
             visited * bq * bkv / (int(cells.sum()) * block * block))
 
 
-def kernel_tile_gauges(seq_len: int, block: int):
-    """(name, value, {"kernel": ...}) of `tile_tally` for each of the
-    kernels as `blockdiff_attention` builds them at this shape."""
-    tiles = _fit(seq_len, block, KERNEL_TILES, None)
+def _tile_gauges(tiles: Tiles, tally):
+    """(name, value, {"kernel": ...}) of `tally(bq, bkv)` a kernel."""
     names = ("attn_tiles_visited", "attn_tiles_partial",
              "attn_visited_over_allowed")
     return [(name, value, {"kernel": kernel})
             for kernel, (bq, bkv, *_) in tiles._asdict().items()
-            for name, value in zip(names, tile_tally(seq_len, block, bq, bkv))]
+            for name, value in zip(names, tally(bq, bkv))]
+
+
+def kernel_tile_gauges(seq_len: int, block: int):
+    """`tile_tally` for each of the kernels as `blockdiff_attention` builds
+    them at this shape."""
+    return _tile_gauges(_fit(seq_len, block, KERNEL_TILES, None),
+                        functools.partial(tile_tally, seq_len, block))
 
 
 def _blocked(q, k, v, seq_len: int, block: int, tile: int):
@@ -191,22 +203,23 @@ def _splash_kernel(seq_len: int, block: int, group: int, tiles: Tiles,
         def __hash__(self):
             return hash((type(self).__name__, self.shape, seq_len, block))
 
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mqa(
+            sm.MultiHeadMask([BlockDiffusionMask() for _ in range(group)]),
+            block_sizes=_block_sizes(sk, tiles), head_shards=1,
+            q_seq_shards=1, interpret=interpret)
+
+
+def _block_sizes(sk, tiles: Tiles):
     (bq, bkv, compute), (bq_dkv, bkv_dkv, compute_dkv), (bq_dq, bkv_dq) = tiles
-    sizes = sk.BlockSizes(
+    return sk.BlockSizes(
         block_q=bq, block_kv=bkv, block_kv_compute=compute,
         block_q_dkv=bq_dkv, block_kv_dkv=bkv_dkv,
         block_kv_dkv_compute=compute_dkv,
         block_q_dq=bq_dq, block_kv_dq=bkv_dq)
-    with jax.ensure_compile_time_eval():
-        return sk.make_splash_mqa(
-            sm.MultiHeadMask([BlockDiffusionMask() for _ in range(group)]),
-            block_sizes=sizes, head_shards=1, q_seq_shards=1,
-            interpret=interpret)
 
 
-def _splash(q, k, v, seq_len: int, block: int, tiles: Tiles,
-            interpret: bool):
-    kernel = _splash_kernel(seq_len, block, q.shape[2], tiles, interpret)
+def _splash(kernel, q, k, v):
     # The MXU rounds float32 operands to bfloat16 at the default matmul
     # precision anyway; handing the kernel bfloat16 saves it the f32 passes.
     cast = lambda a: a.astype(jnp.bfloat16)
@@ -234,9 +247,83 @@ def blockdiff_attention(q, k, v, *, seq_len: int, block: int,
         if kernels:
             tiles, cap = (tile, None) if isinstance(tile, Tiles) \
                 else (KERNEL_TILES, tile)
-            out = _splash(qg, k, v, seq_len, block,
-                          _fit(seq_len, block, tiles, cap), interpret)
+            out = _splash(_splash_kernel(
+                seq_len, block, qg.shape[2],
+                _fit(seq_len, block, tiles, cap), interpret), qg, k, v)
         else:
             out = _blocked(qg, k, v, seq_len, block,
                            _tile(seq_len, block, tile or 128))
+    return out.reshape(s, hq, n, d)
+
+
+# -- the causal rule ----------------------------------------------------------
+
+# The kernels take `KERNEL_TILES`, the block-diffusion kernels' shapes, at
+# head size 256, 8 query heads a key/value head, P = 8192 (the shapes of
+# models/qwen3next.py) too: the deviceless v5e compile takes them at twice
+# their head size; no sweep at this head size gave others (PERF.md
+# section 7).
+
+def causal_tile_tally(n: int, bq: int, bkv: int):
+    """`tile_tally` under the causal rule over n positions: a tile is
+    visited if its last row reaches its first key, wholly allowed if its
+    first row reaches its last key."""
+    rows, keys = np.arange(0, n, bq), np.arange(0, n, bkv)
+    some = (rows[:, None] + bq - 1) >= keys[None, :]
+    every = rows[:, None] >= (keys[None, :] + bkv - 1)
+    visited = int(some.sum())
+    return (visited, int((some & ~every).sum()),
+            visited * bq * bkv / (n * (n + 1) // 2))
+
+
+def causal_tile_gauges(n: int):
+    """`kernel_tile_gauges` for the kernels `causal_attention` builds."""
+    return _tile_gauges(_fit(n, 1, KERNEL_TILES, None),
+                        functools.partial(causal_tile_tally, n))
+
+
+def _blocked_causal(q, k, v, tile: int):
+    """q [S, HKV, G, P, D]; k, v [S, HKV, P, D] -> like q."""
+    out = []
+    for lo in range(0, q.shape[-2], tile):
+        hi = lo + tile
+        allowed = np.arange(lo, hi)[:, None] >= np.arange(hi)[None, :]
+        s = jnp.einsum("shgqd,shkd->shgqk", q[..., lo:hi, :], k[..., :hi, :],
+                       preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(jnp.where(allowed, s, -jnp.inf), axis=-1)
+        out.append(jnp.einsum(
+            "shgqk,shkd->shgqd", p.astype(v.dtype), v[..., :hi, :],
+            preferred_element_type=jnp.float32).astype(q.dtype))
+    return jnp.concatenate(out, axis=-2)
+
+
+@functools.lru_cache(maxsize=8)
+def _causal_kernel(n: int, group: int, tiles: Tiles, interpret: bool):
+    """The splash MQA kernel under the library's causal mask."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mqa(
+            sm.MultiHeadMask([sm.CausalMask((n, n)) for _ in range(group)]),
+            block_sizes=_block_sizes(sk, tiles), head_shards=1,
+            q_seq_shards=1, interpret=interpret)
+
+
+def causal_attention(q, k, v, *, kernels: bool, interpret: bool = False,
+                     tile: int | None = None):
+    """softmax(q k^T + causal) v over the P positions of each sequence.
+
+    q [S, HQ, P, D] ALREADY scaled; k, v [S, HKV, P, D]; each key/value
+    head serves HQ/HKV query heads.  Returns [S, HQ, P, D].  `kernels` and
+    `tile` as `blockdiff_attention`'s (`KERNEL_TILES` for the kernels)."""
+    s, hq, n, d = q.shape
+    hkv = k.shape[1]
+    qg = q.reshape(s, hkv, hq // hkv, n, d)
+    with jax.named_scope("attn_causal"):
+        if kernels:
+            out = _splash(_causal_kernel(
+                n, qg.shape[2], _fit(n, 1, KERNEL_TILES, tile), interpret),
+                qg, k, v)
+        else:
+            out = _blocked_causal(qg, k, v, _tile(n, 1, tile or 128))
     return out.reshape(s, hq, n, d)

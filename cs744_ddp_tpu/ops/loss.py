@@ -75,3 +75,19 @@ def blockdiff_head_counts(hidden: jax.Array, w_head: jax.Array,
         return loss, hit.astype(jnp.int32), jnp.sum(m).astype(jnp.int32)
 
     return jax.lax.map(one, (hidden, targets, masked, weight))
+
+
+def next_token_head_counts(hidden: jax.Array, w_head: jax.Array,
+                           tokens: jax.Array):
+    """Head + next-token cross-entropy, one sequence at a time: the logit
+    at position t predicts token t + 1, every position but the last.
+    hidden [S, L, H], tokens [S, L].  Returns per sequence (loss [S]: the
+    mean of -log softmax(logits)[next token] over the L - 1 predicted
+    positions; correct [S]; count [S] = L - 1): `blockdiff_head_counts` on
+    shifted targets."""
+    length = tokens.shape[1]
+    predicted = jnp.broadcast_to(jnp.arange(length) < length - 1,
+                                 tokens.shape)
+    weight = jnp.full(tokens.shape, length / (length - 1.0), jnp.float32)
+    return blockdiff_head_counts(hidden, w_head, jnp.roll(tokens, -1, axis=1),
+                                 predicted, weight)

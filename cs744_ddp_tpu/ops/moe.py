@@ -28,6 +28,10 @@ positions' choices, as before the ladder, on a long one (`_short`).
 
 Rows of the prefix past the live ones are never written by the kernels and
 hold whatever the memory held; every read of them is behind a select.
+
+A model with a SHARED expert (models/qwen3next.py) adds `shared_expert`
+beside the routed part: every position goes through it, every chip of the
+deployment computes it alike, and nothing of it is sorted or sliced.
 """
 
 from __future__ import annotations
@@ -291,3 +295,13 @@ def expert_layer(h, params, *, held, num_experts: int, top_k: int,
         out = experts(plan, h, weights, *held_w).astype(h.dtype)
     sizes = plan[2]
     return out, jnp.sum(sizes), jnp.max(sizes)
+
+
+def shared_expert(h, params):
+    """The expert every position goes through, times its own sigmoid gate:
+    h [P, H] -> sigmoid(h w_sig) * W_d (silu(W_g h) * (W_u h)).  `params`:
+    shared_gate, shared_up [H, F]; shared_down [F, H]; shared_sig [H, 1]."""
+    with jax.named_scope("moe_shared"):
+        dot = lambda x, name: jnp.dot(x, params[name].astype(x.dtype))
+        act = jax.nn.silu(dot(h, "shared_gate")) * dot(h, "shared_up")
+        return dot(act, "shared_down") * jax.nn.sigmoid(dot(h, "shared_sig"))

@@ -470,13 +470,13 @@ def _moe_lines(events) -> list:
     """A decoder's expert layer, per epoch: the rows this chip's experts
     computed against what even routing would have sent here, the rows of
     the dropless buffer they were computed on (the prefix ops/moe.py chose)
-    over them, and the masked tokens its loss was taken on (train/loop.py
+    over them, and the tokens its loss was taken on (train/loop.py
     `_tally_extras`)."""
     per = {}
     for e in events:
         if e.get("kind") == "counter" and e.get("name") in (
                 "moe_rows_local", "moe_rows_expected", "moe_rows_touched",
-                "tokens_masked"):
+                "tokens_masked", "tokens_predicted"):
             row = per.setdefault(e.get("epoch"), {})
             row[e["name"]] = row.get(e["name"], 0) + e.get("inc", 0)
     if not per:
@@ -489,10 +489,13 @@ def _moe_lines(events) -> list:
         share = f"{rows / exp:.4f}" if exp else "n/a"
         touched = row.get("moe_rows_touched", 0)
         over = f"{touched / rows:.3f}" if touched and rows else "n/a"
+        # the loss's tokens: a block-diffusion decoder's masked ones, a
+        # next-token decoder's predicted ones
+        kind = "predicted" if "tokens_predicted" in row else "masked"
         lines.append(f"  epoch {epoch}: rows here {rows:,.0f} of "
                      f"{exp:,.0f} expected (share {share}), buffer rows "
                      f"touched {touched:,.0f} (touched / live {over}), "
-                     f"masked tokens {row.get('tokens_masked', 0):,.0f}")
+                     f"{kind} tokens {row.get('tokens_' + kind, 0):,.0f}")
     lines.append("")
     return lines
 
@@ -518,6 +521,20 @@ def _attn_lines(events) -> list:
             f"{row.get('attn_visited_over_allowed', 0.0):.3f}")
     lines.append("")
     return lines
+
+
+def _gdn_lines(events) -> list:
+    """A hybrid decoder's linear-attention layers, as built (ops/gdn.py):
+    the chunk the gated delta rule is computed in and the chunks the state
+    is carried through a sequence."""
+    g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
+         and e.get("name") in ("gdn_chunk", "gdn_chunks_per_sequence")}
+    if not g:
+        return []
+    return ["== linear attention ==",
+            f"  the gated delta rule in chunks of {g.get('gdn_chunk', 0):,} "
+            f"positions, {g.get('gdn_chunks_per_sequence', 0):,} a sequence",
+            ""]
 
 
 def _loop_lines(events) -> list:
@@ -647,6 +664,7 @@ def render(out_dir: str) -> str:
     lines.extend(_loop_lines(events))
     lines.extend(_moe_lines(events))
     lines.extend(_attn_lines(events))
+    lines.extend(_gdn_lines(events))
     lines.extend(_wire_ext_lines(events))
 
     lines.extend(_serving_lines(events))
