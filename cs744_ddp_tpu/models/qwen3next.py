@@ -148,7 +148,7 @@ def _norm(x, gain, eps):
     return rmsnorm(x, 1.0 + gain, eps)
 
 
-def _gdn_mixer(shape: Shape, h, p):
+def _gdn_mixer(shape: Shape, kernels: bool, h, p):
     """h [P, H] (after ln1) -> the linear-attention mixer's output [P, H]."""
     s = shape
     P = h.shape[0]
@@ -163,14 +163,14 @@ def _gdn_mixer(shape: Shape, h, p):
         z = qkvz[:, 2 * kd + vd:]
         beta = jax.nn.sigmoid(ba[:, :hv])
         g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[:, hv:] + p["dt_bias"])
-        heads = lambda x, n, d: x.reshape(P, n, d).transpose(1, 0, 2).astype(f32)
+        heads = lambda x, n, d: x.reshape(P, n, d).astype(f32)
         l2 = lambda x: x * lax.rsqrt(
             jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-        q = jnp.repeat(l2(heads(u[:, :kd], hk, dk)), hv // hk, axis=0) \
-            * dk ** -0.5
-        k = jnp.repeat(l2(heads(u[:, kd:2 * kd], hk, dk)), hv // hk, axis=0)
-        o = gdn.delta_rule(q, k, heads(u[:, 2 * kd:], hv, dv), g.T, beta.T,
-                           gdn.chunk_for(P)).transpose(1, 0, 2)  # [P, hv, dv]
+        q = l2(heads(u[:, :kd], hk, dk)) * dk ** -0.5
+        k = l2(heads(u[:, kd:2 * kd], hk, dk))
+        # a key head serves hv / hk value heads in a row (ops/gdn.py)
+        o = gdn.delta_rule(q, k, heads(u[:, 2 * kd:], hv, dv), g, beta,
+                           gdn.chunk_for(P), kernels=kernels)   # [P, hv, dv]
         y = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True) + s.eps)
         y = y * p["gdn_norm"] * jax.nn.silu(z.reshape(P, hv, dv).astype(f32))
         return jnp.dot(y.reshape(P, vd).astype(h.dtype),
@@ -216,9 +216,10 @@ def _layer(shape: Shape, kernels: bool, mixer, x, p):
 
 
 def make(shape: Shape = Shape(), kernels=None):
-    """(init_fn, apply_fn) for one share.  `kernels`: the Pallas attention
-    and grouped-matmul kernels; None = wherever the default backend is a
-    TPU (a deviceless compile for a described TPU passes True)."""
+    """(init_fn, apply_fn) for one share.  `kernels`: the Pallas attention,
+    grouped-matmul and delta-rule kernels; None = wherever the default
+    backend is a TPU (a deviceless compile for a described TPU passes
+    True)."""
     s = shape
     if s.layers % s.interval or s.heads % s.kv_heads \
             or s.lin_value_heads % s.lin_key_heads:
@@ -230,14 +231,16 @@ def make(shape: Shape = Shape(), kernels=None):
     def init_fn(key):
         return init_params(key, s), {}
 
+    def resolved() -> bool:
+        return jax.default_backend() == "tpu" if kernels is None else kernels
+
     def apply_fn(params, bn_state, x, train=True, compute_dtype=None):
         """x: token ids [S, L].  Returns (hidden [S, L, H] after the final
         norm, {}, (rows computed by this chip's experts summed over layers,
         rows of the fullest held expert of any layer, rows of the dropless
         buffers touched for them))."""
         del train                       # no dropout, no batch statistics
-        on_tpu = jax.default_backend() == "tpu" if kernels is None \
-            else kernels
+        on_tpu = resolved()
         h = params["embed"][x]
         if compute_dtype is not None:
             h = h.astype(compute_dtype)
@@ -257,8 +260,8 @@ def make(shape: Shape = Shape(), kernels=None):
             return run
 
         def period(x, p):
-            x, lin = lax.scan(layer(functools.partial(_gdn_mixer, s)), x,
-                              p["linear"])
+            x, lin = lax.scan(layer(functools.partial(_gdn_mixer, s, on_tpu)),
+                              x, p["linear"])
             x, last = layer(full)(x, p["full"])
             return x, jnp.concatenate([lin, last[None]])
         h, counts = lax.scan(period, h, params["periods"])
@@ -267,7 +270,7 @@ def make(shape: Shape = Shape(), kernels=None):
         return hidden, bn_state, (jnp.sum(counts[:, 0]), jnp.max(counts[:, 1]),
                                   jnp.sum(counts[:, 2]))
 
-    apply_fn.objective = NextToken(s)
+    apply_fn.objective = NextToken(s, resolved)
     return init_fn, apply_fn
 
 
@@ -285,8 +288,8 @@ class NextToken:
     stream = True           # every epoch its own sequences (data/tokens.py)
     checks_vma = False      # train/step.py `_vary`
 
-    def __init__(self, shape: Shape):
-        self.shape = shape
+    def __init__(self, shape: Shape, kernels=lambda: False):
+        self.shape, self.kernels = shape, kernels
         self.seq_len, self.vocab = shape.seq_len, shape.vocab
         self.example_shape = (shape.seq_len,)
         self.per_example = {"moe_rows_expected": (
@@ -295,10 +298,13 @@ class NextToken:
 
     @functools.cached_property
     def gauges(self):
-        """The recurrence's chunking and what the causal kernels' tiles
+        """Which form of the recurrence runs (1 the Pallas kernels, 0 the
+        jax.numpy one) and its chunking; what the causal kernels' tiles
         cost at this length (the TPU's path): a recorder's, once."""
-        c = gdn.chunk_for(self.seq_len)
-        return [("gdn_chunk", c, {}),
+        s = self.shape
+        fused, c = gdn.plan(self.seq_len, s.lin_key_dim, s.lin_value_dim,
+                            self.kernels())
+        return [("gdn_kernel", int(fused), {}), ("gdn_chunk", c, {}),
                 ("gdn_chunks_per_sequence", -(-self.seq_len // c), {})] \
             + attention.causal_tile_gauges(self.seq_len)
 

@@ -42,21 +42,26 @@ def close(a, b, tol=2e-5):
 
 # -- the recurrence and the convolution ----------------------------------------
 
-def recurrence_inputs(heads=3, length=40, dk=8, dv=6, seed=0):
+def recurrence_inputs(heads=3, length=40, dk=8, dv=6, seed=0, key_heads=None):
+    """Positions first, as the mixer hands them over: q, k [P, key heads,
+    dk], v [P, heads, dv], g, beta [P, heads]."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    q = jax.random.normal(ks[0], (heads, length, dk))
-    k = jax.random.normal(ks[1], (heads, length, dk))
+    hk = key_heads or heads
+    q = jax.random.normal(ks[0], (length, hk, dk))
+    k = jax.random.normal(ks[1], (length, hk, dk))
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (heads, length, dv))
-    g = -4.0 * jax.random.uniform(ks[3], (heads, length))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (heads, length)))
+    v = jax.random.normal(ks[2], (length, heads, dv))
+    g = -4.0 * jax.random.uniform(ks[3], (length, heads))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (length, heads)))
     return q, k, v, g, beta
 
 
-def by_token(*x):
-    """The reference's token-by-token loop ([P, heads, ...] there)."""
-    seq_first = lambda a: jnp.moveaxis(a, 1, 0)
-    return jnp.moveaxis(ref.recurrence(*map(seq_first, x)), 0, 1)
+def by_token(q, k, v, g, beta):
+    """The reference's token-by-token loop, a key head repeated for its
+    value heads."""
+    group = v.shape[1] // q.shape[1]
+    return ref.recurrence(jnp.repeat(q, group, 1), jnp.repeat(k, group, 1),
+                          v, g, beta)
 
 
 @pytest.mark.parametrize("chunk,segment", [
@@ -89,6 +94,106 @@ def test_recurrence_forgets_by_its_decay_and_writes_by_beta():
     o = gdn.delta_rule(q, k, v, jnp.full_like(g, -40.0), beta, 4, 2)
     own = (beta * jnp.sum(q * k, -1))[..., None] * v
     assert close(o, own)
+
+
+# The kernels (Pallas' interpreter): keys and values of 128, the MXU's width.
+KERNEL_CASES = [(64, 128), (64, 100), (128, 256), (128, 200)]
+
+
+def kernel_rule(chunk, monkeypatch, heads_a_step=2):
+    monkeypatch.setattr(gdn, "KERNEL_CHUNK", chunk)
+    monkeypatch.setattr(gdn, "KERNEL_HEADS", heads_a_step)
+    return lambda *a: gdn.delta_rule(*a, kernels=True, interpret=True)
+
+
+@pytest.mark.parametrize("chunk,length", KERNEL_CASES, ids=lambda c: str(c))
+def test_kernels_are_the_token_by_token_recurrence(chunk, length,
+                                                   monkeypatch):
+    """The forward kernel's output and the backward kernel's five
+    gradients against the loop, at a length of whole chunks (2 of either
+    size) and at one that is padded (100 = 64 + 36; 200 = 128 + 72): three
+    heads with a key head each, all three a grid step; then four heads on two
+    key heads, a key head's cotangents summed over its two value heads."""
+    x = recurrence_inputs(heads=3, length=length, dk=128, dv=128)
+    want = by_token(*x)
+    grad = lambda f: jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))),
+                              argnums=(0, 1, 2, 3, 4))(*x)
+    fused = kernel_rule(chunk, monkeypatch)
+    got = fused(*x)
+    assert got.shape == want.shape and close(got, want)
+    for a, b in zip(grad(fused), grad(by_token)):
+        assert a.shape == b.shape and close(a, b, 1e-4)
+    # four heads of two key heads, four a grid step then two
+    x = recurrence_inputs(heads=4, key_heads=2, length=length, dk=128, dv=128)
+    want = by_token(*x)
+    for heads_a_step in (4, 2):
+        fused = kernel_rule(chunk, monkeypatch, heads_a_step)
+        assert close(fused(*x), want)
+    for a, b in zip(grad(fused), grad(by_token)):
+        assert a.shape == b.shape and close(a, b, 1e-4)
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_kernels_forget_by_their_decay_and_write_by_beta(chunk, monkeypatch):
+    """`test_recurrence_forgets...` on the kernels' path: beta = 0 writes
+    nothing; under a decay of exp(-40) a position only its own write is
+    left, and no exponent of a positive difference is taken (no inf, no
+    nan in the output or in a gradient)."""
+    q, k, v, g, beta = recurrence_inputs(heads=2, length=chunk + 8, dk=128,
+                                         dv=128)
+    fused = kernel_rule(chunk, monkeypatch)
+    assert not np.any(fused(q, k, v, g, jnp.zeros_like(beta)))
+    hard = jnp.full_like(g, -40.0)
+    o = fused(q, k, v, hard, beta)
+    own = (beta * jnp.sum(q * k, -1))[..., None] * v
+    assert close(o, own)
+    grads = jax.grad(lambda *a: jnp.sum(fused(*a)), argnums=(0, 1, 2, 3, 4))(
+        q, k, v, hard, beta)
+    assert all(np.all(np.isfinite(np.asarray(a))) for a in grads)
+
+
+def test_the_path_is_chosen_from_the_backend_and_the_shapes(monkeypatch):
+    """`kernels=False` (off the TPU) and key / value sizes that are not
+    whole lanes take the jax.numpy form: no `pallas_call` in the program;
+    at a shape both take, the two forms give the same numbers."""
+    calls = lambda f, x: str(jax.make_jaxpr(f)(*x)).count("pallas_call")
+    wide = recurrence_inputs(heads=2, length=72, dk=128, dv=128)
+    narrow = recurrence_inputs(heads=2, length=72)           # dk 8, dv 6
+    fused = kernel_rule(64, monkeypatch)
+    plain = lambda *a: gdn.delta_rule(*a, 16, 2)
+    assert calls(fused, wide) == 1 and calls(jax.grad(
+        lambda *a: jnp.sum(fused(*a))), wide) == 2
+    assert calls(plain, wide) == 0 and calls(fused, narrow) == 0
+    assert calls(lambda *a: gdn.delta_rule(*a, kernels=False,
+                                           interpret=True), wide) == 0
+    assert close(fused(*wide), plain(*wide))
+    assert close(fused(*narrow), plain(*narrow))
+    assert gdn.kernel_fits(128, 256) and not gdn.kernel_fits(128, 64)
+
+
+def test_the_gauge_says_which_form_runs():
+    """`gdn_kernel` 1 and the kernels' chunk where the model is built with
+    the kernels and they fit its key and value sizes; 0 and the jax.numpy
+    chunk where either fails (the tiny size: keys of 8); printed by
+    tools/telemetry_report.py."""
+    def gauges(shape, kernels):
+        _, apply_fn = qwen3next.make(shape, kernels=kernels)
+        return {name: value for name, value, _ in apply_fn.objective.gauges
+                if name.startswith("gdn_")}
+    wide = TINY._replace(lin_key_dim=128, lin_value_dim=128, seq_len=256)
+    assert gauges(wide, True) == {
+        "gdn_kernel": 1, "gdn_chunk": gdn.KERNEL_CHUNK,
+        "gdn_chunks_per_sequence": 256 // gdn.KERNEL_CHUNK}
+    assert gauges(wide, False) == gauges(wide, None) == {
+        "gdn_kernel": 0, "gdn_chunk": 64, "gdn_chunks_per_sequence": 4}
+    assert gauges(TINY, True) == {
+        "gdn_kernel": 0, "gdn_chunk": 8, "gdn_chunks_per_sequence": 4}
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    import telemetry_report
+    events = [dict(kind="gauge", name=n, value=v)
+              for n, v in gauges(wide, True).items()]
+    assert f"(the Pallas kernels) in chunks of {gdn.KERNEL_CHUNK} " \
+        in "\n".join(telemetry_report._gdn_lines(events))
 
 
 def test_convolution_is_the_explicit_four_tap_sum():
@@ -322,6 +427,7 @@ def test_three_sgd_steps_and_test_model_through_trainer_match_the_reference(
     # gauges, once: the recurrence's chunking, the causal kernels' tiles
     gauges = {(r["name"], r.get("kernel")): r["value"] for r in tel.records
               if r["kind"] == "gauge"}
+    assert gauges["gdn_kernel", None] == 0          # the CPU: jax.numpy
     assert gauges["gdn_chunk", None] == 8
     assert gauges["gdn_chunks_per_sequence", None] == 4
     assert all(gauges["attn_tiles_visited", k] == 1
@@ -331,7 +437,7 @@ def test_three_sgd_steps_and_test_model_through_trainer_match_the_reference(
     text = "\n".join(telemetry_report._gdn_lines(tel.records)
                      + telemetry_report._moe_lines(tel.records))
     assert "== linear attention ==" in text
-    assert "chunks of 8 positions, 4 a sequence" in text
+    assert "(jax.numpy) in chunks of 8 positions, 4 a sequence" in text
     assert f"predicted tokens {3 * b * 31:,}" in text
 
 

@@ -171,3 +171,29 @@ def test_large_zoo_models_compile_for_v5e8(v5e8_mesh):
     assert " all-reduce(" in txt
     txt = _compile_step(v5e8_mesh, resnet.ResNet34(), "ddp", 64)
     assert " all-reduce(" in txt
+
+
+def test_delta_rule_kernels_compile_for_a_v5e_under_their_scope(v5e8_mesh):
+    """ops/gdn.py's two Pallas kernels at the hybrid decoder's widths (8192
+    positions x 32 heads on 16 key heads x 128): Mosaic takes the forward kernel and, in the
+    gradient, the state-keeping forward and the backward one; each
+    custom-call is named `gdn_chunks_*` and keeps `gdn_recurrence` in its
+    op_name, which is how the benchmark's reader finds its time."""
+    from jax.sharding import SingleDeviceSharding
+    from cs744_ddp_tpu.ops import gdn
+    one = SingleDeviceSharding(v5e8_mesh.devices.flat[0])
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
+    x = (sds(8192, 16, 128),) * 2 + (sds(8192, 32, 128),) \
+        + (sds(8192, 32),) * 2
+    rule = lambda *a: gdn.delta_rule(*a, kernels=True)
+    grads = jax.grad(lambda *a: jnp.sum(rule(*a)), argnums=(0, 1, 2, 3, 4))
+    for f, kernels in ((rule, ["gdn_chunks_fwd"]),
+                       (grads, ["gdn_chunks_fwd", "gdn_chunks_bwd"])):
+        calls = [line for line in
+                 jax.jit(f).lower(*x).compile().as_text().splitlines()
+                 if "custom-call(" in line and "tpu_custom_call" in line]
+        assert len(calls) == len(kernels)
+        for line, name in zip(calls, kernels):
+            assert re.search(r"%?" + name + r"[.\d]* = ", line), line
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "gdn_recurrence" in op_name and name in op_name

@@ -525,15 +525,18 @@ def _attn_lines(events) -> list:
 
 def _gdn_lines(events) -> list:
     """A hybrid decoder's linear-attention layers, as built (ops/gdn.py):
-    the chunk the gated delta rule is computed in and the chunks the state
-    is carried through a sequence."""
+    which form of the gated delta rule runs, the chunk it is computed in
+    and the chunks the state is carried through a sequence."""
     g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
-         and e.get("name") in ("gdn_chunk", "gdn_chunks_per_sequence")}
+         and e.get("name") in ("gdn_kernel", "gdn_chunk",
+                               "gdn_chunks_per_sequence")}
     if not g:
         return []
+    form = "the Pallas kernels" if g.get("gdn_kernel") else "jax.numpy"
     return ["== linear attention ==",
-            f"  the gated delta rule in chunks of {g.get('gdn_chunk', 0):,} "
-            f"positions, {g.get('gdn_chunks_per_sequence', 0):,} a sequence",
+            f"  the gated delta rule ({form}) in chunks of "
+            f"{g.get('gdn_chunk', 0):,} positions, "
+            f"{g.get('gdn_chunks_per_sequence', 0):,} a sequence",
             ""]
 
 
