@@ -57,8 +57,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="vgg11/13/16/19, resnet18/34, a decoder of "
                         "models.DECODERS (sdar-30b-a3b: block diffusion, "
                         "MoE; qwen3-next-80b-a3b: linear + full attention, "
-                        "MoE with a shared expert; sdar-tiny / "
-                        "qwen3-next-tiny: their CPU test sizes; see "
+                        "MoE with a shared expert; xing4.0-29b-a4b: latent "
+                        "attention, a 4-stream hyper-connected residual, "
+                        "sigmoid-routed MoE; sdar-tiny / qwen3-next-tiny / "
+                        "xing4-tiny: their CPU test sizes; see "
                         "'decoder share'), or any name "
                         "registered via models.register_model (validated "
                         "by the model zoo, not argparse, so plugged-in "
@@ -71,11 +73,17 @@ def build_parser() -> argparse.ArgumentParser:
         "128, 4096 tokens in blocks of 4; qwen3-next-80b-a3b: 4 of 48 "
         "layers (whole periods of linear, linear, linear, full: the kind "
         "of a layer comes from the configuration), experts 0-31 of 512, "
-        "8192 tokens; both 18,992 rows of the vocabulary.  Data: "
+        "8192 tokens; both 18,992 rows of the vocabulary; xing4.0-29b-a4b: "
+        "5 of 40 layers of which the first is dense, experts 0-7 of 64, "
+        "4096 tokens, 16,384 rows.  Data: "
         "<data-dir>/tokens/train.npy + heldout.npy ([N, L] int32) or a "
         "synthetic stream; an epoch is --limit-train-batches steps of the "
         "stream")
     lm.add_argument("--lm-layers", type=int, default=None)
+    lm.add_argument("--lm-dense-layers", type=int, default=None,
+                    help="how many of --lm-layers, the leading ones, have "
+                         "a dense feed-forward (a decoder with leading "
+                         "dense layers only)")
     lm.add_argument("--lm-experts-held", default=None, metavar="IDS",
                     help="expert ids held here, e.g. 0-15 or 0,3,5")
     lm.add_argument("--lm-vocab", type=int, default=None,
@@ -354,6 +362,7 @@ def model_from_args(args):
     any of its share's flags the (init_fn, apply_fn) pair of that share."""
     share = {}
     for flag, field in (("lm_layers", "layers"), ("lm_vocab", "vocab"),
+                        ("lm_dense_layers", "dense_layers"),
                         ("lm_seq_len", "seq_len"), ("lm_block", "block")):
         if getattr(args, flag) is not None:
             share[field] = getattr(args, flag)

@@ -12,6 +12,8 @@ DECODERS = {
     "sdar-tiny": ("sdar", True),
     "qwen3-next-80b-a3b": ("qwen3next", False),     # linear + full attention
     "qwen3-next-tiny": ("qwen3next", True),
+    "xing4.0-29b-a4b": ("xing4", False),    # latent attention, 4 streams
+    "xing4-tiny": ("xing4", True),
 }
 
 # User-registered factories (name -> () -> (init_fn, apply_fn)); lets tests

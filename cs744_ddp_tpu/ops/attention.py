@@ -313,9 +313,9 @@ def causal_attention(q, k, v, *, kernels: bool, interpret: bool = False,
                      tile: int | None = None):
     """softmax(q k^T + causal) v over the P positions of each sequence.
 
-    q [S, HQ, P, D] ALREADY scaled; k, v [S, HKV, P, D]; each key/value
-    head serves HQ/HKV query heads.  Returns [S, HQ, P, D].  `kernels` and
-    `tile` as `blockdiff_attention`'s (`KERNEL_TILES` for the kernels)."""
+    q [S, HQ, P, D] ALREADY scaled; k [S, HKV, P, D], v [S, HKV, P, DV]
+    (DV may differ: ops/mla.py, 192 and 128), HQ/HKV query heads a key/value
+    head.  Returns [S, HQ, P, DV].  `kernels`, `tile`: as the other rule's."""
     s, hq, n, d = q.shape
     hkv = k.shape[1]
     qg = q.reshape(s, hkv, hq // hkv, n, d)
@@ -326,4 +326,4 @@ def causal_attention(q, k, v, *, kernels: bool, interpret: bool = False,
                 qg, k, v)
         else:
             out = _blocked_causal(qg, k, v, _tile(n, 1, tile or 128))
-    return out.reshape(s, hq, n, d)
+    return out.reshape(s, hq, n, v.shape[-1])

@@ -29,10 +29,10 @@ positions' choices, as before the ladder, on a long one (`_short`).
 Rows of the prefix past the live ones are never written by the kernels and
 hold whatever the memory held; every read of them is behind a select.
 
-A model with a SHARED expert (models/qwen3next.py) adds `shared_expert`
+A model with a SHARED expert adds `shared_expert` (models/qwen3next.py) or
+`shared_expert_ungated` (models/xing4.py; its router is `route_sigmoid`)
 beside the routed part: every position goes through it, every chip of the
-deployment computes it alike, and nothing of it is sorted or sliced.
-"""
+deployment computes it alike, nothing of it is sorted or sliced.  """
 
 from __future__ import annotations
 
@@ -270,18 +270,18 @@ def _on_ladder(rungs: tuple, grouped, dtype):
 
 
 def expert_layer(h, params, *, held, num_experts: int, top_k: int,
-                 kernels: bool, interpret: bool = False):
+                 kernels: bool, interpret: bool = False, scoring=None):
     """h [P, H] -> (this chip's part of the layer's output [P, H],
-    rows computed here, rows of the fullest held expert).
-
-    `params`: router [H, E]; w_gate, w_up [len(held), H, F]; w_down
-    [len(held), F, H].  `held`: the expert ids this chip holds, static."""
+    rows computed here, rows of the fullest held expert).  `params`: router
+    [H, E]; w_gate, w_up [len(held), H, F]; w_down [len(held), F, H].
+    `held`: the expert ids this chip holds, static.  `scoring(h, params,
+    top_k)`: another rule than `route` (`route_sigmoid`, below)."""
     if params["router"].shape[1] != num_experts or \
             not all(0 <= e < num_experts for e in held):
         raise ValueError(f"moe: a router over {params['router'].shape[1]} "
                          f"experts, num_experts {num_experts}, held {held}")
     with jax.named_scope("moe_route"):
-        top_e, weights = route(h, params["router"], top_k)
+        top_e, weights = (scoring or _route_softmax)(h, params, top_k)
         plan = local_plan(top_e, held)
     with jax.named_scope("moe_experts"):
         experts = _on_ladder(
@@ -305,3 +305,35 @@ def shared_expert(h, params):
         dot = lambda x, name: jnp.dot(x, params[name].astype(x.dtype))
         act = jax.nn.silu(dot(h, "shared_gate")) * dot(h, "shared_up")
         return dot(act, "shared_down") * jax.nn.sigmoid(dot(h, "shared_sig"))
+
+
+# -- a second scoring rule, an ungated shared expert (models/xing4.py) --------
+
+def _route_softmax(h, params, top_k: int):
+    return route(h, params["router"], top_k)
+
+
+def route_sigmoid(h, params, top_k: int, scale: float = 1.0):
+    """(expert ids [P, k], weights [P, k]): s = sigmoid(h W_r) over ALL
+    experts in float32; the k largest of s + `router_bias` (a selection
+    bias that only chooses: no gradient reaches it, and the weights are
+    made of s alone); w = scale * s[chosen] / (sum s[chosen] + 1e-20).
+    One group of experts (n_group = topk_group = 1: the group stage of
+    `noaux_tc` is the identity)."""
+    logits = jnp.dot(h, params["router"].astype(h.dtype),
+                     preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, top_e = lax.top_k(s + lax.stop_gradient(
+        params["router_bias"].astype(jnp.float32)), top_k)
+    top_s = jnp.take_along_axis(s, top_e, axis=-1)
+    return top_e, scale * top_s / (jnp.sum(top_s, -1, keepdims=True) + 1e-20)
+
+
+def shared_expert_ungated(h, params):
+    """`shared_expert` without the sigmoid gate: h [P, H] ->
+    W_d (silu(W_g h) * (W_u h)).  `params`: shared_gate, shared_up [H, F];
+    shared_down [F, H]."""
+    with jax.named_scope("moe_shared"):
+        dot = lambda x, name: jnp.dot(x, params[name].astype(x.dtype))
+        act = jax.nn.silu(dot(h, "shared_gate")) * dot(h, "shared_up")
+        return dot(act, "shared_down")

@@ -464,9 +464,11 @@ def test_every_decoder_of_the_table_resolves_at_its_published_share(name):
     shape = apply_fn.objective.shape
     params = jax.eval_shape(lambda k: init_fn(k)[0], jax.random.PRNGKey(0))
     n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
-    want = {"sdar-30b-a3b": 645_623_296, "qwen3-next-80b-a3b": 625_667_136}
+    want = {"sdar-30b-a3b": (645_623_296, 18992),
+            "qwen3-next-80b-a3b": (625_667_136, 18992),
+            "xing4.0-29b-a4b": (759_346_446, 16384)}
     if name in want:
-        assert n == want[name] and shape.vocab == 18992
+        assert (n, shape.vocab) == want[name]
     else:
         assert n < 1_000_000 and shape.vocab == 64
 

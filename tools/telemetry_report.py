@@ -540,6 +540,39 @@ def _gdn_lines(events) -> list:
             ""]
 
 
+def _mla_lines(events) -> list:
+    """A latent-attention decoder's attention, as built (ops/mla.py): the
+    form that runs and the key and value sizes it runs at."""
+    g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
+         and e.get("name") in ("mla_kernel", "mla_qk_dim", "mla_v_dim")}
+    if not g:
+        return []
+    form = "the Pallas kernels" if g.get("mla_kernel") else "jax.numpy"
+    return ["== latent attention ==",
+            f"  causal attention ({form}), a key/value head a query head: "
+            f"keys of {g.get('mla_qk_dim', 0):,}, values of "
+            f"{g.get('mla_v_dim', 0):,}",
+            ""]
+
+
+def _mhc_lines(events) -> list:
+    """A hyper-connected residual, as built (ops/hyper.py), and how far
+    from doubly stochastic the worst H_res of any step was (the step
+    events' `mhc_res_gap`, a maximum)."""
+    g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
+         and e.get("name") in ("mhc_streams", "mhc_sinkhorn_iters")}
+    if not g:
+        return []
+    gaps = [e["mhc_res_gap"] for e in events
+            if e.get("kind") == "step" and "mhc_res_gap" in e]
+    worst = f"{max(gaps):.3g} over {len(gaps):,} steps" if gaps else "n/a"
+    return ["== hyper-connections ==",
+            f"  {g.get('mhc_streams', 0):,} streams, "
+            f"{g.get('mhc_sinkhorn_iters', 0):,} Sinkhorn iterations a "
+            f"position; largest |row or column sum - 1| of H_res {worst}",
+            ""]
+
+
 def _loop_lines(events) -> list:
     """Dispatch-loop rendering: per span name of the default windowed path
     its count, median, longest and total per epoch, then every span that
@@ -668,6 +701,8 @@ def render(out_dir: str) -> str:
     lines.extend(_moe_lines(events))
     lines.extend(_attn_lines(events))
     lines.extend(_gdn_lines(events))
+    lines.extend(_mla_lines(events))
+    lines.extend(_mhc_lines(events))
     lines.extend(_wire_ext_lines(events))
 
     lines.extend(_serving_lines(events))
