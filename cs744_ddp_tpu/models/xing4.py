@@ -193,7 +193,8 @@ def _layer(shape: Shape, kernels: bool, feed_forward, rotary, x, p):
     """One sequence through one layer: x [n, P, C] -> (x, the feed-forward's
     three counts, the larger `res_gap` of the layer's two mixers)."""
     s = shape
-    hc = dict(iters=s.sinkhorn_iters, eps=s.hc_eps, clamp=s.res_clamp)
+    hc = dict(iters=s.sinkhorn_iters, eps=s.hc_eps, clamp=s.res_clamp,
+              kernels=kernels)
     norm = lambda a, gain: rmsnorm(a, gain, s.eps)
     positions, inv_freq, scale, attn_factor = rotary
 
@@ -210,9 +211,10 @@ def _layer(shape: Shape, kernels: bool, feed_forward, rotary, x, p):
 
 
 def make(shape: Shape = Shape(), kernels=None):
-    """(init_fn, apply_fn) for one share.  `kernels`: the Pallas attention
-    and grouped-matmul kernels; None = wherever the default backend is a
-    TPU (a deviceless compile for a described TPU passes True)."""
+    """(init_fn, apply_fn) for one share.  `kernels`: the Pallas attention,
+    grouped-matmul and hyper-connection kernels; None = wherever the
+    default backend is a TPU (a deviceless compile for a described TPU
+    passes True)."""
     s = shape
     if not 0 <= s.dense_layers <= s.layers or s.rope_dim % 2:
         raise ValueError(
@@ -290,13 +292,19 @@ class NextToken(qwen3next.NextToken):
         """Whether the attention runs as the Pallas kernels (1) or as
         jax.numpy (0), its key and value sizes, what the causal kernels'
         tiles cost at this length, the residual's width and its
-        iterations: a recorder's, once."""
+        iterations, whether the hyper-connections run as theirs (float32
+        streams: what `apply_fn` carries unless told a compute dtype) and
+        on which tile of positions: a recorder's, once."""
         s = self.shape
+        tile = hyper.plan((s.streams, self.seq_len, s.hidden), jnp.float32,
+                          self.kernels())
         return [("mla_kernel", int(self.kernels()), {}),
                 ("mla_qk_dim", s.nope_dim + s.rope_dim, {}),
                 ("mla_v_dim", s.v_dim, {}),
                 ("mhc_streams", s.streams, {}),
-                ("mhc_sinkhorn_iters", s.sinkhorn_iters, {})] \
+                ("mhc_sinkhorn_iters", s.sinkhorn_iters, {}),
+                ("mhc_kernel", int(tile > 0), {}),
+                ("mhc_tile", tile, {})] \
             + attention.causal_tile_gauges(self.seq_len)
 
     def loss(self, apply_fn, params, bn_state, x, labels=None,

@@ -197,3 +197,48 @@ def test_delta_rule_kernels_compile_for_a_v5e_under_their_scope(v5e8_mesh):
             assert re.search(r"%?" + name + r"[.\d]* = ", line), line
             op_name = re.search(r'op_name="([^"]*)"', line).group(1)
             assert "gdn_recurrence" in op_name and name in op_name
+
+
+def test_hyper_connection_kernels_compile_for_a_v5e_under_their_scope(
+        v5e8_mesh):
+    """ops/hyper.py's four Pallas kernels at the latent decoder's widths
+    (4 streams x 4096 x 3584) around a stand-in sublayer: Mosaic takes the
+    forward pair and, in the gradient, the backward pair beside it; every
+    custom-call is named `mhc_*` and keeps `mhc_mix` in its op_name, the
+    backward pair's inside the `custom_vjp`'s backward rule, which is how
+    the benchmark's reader finds their time; the sublayer's own product
+    does not."""
+    from jax.sharding import SingleDeviceSharding
+    from cs744_ddp_tpu.ops import hyper
+    one = SingleDeviceSharding(v5e8_mesh.devices.flat[0])
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
+    n, width = 4, 3584
+    x, w = sds(n, 4096, width), sds(width, width)
+    p = {"phi": sds(n * width, n * n + 2 * n), "alpha": sds(3),
+         "bias": sds(n * n + 2 * n)}
+
+    def connection(x, p, w):
+        def sublayer(h):
+            with jax.named_scope("stand_in"):
+                return jnp.dot(h, w), ()
+        return hyper.connect(sublayer, x, p, iters=20, eps=1e-6,
+                             clamp=(-30.0, 30.0), kernels=True)[0]
+    grads = jax.grad(lambda *a: jnp.sum(connection(*a) ** 2),
+                     argnums=(0, 1, 2))
+    for f, kernels in (
+            (connection, ["mhc_read_fwd", "mhc_write_fwd"]),
+            (grads, ["mhc_read_fwd", "mhc_write_fwd", "mhc_write_bwd",
+                     "mhc_read_bwd"])):
+        text = jax.jit(f).lower(x, p, w).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if "custom-call(" in line and "tpu_custom_call" in line]
+        assert len(calls) == len(kernels)
+        for line, name in zip(calls, kernels):
+            assert re.search(r"%?" + name + r"[.\d]* = ", line), line
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "mhc_mix" in op_name and name in op_name
+            assert ("transpose(jvp(mhc_mix))" in op_name) == \
+                name.endswith("_bwd")
+        inside = [m for m in re.findall(r'op_name="([^"]*)"', text)
+                  if "stand_in" in m]
+        assert inside and not any("mhc_mix" in m for m in inside)

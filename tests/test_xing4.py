@@ -184,8 +184,8 @@ def test_pallas_causal_kernels_at_keys_of_192_and_values_of_128_interpreted():
 
 # -- hyper-connections --------------------------------------------------------
 
-def hc_params(key, n=4, c=6):
-    return {"phi": 0.5 * jax.random.normal(key, (n * c, n * n + 2 * n)),
+def hc_params(key, n=4, c=6, std=0.5):
+    return {"phi": std * jax.random.normal(key, (n * c, n * n + 2 * n)),
             "alpha": jnp.asarray([0.7, 0.5, 0.9]),
             "bias": xing4.hc_bias(n)
             + 0.3 * jax.random.normal(jax.random.fold_in(key, 1),
@@ -266,24 +266,50 @@ def test_h_res_is_doubly_stochastic_after_20_iterations_and_not_after_one():
     assert np.all(np.isfinite(np.asarray(h)))
 
 
-def test_hyper_connections_gradient_matches_finite_differences():
+# The kernels' cases: channels in whole lanes and two tiles of positions; Phi
+# narrower by the width's root, so that m is as wide as at 6 channels.
+KERNEL_CASE = dict(positions=2 * hyper.KERNEL_TILE, width=128, std=0.1)
+HC = dict(iters=20, eps=1e-6, clamp=(-30.0, 30.0))
+FORMS = pytest.mark.parametrize(
+    "kernels,case", [(False, dict(positions=3, width=5, std=0.5)),
+                     (True, KERNEL_CASE)], ids=["jax.numpy", "kernels"])
+
+
+def interpreted(sublayer, x, p, kernels=True, **kw):
+    """`connect` with the kernels in Pallas' interpreter."""
+    return hyper.connect(sublayer, x, p, kernels=kernels, interpret=True,
+                         **dict(HC, **kw))
+
+
+def kernel_case(seed, positions=KERNEL_CASE["positions"],
+                width=KERNEL_CASE["width"]):
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(k[0], (4, positions, width)),
+            hc_params(k[1], c=width, std=KERNEL_CASE["std"]),
+            jax.random.normal(k[2], (width, width)) * width ** -0.5)
+
+
+@FORMS
+def test_hyper_connections_gradient_matches_finite_differences(kernels, case):
     """d loss / d (x, phi, alpha, bias) through the norm, the two sigmoids,
     the 20 iterations and the three mixes, against central differences of
-    the position-by-position loop in float64."""
-    p = hc_params(jax.random.PRNGKey(8), c=5)
-    x = jax.random.normal(jax.random.PRNGKey(9), (4, 3, 5))
-    w = jax.random.normal(jax.random.PRNGKey(10), (5, 5))
+    the position-by-position loop in float64: autodiff of the `jax.numpy`
+    form, and the kernels' own backward pair."""
+    c = case["width"]
+    p = hc_params(jax.random.PRNGKey(8), c=c, std=case["std"])
+    x = jax.random.normal(jax.random.PRNGKey(9), (4, case["positions"], c))
+    w = jax.random.normal(jax.random.PRNGKey(10), (c, c)) * (5 / c) ** 0.5
     w64 = np.asarray(w, np.float64)
 
     def loss(x, p):
-        out, _, _ = hyper.connect(
-            lambda h: (jnp.tanh(h @ w), ()), x, p, iters=20, eps=1e-6,
-            clamp=(-30.0, 30.0))
+        out, _, _ = interpreted(lambda h: (jnp.tanh(h @ w), ()), x, p,
+                                kernels=kernels)
         return jnp.sum(jnp.sin(out))
 
     def loop_loss(x, p):
         return float(np.sum(np.sin(connect_by_loop(
             x, p, lambda h: np.tanh(np.asarray(h, np.float64) @ w64)))))
+    assert bool(hyper.plan(x.shape, x.dtype, kernels)) == kernels
     gx, gp = jax.grad(loss, argnums=(0, 1))(x, p)
     rng = np.random.default_rng(0)
     as64 = lambda a: np.asarray(a, np.float64)
@@ -300,6 +326,110 @@ def test_hyper_connections_gradient_matches_finite_differences():
     for name in ("phi", "alpha", "bias"):
         probe(p[name], gp[name],
               lambda v, name=name: loop_loss(x, dict(p, **{name: v})))
+
+
+def test_the_kernels_are_the_jax_numpy_connection():
+    """X', the sublayer's input h and `res_gap`: the four Pallas kernels
+    in the interpreter, two tiles of positions, against the `jax.numpy`
+    form."""
+    x, p, w = kernel_case(11)
+    f = lambda h: (jnp.tanh(h @ w), h)
+    assert hyper.plan(x.shape, x.dtype, True) == hyper.KERNEL_TILE
+    want, h_want, gap_want = interpreted(f, x, p, kernels=False)
+    got, h_got, gap = interpreted(f, x, p)
+    assert got.shape == x.shape and got.dtype == x.dtype
+    assert close(got, want, 1e-5) and close(h_got, h_want, 1e-5)
+    assert close(got, connect_by_loop(
+        x, p, lambda h: np.tanh(h @ np.asarray(w, np.float64))), 1e-5)
+    assert 0 < float(gap) < 0.1
+    assert float(gap) == pytest.approx(float(gap_want), rel=1e-3)
+
+
+def test_the_kernels_gradients_are_autodiffs_on_every_leaf():
+    """x, the sublayer's own parameter (through y), phi, bias, alpha: the
+    backward pair against autodiff of the `jax.numpy` form."""
+    x, p, w = kernel_case(12)
+    co = jax.random.normal(jax.random.PRNGKey(13), x.shape)
+
+    def loss(kernels):
+        def f(x, p, w):
+            out, _, _ = interpreted(lambda h: (jnp.tanh(h @ w), ()), x, p,
+                                    kernels=kernels)
+            return jnp.sum(out * co)
+        return jax.grad(f, argnums=(0, 1, 2))
+    got, want = loss(True)(x, p, w), loss(False)(x, p, w)
+    flat = lambda g: {jax.tree_util.keystr(k): a for k, a in
+                      jax.tree_util.tree_leaves_with_path(g)}
+    got, want = flat(got), flat(want)
+    assert len(want) == 5 and got.keys() == want.keys()
+    for name in want:
+        assert got[name].shape == want[name].shape, name
+        assert close(got[name], want[name], 2e-5 * float(
+            jnp.max(jnp.abs(want[name])))), name
+
+
+def test_one_sinkhorn_iteration_shows_in_the_kernels_as_in_jax_numpy():
+    """A planted `sinkhorn_1` must stay visible: the iterations are the
+    configuration's, inside the kernel too."""
+    x, p, w = kernel_case(14)
+    p = dict(p, bias=xing4.hc_bias(4), alpha=jnp.full((3,), 0.01))
+    f = lambda h: (h @ w, ())
+    run = lambda kernels, iters: interpreted(f, x, p, kernels=kernels,
+                                             iters=iters)
+    one, gap_one = run(True, 1)[::2]
+    twenty, gap_twenty = run(True, 20)[::2]
+    assert close(one, run(False, 1)[0], 1e-5)
+    assert float(jnp.max(jnp.abs(one - twenty))) > 0.1
+    assert float(gap_one) > 0.05 and float(gap_twenty) < 1e-5
+
+
+@pytest.mark.parametrize("positions,width", [
+    (2 * hyper.KERNEL_TILE, 96), (hyper.KERNEL_TILE + 64, 128)],
+    ids=["channels-not-whole-lanes", "positions-not-whole-tiles"])
+def test_a_shape_the_kernels_refuse_takes_the_jax_numpy_form(positions,
+                                                              width):
+    """Bit for bit, values and gradients: nothing of the kernels runs."""
+    x, p, w = kernel_case(15, positions, width)
+    assert hyper.plan(x.shape, x.dtype, True) == 0
+    assert hyper.plan(x.shape, jnp.bfloat16, True) == 0
+
+    def run(kernels):
+        def f(x, p):
+            out, _, gap = hyper.connect(lambda h: (jnp.tanh(h @ w), ()), x,
+                                        p, kernels=kernels, **HC)
+            return jnp.sum(jnp.sin(out)), (out, gap)
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(x, p)
+    got, want = jax.tree.leaves(run(True)), jax.tree.leaves(run(False))
+    assert all(np.array_equal(a, b) for a, b in zip(got, want))
+
+
+def test_the_kernels_run_under_the_scope_the_readers_class_them_by():
+    """`benchmark/readers/lm.py` classes an instruction by the scope its
+    `op_name` holds.  All four kernels' bodies (interpreted here: their
+    operations carry the kernel's name), the backward pair's in the
+    `custom_vjp`'s backward rule, hold `mhc_mix`; the sublayer's own
+    operations, which the backward rule differentiates between the two,
+    do not."""
+    import re
+    x, p, w = kernel_case(16)
+
+    def sublayer(h):
+        with jax.named_scope("stand_in"):
+            return jnp.tanh(h @ w), ()
+    step = jax.jit(jax.grad(lambda x, p, w: jnp.sum(
+        interpreted(sublayer, x, p)[0] ** 2), argnums=(0, 1, 2)))
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           step.lower(x, p, w).compile().as_text()))
+    for kernel, backward in (("mhc_read_fwd", False), ("mhc_write_fwd", False),
+                             ("mhc_write_bwd", True), ("mhc_read_bwd", True)):
+        held = [n for n in names if f"/{kernel}/" in n]
+        assert held, kernel
+        assert all("mhc_mix" in n.split(f"/{kernel}/")[0] for n in held)
+        assert all(("transpose(jvp(mhc_mix))" in n) == backward for n in held)
+    inside = [n for n in names if "stand_in" in n]
+    assert any("transpose" in n for n in inside)
+    assert any("transpose" not in n for n in inside)
+    assert not any("mhc_mix" in n for n in inside)
 
 
 # -- sigmoid routing, the shares ----------------------------------------------
@@ -570,6 +700,7 @@ def test_sgd_steps_and_test_model_through_trainer_match_the_reference(
     assert gauges["mla_qk_dim", None] == 24 and gauges["mla_v_dim", None] == 16
     assert gauges["mhc_streams", None] == 4
     assert gauges["mhc_sinkhorn_iters", None] == 20
+    assert gauges["mhc_kernel", None] == 0 and gauges["mhc_tile", None] == 0
     assert all(gauges["attn_tiles_visited", k] == 1
                for k in ("fwd", "dkv", "dq"))
     sys.path.insert(0, os.path.join(ROOT, "tools"))
@@ -581,7 +712,12 @@ def test_sgd_steps_and_test_model_through_trainer_match_the_reference(
     assert "(jax.numpy), a key/value head a query head: keys of 24, " \
         "values of 16" in text
     assert "== hyper-connections ==" in text
-    assert "4 streams, 20 Sinkhorn iterations a position" in text
+    assert "4 streams (jax.numpy), 20 Sinkhorn iterations a position" in text
+    on = [dict(kind="gauge", name=name, value=v) for name, v in (
+        ("mhc_streams", 4), ("mhc_sinkhorn_iters", 20), ("mhc_kernel", 1),
+        ("mhc_tile", 128))]
+    assert "4 streams (the Pallas kernels on tiles of 128 positions), 20 " \
+        "Sinkhorn" in "\n".join(telemetry_report._mhc_lines(on))
     assert "over 3 steps" in text
     assert f"predicted tokens {3 * b * 31:,}" in text
 
@@ -594,9 +730,14 @@ def test_the_gauges_say_which_attention_runs_at_which_sizes():
     real = xing4.Shape()
     assert gauges(real, True) == {
         "mla_kernel": 1, "mla_qk_dim": 192, "mla_v_dim": 128,
-        "mhc_streams": 4, "mhc_sinkhorn_iters": 20}
-    assert gauges(real, False)["mla_kernel"] == 0
+        "mhc_streams": 4, "mhc_sinkhorn_iters": 20,
+        "mhc_kernel": 1, "mhc_tile": hyper.KERNEL_TILE}
+    off = gauges(real, False)
+    assert (off["mla_kernel"], off["mhc_kernel"], off["mhc_tile"]) == (0,) * 3
     assert gauges(TINY, None)["mla_kernel"] == 0    # the CPU
+    # 64 channels are no whole lane: jax.numpy, whatever the backend
+    assert gauges(TINY, True)["mla_kernel"] == 1
+    assert gauges(TINY, True)["mhc_kernel"] == 0
 
 
 def test_a_share_of_its_own_fields_and_leading_layers_within_the_layers():

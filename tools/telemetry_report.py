@@ -560,14 +560,17 @@ def _mhc_lines(events) -> list:
     from doubly stochastic the worst H_res of any step was (the step
     events' `mhc_res_gap`, a maximum)."""
     g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
-         and e.get("name") in ("mhc_streams", "mhc_sinkhorn_iters")}
+         and e.get("name") in ("mhc_streams", "mhc_sinkhorn_iters",
+                               "mhc_kernel", "mhc_tile")}
     if not g:
         return []
     gaps = [e["mhc_res_gap"] for e in events
             if e.get("kind") == "step" and "mhc_res_gap" in e]
     worst = f"{max(gaps):.3g} over {len(gaps):,} steps" if gaps else "n/a"
+    form = (f"the Pallas kernels on tiles of {g.get('mhc_tile', 0):,} "
+            "positions" if g.get("mhc_kernel") else "jax.numpy")
     return ["== hyper-connections ==",
-            f"  {g.get('mhc_streams', 0):,} streams, "
+            f"  {g.get('mhc_streams', 0):,} streams ({form}), "
             f"{g.get('mhc_sinkhorn_iters', 0):,} Sinkhorn iterations a "
             f"position; largest |row or column sum - 1| of H_res {worst}",
             ""]
