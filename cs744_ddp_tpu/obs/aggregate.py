@@ -95,7 +95,8 @@ def _windows(events, name) -> Dict[int, Tuple[float, float]]:
     out = {}
     for e in _traced_spans(events):
         if e.get("name") == name:
-            out[e["trace_id"]] = (e["t"], e["t"] + e.get("dur_s", 0.0))
+            t = e["t_ns"] / 1e9
+            out[e["trace_id"]] = (t, t + e.get("dur_ns", 0) / 1e9)
     return out
 
 
@@ -152,7 +153,7 @@ def merge_traces(streams: Sequence[ProcessStream],
                  offsets: Optional[Dict[str, ClockEstimate]] = None
                  ) -> Dict[int, List[Dict[str, Any]]]:
     """Group skew-corrected spans by trace_id.  Each returned span is a
-    COPY with ``t`` shifted onto the reference clock and a ``proc``
+    COPY with ``t_ns`` shifted onto the reference clock and a ``proc``
     field naming its source stream."""
     offsets = offsets if offsets is not None else estimate_offsets(streams)
     traces: Dict[int, List[Dict[str, Any]]] = {}
@@ -160,11 +161,11 @@ def merge_traces(streams: Sequence[ProcessStream],
         off = offsets.get(s.name, ClockEstimate(0.0, 0.0, 0, False)).offset_s
         for e in _traced_spans(s.events):
             rec = dict(e)
-            rec["t"] = e["t"] + off
+            rec["t_ns"] = e["t_ns"] + int(off * 1e9)
             rec["proc"] = s.name
             traces.setdefault(e["trace_id"], []).append(rec)
     for spans in traces.values():
-        spans.sort(key=lambda r: r["t"])
+        spans.sort(key=lambda r: r["t_ns"])
     return traces
 
 
@@ -183,7 +184,7 @@ def batch_span_index(streams: Sequence[ProcessStream],
             if e.get("kind") != "span" or e.get("name") not in _BATCH_SPANS:
                 continue
             rec = dict(e)
-            rec["t"] = e.get("t", 0.0) + off
+            rec["t_ns"] = e.get("t_ns", 0) + int(off * 1e9)
             rec["proc"] = s.name
             for bt in (e.get("traces") or ()):
                 index.setdefault(bt, []).append(rec)
@@ -203,7 +204,7 @@ def _build_waterfall(spans: List[Dict[str, Any]]) -> Dict[str, Any]:
         if e.get("origin"):
             origins.add(e["origin"])
         name = e.get("name")
-        dur_ms = e.get("dur_s", 0.0) * 1e3
+        dur_ms = e.get("dur_ns", 0) / 1e6
         if name == CLIENT_SPAN:
             client_ms = dur_ms
         elif name == FRONTEND_SPAN:
@@ -221,7 +222,7 @@ def _build_waterfall(spans: List[Dict[str, Any]]) -> Dict[str, Any]:
     # spans were pre-joined by the caller (their ``traces`` attr).
     for name, e in batch.items():
         stage = _SPAN_TO_STAGE[name]
-        stages[stage] = stages.get(stage, 0.0) + e.get("dur_s", 0.0) * 1e3
+        stages[stage] = stages.get(stage, 0.0) + e.get("dur_ns", 0) / 1e6
     ordered = {s: round(stages[s], 3) for s in STAGE_ORDER if s in stages}
     total = sum(ordered.values())
     # Complete = the client saw a reply AND the device ran the request.

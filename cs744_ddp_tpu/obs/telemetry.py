@@ -7,17 +7,18 @@ Three primitives, one file format:
   excluded, mirroring ``WindowedTimers``), epoch and iteration number.
 * **spans**   — named host intervals (``kind: "span"``): the dispatch
   loop's phases (README "Observability" lists them), host augment, prefetch
-  put, compile/warmup, checkpoint save.  Spans nest; each record carries
-  ``id`` / ``parent_id`` (integers, per recorder), ``t_ns`` (the start in
-  Unix nanoseconds, ``time.time_ns()``: the clock the profiler stamps the
-  xplane with), ``dur_ns`` (from ``time.perf_counter_ns()``), the older
-  ``t`` / ``dur_s`` / ``depth`` / ``parent`` (name) derived from the same
-  readings, and its attributes.  The span stack is thread-local because
-  the host-augment producer runs on its own thread.  A ``span()`` also
-  opens a ``jax.profiler.TraceAnnotation`` of the same name (where jax is
-  already imported: this module never imports it), so a profiler session
-  shows the span under the device lines; outside a session that twin is a
-  flag test.  Every span an enabled recorder emits also goes to the
+  put, compile/warmup, checkpoint save, the trainer's construction, and
+  jax's own trace / lowering / compile of every program
+  (``utils/compcache.py``).  Spans nest; each record carries ``id`` /
+  ``parent_id`` (integers, per recorder; no ``parent_id`` at the root),
+  ``t_ns`` (the start in Unix nanoseconds, ``time.time_ns()``: the clock
+  the profiler stamps the xplane with), ``dur_ns`` (from
+  ``time.perf_counter_ns()``) and its attributes.  The span stack is
+  thread-local because the host-augment producer runs on its own thread.
+  A ``span()`` also opens a ``jax.profiler.TraceAnnotation`` of the same
+  name (where jax is already imported: this module never imports it), so
+  a profiler session shows the span under the device lines; outside a
+  session that twin is a flag test.  Every span an enabled recorder emits also goes to the
   process-wide bounded ``span_log()``.
 * **gauges/counters** — point-in-time values (``kind: "gauge"``) and
   monotonic tallies (``kind: "counter"``): prefetch queue depth, native-
@@ -246,7 +247,7 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
-        self.id = self._tel._push(self.name)
+        self.id = self._tel._push()
         self._twin = _open_twin(self.name, self.id)
         self.t_ns = time.time_ns()
         self._p0 = time.perf_counter_ns()
@@ -256,12 +257,12 @@ class _Span:
         dur_ns = time.perf_counter_ns() - self._p0
         if self._twin is not None:
             self._twin.__exit__(exc_type, *exc)
-        parent, depth = self._tel._pop()
+        self._tel._pop()
         rec = {"kind": "span", "name": self.name, "id": self.id,
-               "t": self.t_ns / 1e9, "dur_s": dur_ns / 1e9,
-               "t_ns": self.t_ns, "dur_ns": dur_ns, "depth": depth}
+               "t_ns": self.t_ns, "dur_ns": dur_ns}
+        parent = self._tel.open_span_id()
         if parent is not None:
-            rec["parent"], rec["parent_id"] = parent
+            rec["parent_id"] = parent
         if exc_type is not None:
             rec["error"] = exc_type.__name__
         if self.attrs:
@@ -305,27 +306,31 @@ class Telemetry:
             if os.path.exists(self._events_path):   # append to a prior run
                 self._event_bytes = os.path.getsize(self._events_path)
             self._fh = open(self._events_path, "a", buffering=1)
+        from ..utils import compcache   # utils imports obs: not at the top
+        compcache.attach(self)
 
     # -- span stack (per thread) -------------------------------------------
 
-    def _stack(self) -> List[Tuple[str, int]]:
+    def _stack(self) -> List[int]:
         st = getattr(self._tls, "stack", None)
         if st is None:
             st = self._tls.stack = []
         return st
 
-    def _push(self, name: str) -> int:
+    def _push(self) -> int:
         """Open a span on this thread's stack; returns its id."""
         span_id = next(self._span_ids)
-        self._stack().append((name, span_id))
+        self._stack().append(span_id)
         return span_id
 
-    def _pop(self) -> Tuple[Optional[Tuple[str, int]], int]:
-        """Close the innermost span -> ((parent name, parent id) or None,
-        depth)."""
+    def _pop(self) -> None:
+        """Close the innermost span of this thread."""
+        self._stack().pop()
+
+    def open_span_id(self) -> Optional[int]:
+        """The id of the innermost span open on the calling thread."""
         st = self._stack()
-        st.pop()
-        return (st[-1] if st else None), len(st)
+        return st[-1] if st else None
 
     # -- emission -----------------------------------------------------------
 
@@ -408,15 +413,18 @@ class Telemetry:
                    **attrs) -> None:
         """Record an ALREADY-MEASURED interval as a span event.  Unlike
         ``span()`` (a context manager bound to one thread's span stack)
-        this suits asynchronous intervals whose endpoints live on
-        different threads or came off the wire — a client round-trip, a
-        queue wait — so depth is 0 and parenting comes from the caller's
-        trace attrs, not the thread-local stack.  ``t0`` is Unix seconds
-        (``time.time()``); an interval that is over when it is recorded
-        gets no twin in the profiler's trace."""
+        this suits intervals whose endpoints live on different threads or
+        came off the wire — a client round-trip, a queue wait, a compile
+        jax reports when it is over.  Its parent is the span open on the
+        calling thread, if any (a request's own parenting travels in the
+        caller's trace attrs).  ``t0`` is Unix seconds (``time.time()``);
+        an interval that is over when it is recorded gets no twin in the
+        profiler's trace."""
         rec = {"kind": "span", "name": name, "id": next(self._span_ids),
-               "t": float(t0), "dur_s": float(dur_s),
-               "t_ns": int(t0 * 1e9), "dur_ns": int(dur_s * 1e9), "depth": 0}
+               "t_ns": int(t0 * 1e9), "dur_ns": int(dur_s * 1e9)}
+        parent = self.open_span_id()
+        if parent is not None:
+            rec["parent_id"] = parent
         if attrs:
             rec.update(attrs)
         self._emit(rec)
@@ -498,7 +506,7 @@ def summarize_events(events: List[Dict[str, Any]],
         if e.get("kind") == "span":
             agg = spans.setdefault(e["name"], {"count": 0, "total_s": 0.0})
             agg["count"] += 1
-            agg["total_s"] += e.get("dur_s", 0.0)
+            agg["total_s"] += e.get("dur_ns", 0) / 1e9
     counters: Dict[str, float] = {}
     for e in events:
         if e.get("kind") == "counter":

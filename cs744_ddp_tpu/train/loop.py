@@ -22,6 +22,7 @@ Differences from the reference, by design (all documented in BASELINE.md):
 
 from __future__ import annotations
 
+import functools
 import os
 import queue
 import signal
@@ -129,9 +130,20 @@ def emit_memory_gauges(telemetry, **attrs) -> None:
     telemetry.gauge("memory", payload, **attrs)
 
 
+def _init_span(init):
+    """Run ``Trainer.__init__`` inside one ``trainer_init`` span of its own
+    recorder (a no-op through ``NULL``)."""
+    @functools.wraps(init)
+    def traced(self, *args, telemetry=NULL, **kw):
+        with telemetry.span("trainer_init"):
+            init(self, *args, telemetry=telemetry, **kw)
+    return traced
+
+
 class Trainer:
     """Wires data + model + strategy + mesh into the reference's run()."""
 
+    @_init_span
     def __init__(self, model: str = "vgg11", strategy: str = "allreduce",
                  *, mesh=None, num_devices: Optional[int] = None,
                  compress_rank: Optional[int] = None,
@@ -336,12 +348,14 @@ class Trainer:
                     f"model {self.model_name!r} trains on the default "
                     f"windowed path only; not with {unsupported}")
             from ..data import tokens
-            self.train_split, self.test_split, self.real_data = tokens.load(
-                data_dir, self.objective.seq_len, self.objective.vocab - 1,
-                seed)
+            with telemetry.span("load_splits"):
+                self.train_split, self.test_split, self.real_data = \
+                    tokens.load(data_dir, self.objective.seq_len,
+                                self.objective.vocab - 1, seed)
         else:
-            self.train_split, self.test_split, self.real_data = \
-                cifar10.load(data_dir)
+            with telemetry.span("load_splits"):
+                self.train_split, self.test_split, self.real_data = \
+                    cifar10.load(data_dir)
         # Reference parity: these lines print len(train_loader) — the
         # per-rank BATCH count, not the example count (Part 2a/main.py:46,55).
         def ceil_div(a, b):
@@ -369,8 +383,10 @@ class Trainer:
         strat = self._strategy = get_strategy(
             strategy, **({} if compress_rank is None
                          else {"compress_rank": compress_rank}))
-        self.state = steplib.init_train_state(
-            init_fn, jax.random.PRNGKey(self.init_seed), strat, self.world)
+        with telemetry.span("init_state"):
+            self.state = steplib.init_train_state(
+                init_fn, jax.random.PRNGKey(self.init_seed), strat,
+                self.world)
         # Commit the state to the mesh up front: otherwise the first
         # windowed call sees uncommitted arrays and the second call a
         # different sharding signature -> a full recompile.  Everything is
@@ -1115,8 +1131,9 @@ class Trainer:
         on = tel.enabled
         span = self._loop_span
         clock = time.perf_counter_ns    # the parity timers' own readings
-        if on:
-            self._emit_collective_telemetry()
+        if on and not self._collective_stats_emitted:
+            with span("obs_emit"):  # lowers the step: a disabled run does not
+                self._emit_collective_telemetry()
         timers = WindowedTimers(self.log, telemetry=tel, epoch=epoch)
         key = jax.random.fold_in(jax.random.PRNGKey(self.seed), epoch)
         with span("stage_lookup"):

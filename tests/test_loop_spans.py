@@ -51,7 +51,7 @@ def make_trainer(tmp_path, mesh, recorder, n_train, **kw):
 
 def spans_of(recorder, epoch=None):
     return [r for r in recorder.records if r["kind"] == "span"
-            and (epoch is None or r["epoch"] == epoch)]
+            and (epoch is None or r.get("epoch", -1) == epoch)]
 
 
 def counts(recorder, name, epoch):
@@ -91,12 +91,14 @@ def test_epoch_yields_exactly_the_span_tree(tmp_path, mesh4, n_train, windows,
             if s["name"] == "compile_warmup":
                 assert parent["name"] in WARMUP_PARENTS
                 continue
+            if s["name"] == "obs_emit" and parent["name"] == "epoch_train":
+                assert epoch == 0       # the collective statistics, once
+                continue
             want = expect[s["name"]]
             if want is None:
-                assert "parent_id" not in s and s["depth"] == 0
+                assert "parent_id" not in s
                 continue
-            # parents by id, and the name the older readers use agrees
-            assert parent["name"] == want == s["parent"]
+            assert parent["name"] == want
             assert parent["id"] < s["id"]
             # children lie inside their parents in time
             assert parent["t_ns"] <= s["t_ns"]
@@ -105,7 +107,8 @@ def test_epoch_yields_exactly_the_span_tree(tmp_path, mesh4, n_train, windows,
         per_window = ("train_window", "window_dispatch", "window_drain",
                       "window_host", "obs_emit")
         for name in expect:
-            assert names.count(name) == (windows if name in per_window else 1)
+            assert names.count(name) == (windows if name in per_window else 1) \
+                + (name == "obs_emit" and epoch == 0)
         # one program in flight at a time: dispatches and fetches alternate
         marks = [n for n in names if n.endswith(("_dispatch", "_drain",
                                                  "_fetch"))]
@@ -129,7 +132,7 @@ def test_train_window_keeps_its_attributes_and_eval_its_units_epoch(
     tel = Telemetry()
     tr = make_trainer(tmp_path, mesh4, tel, 200)
     tr.test_model()                 # before any train_model: no epoch yet
-    assert {s["epoch"] for s in spans_of(tel)} == {None}
+    assert {s["epoch"] for s in spans_of(tel) if "epoch" in s} == {None}
     tr.train_model(7)
     tr.test_model()
     (win,) = [s for s in spans_of(tel, 7) if s["name"] == "train_window"]
@@ -146,17 +149,19 @@ def test_span_record_fields_and_clocks():
         with tel.span("inner"):
             time.sleep(0.002)
     tel.span_event("waited", time.time(), 0.25, trace_id=9)
+    with tel.span("open"):          # an interval recorded after the fact
+        tel.span_event("over", time.time(), 0.001)  # under the open span
     after = time.time_ns()
-    inner, outer, event = spans_of(tel)
+    inner, outer, event, over, open_ = spans_of(tel)
     assert (outer["id"], inner["id"], event["id"]) == (1, 2, 3)
-    assert inner["parent_id"] == 1 and inner["parent"] == "outer"
+    assert inner["parent_id"] == 1
     assert "parent_id" not in outer and "parent_id" not in event
-    for s in (inner, outer, event):
+    assert over["parent_id"] == open_["id"]
+    for s in (inner, outer, event, over, open_):
         assert isinstance(s["t_ns"], int) and isinstance(s["dur_ns"], int)
         assert before <= s["t_ns"] <= after          # the Unix clock
-        # the older fields are derived from the same two readings
-        assert s["t"] == pytest.approx(s["t_ns"] / 1e9, abs=1e-6)
-        assert s["dur_s"] == pytest.approx(s["dur_ns"] / 1e9, abs=1e-9)
+        # written once: no seconds, depth or parent name beside them
+        assert not {"t", "dur_s", "depth", "parent"} & s.keys()
     assert inner["dur_ns"] >= 2_000_000
     assert outer["dur_ns"] >= inner["dur_ns"]
     assert event["dur_ns"] == 250_000_000 and event["trace_id"] == 9
@@ -180,9 +185,10 @@ def test_span_ends_never_run_backwards_within_a_thread(tmp_path, mesh4):
     tr.train_model(0)
     tr.test_model()
     t.join()
+    # the loop's own spans (jax's compile spans carry jax's clock)
     for mine in (True, False):
         ends = [s["t_ns"] + s["dur_ns"] for s in spans_of(tel)
-                if (s["name"] == "worker") != mine]
+                if "epoch" in s and (s["name"] == "worker") != mine]
         assert len(ends) >= 15
         assert ends == sorted(ends)   # records are emitted as spans close
 
@@ -233,7 +239,7 @@ def test_twin_lands_in_a_real_profiler_trace(tmp_path):
     planes = list(ProfileData.from_file(path).planes)
     twins = [e for plane in planes for line in plane.lines
              for e in line.events if e.name == "window_host"]
-    (rec,) = spans_of(tel)
+    (rec,) = [s for s in spans_of(tel) if s["name"] == "window_host"]
     assert len(twins) == 1 and dict(twins[0].stats)["span_id"] == rec["id"]
     # The xplane counts from the session's start, which it records in Unix
     # nanoseconds: on that clock the twin starts within a millisecond of

@@ -572,7 +572,7 @@ def test_pipelined_occupancy_bound_and_span_causality(pool):
     assert [d["traces"] for d in dspans] == want
     assert [f["traces"] for f in fspans] == want
     for prev, nxt in zip(dspans, dspans[1:]):
-        assert nxt["t"] >= prev["t"] + prev["dur_s"] - 1e-6
+        assert nxt["t_ns"] >= prev["t_ns"] + prev["dur_ns"] - 1000
 
 
 def test_telemetry_report_pipeline_section(tmp_path, monkeypatch):

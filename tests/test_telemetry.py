@@ -62,8 +62,10 @@ def test_summarize_events_hand_computed():
     # Warmup steps: counted in num_steps and losses, NOT in steady stats.
     events.append({"kind": "step", "epoch": 0, "iter": 1, "loss": 99.0,
                    "step_time_s": 5.0, "steady": False})
-    events.append({"kind": "span", "name": "host_augment", "dur_s": 0.5})
-    events.append({"kind": "span", "name": "host_augment", "dur_s": 0.25})
+    events.append({"kind": "span", "name": "host_augment",
+                   "dur_ns": 500_000_000})
+    events.append({"kind": "span", "name": "host_augment",
+                   "dur_ns": 250_000_000})
     events.append({"kind": "counter", "name": "c", "inc": 2, "total": 2})
     events.append({"kind": "counter", "name": "c", "inc": 3, "total": 5})
 
@@ -125,7 +127,9 @@ def test_file_backed_round_trip(tmp_path):
                                    "t": by_kind["gauge"][0]["t"], "value": 3,
                                    "window": 1}
     assert [c["total"] for c in by_kind["counter"]] == [10, 15]
-    assert {"name", "t", "dur_s", "depth"} <= by_kind["span"][0].keys()
+    # a span is written once: ids and nanosecond clocks, no duplicates
+    assert {"name", "id", "t_ns", "dur_ns"} <= by_kind["span"][0].keys()
+    assert not {"t", "dur_s", "depth", "parent"} & by_kind["span"][0].keys()
 
     assert summary["num_steps"] == 2 and summary["num_steady_steps"] == 1
     assert summary["counters"] == {"bytes": 15}
@@ -145,13 +149,11 @@ def test_span_nesting_and_thread_local_stack():
         with tel.span("inner", window=3):
             pass
     recs = {r["name"]: r for r in tel.records if r["kind"] == "span"}
-    assert recs["worker"]["depth"] == 0
-    assert "parent" not in recs["worker"]
-    assert recs["inner"]["depth"] == 1
-    assert recs["inner"]["parent"] == "outer"
+    assert "parent_id" not in recs["worker"]
+    assert recs["inner"]["parent_id"] == recs["outer"]["id"]
     assert recs["inner"]["window"] == 3            # attrs pass through
-    assert recs["outer"]["depth"] == 0
-    assert all(r["dur_s"] >= 0 for r in recs.values())
+    assert "parent_id" not in recs["outer"]
+    assert all(r["dur_ns"] >= 0 for r in recs.values())
 
 
 def test_span_records_error_and_reraises():
@@ -306,8 +308,8 @@ def test_trainer_host_augment_pipeline_telemetry(tmp_path, mesh4):
     assert all(s["batches"] >= 1 for s in by_name["chunk_put"])
     assert any(s["last"] for s in by_name["chunk_put"])  # window boundary
     # The producer thread has its own span stack: these are top-level.
-    assert all(s["depth"] == 0 for s in by_name["host_augment"])
-    assert all(s["depth"] == 0 for s in by_name["chunk_put"])
+    assert all("parent_id" not in s for s in by_name["host_augment"])
+    assert all("parent_id" not in s for s in by_name["chunk_put"])
     # Consumer-side stall probe + pipeline gauges.
     assert by_name["chunk_wait"]
     depths = [r["value"] for r in tel.records
@@ -433,23 +435,28 @@ def test_telemetry_report_renders_loop_section(tmp_path, monkeypatch):
     monkeypatch.syspath_prepend(os.path.join(repo, "tools"))
     import telemetry_report
 
+    ids = iter(range(1, 100))
+
     def record(name, dur_ms, epoch, parent=None):
-        rec = {"kind": "span", "name": name, "t_ns": 10 ** 18 + epoch,
-               "dur_ns": int(dur_ms * 1e6), "epoch": epoch}
+        rec = {"kind": "span", "name": name, "id": next(ids),
+               "t_ns": 10 ** 18 + epoch, "dur_ns": int(dur_ms * 1e6),
+               "epoch": epoch}
         if parent:
-            rec["parent"] = parent
+            rec["parent_id"] = parent["id"]
         return rec
 
     events = []
     for epoch in range(4):
-        events += [record("epoch_train", 700, epoch),
-                   record("window_dispatch", 2, epoch, "train_window"),
-                   record("window_drain", 600, epoch, "train_window"),
+        train = record("epoch_train", 700, epoch)
+        window = record("train_window", 650, epoch, train)
+        events += [train, window,
+                   record("window_dispatch", 2, epoch, window),
+                   record("window_drain", 600, epoch, window),
                    record("window_host", 41.5 if epoch == 2 else 1.5, epoch,
-                          "epoch_train"),
-                   record("eval_fetch", 45, epoch, "eval")]
-    events.append({"kind": "span", "name": "host_augment", "t": 1.0,
-                   "dur_s": 9.0})          # not a span of this section
+                          train),
+                   record("eval_fetch", 45, epoch)]
+    events.append({"kind": "span", "name": "host_augment", "t_ns": 10 ** 9,
+                   "dur_ns": 9 * 10 ** 9})  # not a span of this section
     lines = telemetry_report._loop_lines(events)
     assert lines[0] == "== loop (dispatch-loop spans, 4 epoch(s)) =="
     rows = {l.split()[0]: l.split() for l in lines[2:] if l.strip()}

@@ -215,8 +215,8 @@ def test_wire_reply_compat_both_directions():
 
 
 def _span(name, t, dur, ctx, **extra):
-    return {"kind": "span", "name": name, "t": t, "dur_s": dur,
-            **ctx.attrs(), **extra}
+    return {"kind": "span", "name": name, "t_ns": round(t * 1e9),
+            "dur_ns": round(dur * 1e9), **ctx.attrs(), **extra}
 
 
 def _stream_pair(n=20, offset=5.0, d_req=0.001, d_rep=0.009):

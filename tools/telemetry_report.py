@@ -583,10 +583,12 @@ def _loop_lines(events) -> list:
     as one slow ``window_host`` or ``*_fetch``, not as a slower median).
     Returns [] for runs without these spans (host-fed, per-step, serving,
     runs recorded before the spans existed)."""
-    by_name = {}
+    by_name, name_of = {}, {}
     for e in events:
-        if e.get("kind") == "span" and e.get("name") in LOOP_SPANS \
-                and "dur_ns" in e:
+        if e.get("kind") != "span":
+            continue
+        name_of[e.get("id")] = e.get("name")
+        if e.get("name") in LOOP_SPANS and "dur_ns" in e:
             by_name.setdefault(e["name"], []).append(e)
     if not by_name:
         return []
@@ -609,7 +611,8 @@ def _loop_lines(events) -> list:
     for e, p50 in sorted(slow, key=lambda x: x[0].get("t_ns", 0)):
         lines.append(f"  slow: {e['name']} {e['dur_ns'] / 1e6:.3f} ms "
                      f"(+{e['dur_ns'] / 1e6 - p50:.3f} over its median) "
-                     f"epoch {e.get('epoch')} parent {e.get('parent')}")
+                     f"epoch {e.get('epoch')} "
+                     f"parent {name_of.get(e.get('parent_id'))}")
     lines.append("")
     return lines
 
