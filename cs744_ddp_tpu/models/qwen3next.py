@@ -159,18 +159,13 @@ def _gdn_mixer(shape: Shape, kernels: bool, h, p):
     with jax.named_scope("attn_gdn"):
         qkvz = jnp.dot(h, p["w_qkvz"].astype(h.dtype))
         ba = jnp.dot(h, p["w_ba"].astype(h.dtype)).astype(f32)
-        u = jax.nn.silu(gdn.causal_conv(qkvz[:, :2 * kd + vd], p["conv"]))
-        z = qkvz[:, 2 * kd + vd:]
+        q, k, v, z = gdn.front(qkvz, p["conv"], kd, dk, kernels=kernels)
         beta = jax.nn.sigmoid(ba[:, :hv])
         g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[:, hv:] + p["dt_bias"])
-        heads = lambda x, n, d: x.reshape(P, n, d).astype(f32)
-        l2 = lambda x: x * lax.rsqrt(
-            jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-        q = l2(heads(u[:, :kd], hk, dk)) * dk ** -0.5
-        k = l2(heads(u[:, kd:2 * kd], hk, dk))
         # a key head serves hv / hk value heads in a row (ops/gdn.py)
-        o = gdn.delta_rule(q, k, heads(u[:, 2 * kd:], hv, dv), g, beta,
-                           gdn.chunk_for(P), kernels=kernels)   # [P, hv, dv]
+        o = gdn.delta_rule(q.reshape(P, hk, dk), k.reshape(P, hk, dk),
+                           v.reshape(P, hv, dv), g, beta, gdn.chunk_for(P),
+                           kernels=kernels)                     # [P, hv, dv]
         y = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True) + s.eps)
         y = y * p["gdn_norm"] * jax.nn.silu(z.reshape(P, hv, dv).astype(f32))
         return jnp.dot(y.reshape(P, vd).astype(h.dtype),
@@ -299,13 +294,19 @@ class NextToken:
     @functools.cached_property
     def gauges(self):
         """Which form of the recurrence runs (1 the Pallas kernels, 0 the
-        jax.numpy one) and its chunking; what the causal kernels' tiles
-        cost at this length (the TPU's path): a recorder's, once."""
+        jax.numpy one) and its chunking; which form of the mixer's front
+        (the same, for a float32 projection: what `apply_fn` computes
+        unless told a compute dtype); what the causal kernels' tiles cost
+        at this length (the TPU's path): a recorder's, once."""
         s = self.shape
         fused, c = gdn.plan(self.seq_len, s.lin_key_dim, s.lin_value_dim,
                             self.kernels())
+        front = gdn.front_plan(self.seq_len, s.lin_key_dim,
+                               s.lin_value_heads * s.lin_value_dim,
+                               jnp.float32, self.kernels())
         return [("gdn_kernel", int(fused), {}), ("gdn_chunk", c, {}),
-                ("gdn_chunks_per_sequence", -(-self.seq_len // c), {})] \
+                ("gdn_chunks_per_sequence", -(-self.seq_len // c), {}),
+                ("gdn_front_kernel", int(front > 0), {})] \
             + attention.causal_tile_gauges(self.seq_len)
 
     def prepare(self, key, tokens, augment=None, compute_dtype=None):

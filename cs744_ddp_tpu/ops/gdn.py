@@ -69,11 +69,34 @@ plain reference it is compared with is a float32 loop.  Every exponent is
 of a difference G_t - G_s with s <= t, never positive.  A length that is
 not whole chunks (segments) is padded with positions that write nothing
 (beta 0) and do not decay (g 0).
+
+THE FRONT (`front`): what the mixer does between its projection and the
+recurrence, the causal convolution of 4 taps over the first 2 kd + vd
+channels of the projection, `silu`, the split into q, k, v, the l2 norm of
+every key head of q and of k and q's scale dk^-1/2.  On the TPU with key
+heads and value channels in whole lanes and the length in whole tiles of
+`FRONT_TILE` (`front_plan`), two Pallas kernels under one
+`jax.custom_vjp`, both under the scope `gdn_conv`: `gdn_front_fwd` walks
+tiles of positions, reads the projection's channels in place (the block's
+channel index; the 8 positions before the tile as a second small block,
+for the taps' halo), convolves, applies `silu` and the norms, and writes
+q, k, v as the recurrence's kernels read them: a loop over each part's
+groups of `FRONT_LANES` lanes (whole key heads), `FRONT_STRIPS` strips of 8
+positions a turn (the taps as sublane rolls; independent strips for the
+scheduler: PERF.md section 6, PR 38); `gdn_front_bwd` walks the tiles in
+reverse, recomputes all that from the same input, pulls dq, dk, dv back
+through it, hands the cotangent of a tile's first positions to the tile
+before in a VMEM scratch, sums the weight's cotangent over the tiles in its
+resident output block, and writes the projection's cotangent once: its
+first channels from the kernel, the rest (z's) by an update in place.  Float32
+elementwise, in the jax.numpy form's order of terms; nothing kept but the
+projection and the weight.  Everywhere else that jax.numpy form.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -438,3 +461,343 @@ def delta_rule(q, k, v, g, beta, chunk: int = CHUNK, segment: int = SEGMENT,
         return jnp.moveaxis(_chunked(
             per_value_head(q), per_value_head(k), heads_first(v), g.T, beta.T,
             chunk, segment), 0, 1)
+
+
+# -- the front: convolution, silu, the q / k norms ----------------------------
+
+FRONT_TILE = 128        # positions a grid step of the front's kernels
+FRONT_LANES = 256       # lanes a turn of a kernel's loop takes at most
+FRONT_STRIPS = 4        # strips of 8 positions a turn takes, one by one
+FRONT_VMEM = 64 * 2 ** 20
+_LANES = 128
+_STRIP = 8
+_L2_EPS = 1e-6
+
+
+def front_plan(positions: int, dk: int, value_width: int, dtype,
+               kernels: bool) -> int:
+    """The positions a grid step of the front's kernels, or 0 where the
+    jax.numpy front runs: `kernels` as `plan` takes it, key heads (`dk`)
+    and the value channels in whole lanes, the length in whole tiles, a
+    float32 projection."""
+    fits = (kernels and kernel_fits(dk, value_width) and dtype == jnp.float32
+            and positions % FRONT_TILE == 0)
+    return FRONT_TILE if fits else 0
+
+
+def _front_plain(x, w, key_width: int, dk: int):
+    """The jax.numpy front (`front`)."""
+    positions, channels = x.shape[0], w.shape[1]
+    u = jax.nn.silu(causal_conv(x[:, :channels], w))
+    heads = lambda a: a.reshape(positions, -1, dk).astype(jnp.float32)
+    l2 = lambda a: a * lax.rsqrt(
+        jnp.sum(jnp.square(a), -1, keepdims=True) + _L2_EPS)
+    q = l2(heads(u[:, :key_width])) * dk ** -0.5
+    k = l2(heads(u[:, key_width:2 * key_width]))
+    return (q.reshape(positions, key_width), k.reshape(positions, key_width),
+            u[:, 2 * key_width:].astype(jnp.float32), x[:, channels:])
+
+
+class _Front(NamedTuple):
+    """What the front's kernels are built for, besides their operands'
+    shapes."""
+    key_width: int
+    dk: int
+    tile: int
+    interpret: bool
+
+
+def _width(total: int, unit: int) -> int:
+    """Whole `unit`s, `FRONT_LANES` at most if that divides `total`."""
+    width = max(unit, FRONT_LANES // unit * unit)
+    while total % width:
+        width -= unit
+    return width
+
+
+def _parts(st: _Front, value_width: int):
+    """(part, its first lane in the projection, the lanes a turn of the
+    loop takes: whole key heads of q, of k, whole lanes of v, how many
+    such groups, the scale after the l2 norm: None for no norm)."""
+    kd, dk = st.key_width, st.dk
+    for part, base, total, unit, scale in (
+            (0, 0, kd, dk, dk ** -0.5), (1, kd, kd, dk, 1.0),
+            (2, 2 * kd, value_width, _LANES, None)):
+        width = _width(total, unit)
+        yield part, base, width, total // width, scale
+
+
+def _over_groups(st: _Front, value_width: int, body):
+    """body(part, lanes in the projection, lanes in the part's own array,
+    width, scale) for every group of lanes, a loop a part: the kernel's
+    code is a part's, not a group's, long."""
+    for part, base, width, count, scale in _parts(st, value_width):
+        def group(g, carry, part=part, base=base, width=width, scale=scale):
+            at = lambda offset: pl.ds(
+                pl.multiple_of(offset + g * width, _LANES), width)
+            body(part, at(base), at(0), width, scale)
+            return carry
+        lax.fori_loop(0, count, group, 0)
+
+
+def _turns(strips: int, body, carry):
+    """body(strip, carry) over a tile's strips, `FRONT_STRIPS` of them a
+    turn of the loop (independent work for the scheduler to interleave)."""
+    per = max(1, min(FRONT_STRIPS, strips))
+    while strips % per:
+        per -= 1
+
+    def turn(i, carry):
+        for j in range(per):
+            carry = body(i * per + j, carry)
+        return carry
+    return lax.fori_loop(0, strips // per, turn, carry)
+
+
+def _strip_at(s):
+    return pl.ds(pl.multiple_of(s * _STRIP, _STRIP), _STRIP)
+
+
+def _rolled(a, shifts):
+    """a [8, W] rolled down its rows by each shift."""
+    return tuple(pltpu.roll(a, j, 0) for j in shifts)
+
+
+def _taps_at(w, lanes, width: int):
+    """The weight's rows (its taps) on a group's lanes, each as a whole
+    strip."""
+    return [jnp.broadcast_to(w[j:j + 1, lanes], (_STRIP, width))
+            for j in range(w.shape[0])]
+
+
+def _behind(taps: int):
+    """The rolls that bring x[t - j] to row t, j = 1 .. taps - 1."""
+    return tuple(range(1, taps))
+
+
+def _ahead(taps: int):
+    """The rolls that bring dc[t + m] to row t, m = 1 .. taps - 1."""
+    return tuple(_STRIP - m for m in range(1, taps))
+
+
+def _tapped(taps, terms):
+    """sum_j terms[j] * taps[j], tap 0 first (the jax.numpy form's
+    order)."""
+    total = terms[0] * taps[0]
+    for j in range(1, len(taps)):
+        total = total + terms[j] * taps[j]
+    return total
+
+
+def _convolved(cur, rolled_cur, rolled_prev, taps):
+    """A strip's convolution: cur [8, W] = x[t] and its `_behind` rolls
+    and the strip before's -> (c [8, W], (x[t - taps + 1], .., x[t - 1],
+    x[t]))."""
+    row = lax.broadcasted_iota(jnp.int32, cur.shape, 0)
+    back = [jnp.where(row >= j, r, p) for j, r, p in
+            zip(_behind(len(taps)), rolled_cur, rolled_prev)]
+    seen = tuple(reversed(back)) + (cur,)
+    return _tapped(taps, seen), seen
+
+
+def _by_head(a, dk: int):
+    return [a[:, h * dk:(h + 1) * dk] for h in range(a.shape[1] // dk)]
+
+
+def _activated(c, scale, dk: int):
+    """silu, then (scale not None) the l2 norm over each key head of `dk`
+    lanes and the scale: -> (the output, sigmoid(c), silu(c), the norms'
+    factors, a [8, 1] a head)."""
+    sig = jax.nn.sigmoid(c)
+    u = c * sig
+    if scale is None:
+        return u, sig, u, None
+    r = [lax.rsqrt(jnp.sum(a * a, 1, keepdims=True) + _L2_EPS)
+         for a in _by_head(u, dk)]
+    y = jnp.concatenate([a * f for a, f in zip(_by_head(u, dk), r)], 1)
+    return (y if scale == 1.0 else y * scale), sig, u, r
+
+
+def _front_fwd_kernel(st: _Front, x, halo, w, q, k, v):
+    """A tile of positions: x [T, C] (the projection's first C = 2 kd + vd
+    channels), halo [8, C] (the 8 positions before the tile), w [4, C] ->
+    q, k [T, kd], v [T, vd]."""
+    first = pl.program_id(0) == 0
+    outs, behind = (q, k, v), _behind(w.shape[0])
+
+    def group(part, lanes, own, width, scale):
+        taps = _taps_at(w, lanes, width)
+        prev = jnp.where(first, 0.0, halo[:, lanes])
+
+        def strip(s, rolled_prev):
+            at = _strip_at(s)
+            cur = x[at, lanes]
+            rolled_cur = _rolled(cur, behind)
+            c, _ = _convolved(cur, rolled_cur, rolled_prev, taps)
+            outs[part][at, own] = _activated(c, scale, st.dk)[0]
+            return rolled_cur
+        _turns(st.tile // _STRIP, strip, _rolled(prev, behind))
+    _over_groups(st, v.shape[1], group)
+
+
+def _front_bwd_kernel(st: _Front, x, halo, w, dq, dk, dv, dx, dw, dnext):
+    """The tiles in reverse: x, halo, w as `_front_fwd_kernel`'s; dq, dk
+    [T, kd], dv [T, vd] -> dx [T, C], dw [4, C] (resident: summed over the
+    grid); `dnext` [8, C]: scratch, the cotangent of the convolution's
+    output at the first 8 positions of the tile after."""
+    i = pl.program_id(0)
+    first = i == pl.num_programs(0) - 1
+    strips = st.tile // _STRIP
+    outs, behind, ahead = (dq, dk, dv), _behind(w.shape[0]), \
+        _ahead(w.shape[0])
+
+    @pl.when(i == 0)
+    def _():
+        dw[...] = jnp.zeros_like(dw)
+        dnext[...] = jnp.zeros_like(dnext)
+
+    def group(part, lanes, own, width, scale):
+        taps = _taps_at(w, lanes, width)
+        halo_g = jnp.where(first, 0.0, halo[:, lanes])
+        dout = outs[part]
+
+        def strip(r, carry):
+            rolled_cur, rolled_next, acc, _ = carry
+            s = strips - 1 - r
+            at = _strip_at(s)
+            prev = jnp.where(s == 0, halo_g,
+                             x[_strip_at(jnp.maximum(s - 1, 0)), lanes])
+            rolled_prev = _rolled(prev, behind)
+            cur = x[at, lanes]
+            c, seen = _convolved(cur, rolled_cur, rolled_prev, taps)
+            _, sig, u, r = _activated(c, scale, st.dk)
+            du = dout[at, own]
+            if scale is not None:
+                if scale != 1.0:
+                    du = du * scale
+                du = jnp.concatenate([
+                    f * d - (f * f * f) * jnp.sum(a * d, 1, keepdims=True) * a
+                    for a, d, f in zip(_by_head(u, st.dk),
+                                       _by_head(du, st.dk), r)], 1)
+            dc = du * (sig * (1.0 + c * (1.0 - sig)))
+            acc = tuple(a + dc * b for a, b in zip(acc, seen))
+            # dx[t] = sum_j w[j] dc[t + taps - 1 - j]: dc[t + m] from this
+            # strip's rows and, past its end, the next strip's
+            rolled_dc = _rolled(dc, ahead)
+            row = lax.broadcasted_iota(jnp.int32, dc.shape, 0)
+            later = [jnp.where(row < m, a, b) for m, a, b in
+                     zip(ahead, rolled_dc, rolled_next)]
+            dx[at, lanes] = _tapped(taps, tuple(reversed(later)) + (dc,))
+            return rolled_prev, rolled_dc, acc, dc
+
+        last = x[_strip_at(strips - 1), lanes]
+        zero = jnp.zeros_like(last)
+        _, _, acc, dc0 = _turns(strips, strip, (
+            _rolled(last, behind), _rolled(dnext[:, lanes], ahead),
+            (zero,) * len(taps), zero))
+        dnext[:, lanes] = dc0
+        for j, a in enumerate(acc):
+            dw[j:j + 1, lanes] += jnp.sum(a, 0, keepdims=True)
+    _over_groups(st, dv.shape[1], group)
+
+
+def _front_specs(st: _Front, taps: int, channels: int, tile_of):
+    """Block specs over the projection [P, >= C] (a tile of positions, the
+    first C channels; the 8 positions before it), the weight [4, C] whole,
+    and a tile of positions of an array of `width` lanes; `tile_of` maps
+    the grid's index to the tile."""
+    t8 = st.tile // _STRIP
+    return (pl.BlockSpec((st.tile, channels), lambda i: (tile_of(i), 0)),
+            pl.BlockSpec((_STRIP, channels),
+                         lambda i: (jnp.maximum(tile_of(i) * t8 - 1, 0), 0)),
+            pl.BlockSpec((taps, channels), lambda i: (0, 0)),
+            lambda width: pl.BlockSpec((st.tile, width),
+                                       lambda i: (tile_of(i), 0)))
+
+
+def _front_call(kernel, name, st, grid, in_specs, out_specs, out_shape,
+                scratch=()):
+    return pl.pallas_call(
+        functools.partial(kernel, st), grid=(grid,), in_specs=in_specs,
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=list(scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=FRONT_VMEM),
+        interpret=st.interpret, name=name)
+
+
+def _run_front_fwd(st: _Front, x, w):
+    positions, channels = x.shape[0], w.shape[1]
+    kd, vd = st.key_width, w.shape[1] - 2 * st.key_width
+    block, halo, weight, lanes = _front_specs(st, *w.shape, lambda i: i)
+    f32 = lambda width: jax.ShapeDtypeStruct((positions, width), jnp.float32)
+    return _front_call(
+        _front_fwd_kernel, "gdn_front_fwd", st, positions // st.tile,
+        [block, halo, weight], [lanes(kd), lanes(kd), lanes(vd)],
+        [f32(kd), f32(kd), f32(vd)])(x, x, w)
+
+
+def _run_front_bwd(st: _Front, x, w, dq, dk, dv):
+    """-> (the projection's cotangent [P, width of x], of which the kernel
+    writes the first C channels, the weight's [4, C])."""
+    positions, channels = x.shape[0], w.shape[1]
+    n = positions // st.tile
+    block, halo, weight, lanes = _front_specs(
+        st, *w.shape, lambda i: n - 1 - i)
+    return _front_call(
+        _front_bwd_kernel, "gdn_front_bwd", st, n,
+        [block, halo, weight, lanes(dq.shape[1]), lanes(dk.shape[1]),
+         lanes(dv.shape[1])], [block, weight],
+        [jax.ShapeDtypeStruct(x.shape, jnp.float32),
+         jax.ShapeDtypeStruct(w.shape, jnp.float32)],
+        scratch=[pltpu.VMEM((_STRIP, channels), jnp.float32)])(
+            x, x, w, dq, dk, dv)
+
+
+def _front_primal(x, w, st: _Front):
+    with jax.named_scope("gdn_conv"):
+        q, k, v = _run_front_fwd(st, x, w)
+    return q, k, v, x[:, w.shape[1]:]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _front_kernels(x, w, st: _Front):
+    return _front_primal(x, w, st)
+
+
+def _front_kernels_fwd(x, w, st: _Front):
+    return _front_primal(x, w, st), (x, w)
+
+
+def _front_kernels_bwd(st: _Front, kept, cotangents):
+    x, w = kept
+    dq, dk, dv, dz = cotangents
+    with jax.named_scope("gdn_conv"):
+        dx, dw = _run_front_bwd(st, x, w, dq, dk, dv)
+        if dz.shape[1]:                 # z's channels, in place
+            dx = lax.dynamic_update_slice(dx, dz.astype(dx.dtype),
+                                          (0, w.shape[1]))
+    return dx, dw
+
+
+_front_kernels.defvjp(_front_kernels_fwd, _front_kernels_bwd)
+
+
+def front(x, w, key_width: int, dk: int, *, kernels: bool = False,
+          interpret: bool = False):
+    """The mixer's front (the module's docstring): the projection x [P, 2
+    kd + vd + z's width] and the convolution's weight w [4, 2 kd + vd] ->
+    q, k [P, kd] (l2-normed a key head of `dk`, q scaled by dk^-1/2), v
+    [P, vd], float32, heads side by side in the lanes, and z = the rest of
+    x as it is.  `kernels` (the TPU, as models/qwen3next.py `make`
+    resolves it) and shapes `front_plan` takes, at most 9 taps (a strip's
+    rows and its halo): the Pallas kernels; anything else the jax.numpy
+    form.  `interpret` runs the kernels in Pallas' interpreter (the CPU
+    tests)."""
+    tile = front_plan(x.shape[0], dk, w.shape[1] - 2 * key_width, x.dtype,
+                      kernels)
+    if not tile or w.shape[0] > _STRIP + 1:
+        return _front_plain(x, w, key_width, dk)
+    return _front_kernels(x, w.astype(jnp.float32),
+                          _Front(key_width, dk, tile, interpret))

@@ -183,17 +183,22 @@ def test_the_gauge_says_which_form_runs():
     wide = TINY._replace(lin_key_dim=128, lin_value_dim=128, seq_len=256)
     assert gauges(wide, True) == {
         "gdn_kernel": 1, "gdn_chunk": gdn.KERNEL_CHUNK,
-        "gdn_chunks_per_sequence": 256 // gdn.KERNEL_CHUNK}
+        "gdn_chunks_per_sequence": 256 // gdn.KERNEL_CHUNK,
+        "gdn_front_kernel": 1}
     assert gauges(wide, False) == gauges(wide, None) == {
-        "gdn_kernel": 0, "gdn_chunk": 64, "gdn_chunks_per_sequence": 4}
+        "gdn_kernel": 0, "gdn_chunk": 64, "gdn_chunks_per_sequence": 4,
+        "gdn_front_kernel": 0}
     assert gauges(TINY, True) == {
-        "gdn_kernel": 0, "gdn_chunk": 8, "gdn_chunks_per_sequence": 4}
+        "gdn_kernel": 0, "gdn_chunk": 8, "gdn_chunks_per_sequence": 4,
+        "gdn_front_kernel": 0}
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     import telemetry_report
     events = [dict(kind="gauge", name=n, value=v)
               for n, v in gauges(wide, True).items()]
-    assert f"(the Pallas kernels) in chunks of {gdn.KERNEL_CHUNK} " \
-        in "\n".join(telemetry_report._gdn_lines(events))
+    text = "\n".join(telemetry_report._gdn_lines(events))
+    assert f"(the Pallas kernels) in chunks of {gdn.KERNEL_CHUNK} " in text
+    assert "the front (convolution, silu, q / k norms): the Pallas kernels" \
+        in text
 
 
 def test_convolution_is_the_explicit_four_tap_sum():
@@ -438,6 +443,7 @@ def test_three_sgd_steps_and_test_model_through_trainer_match_the_reference(
                      + telemetry_report._moe_lines(tel.records))
     assert "== linear attention ==" in text
     assert "(jax.numpy) in chunks of 8 positions, 4 a sequence" in text
+    assert "the front (convolution, silu, q / k norms): jax.numpy" in text
     assert f"predicted tokens {3 * b * 31:,}" in text
 
 

@@ -199,6 +199,35 @@ def test_delta_rule_kernels_compile_for_a_v5e_under_their_scope(v5e8_mesh):
             assert "gdn_recurrence" in op_name and name in op_name
 
 
+def test_front_kernels_compile_for_a_v5e_under_their_scope(v5e8_mesh):
+    """ops/gdn.py's front at the hybrid decoder's widths (the projection
+    [8192, 12288] -> q, k [8192, 2048], v [8192, 4096], keys of 128): Mosaic
+    takes `gdn_front_fwd` and, in the gradient, `gdn_front_bwd` beside it;
+    each custom-call keeps `gdn_conv` in its op_name, which is how the
+    benchmark's reader finds its time, and z's cotangent goes into the
+    backward kernel's output in place, with no concatenation."""
+    from jax.sharding import SingleDeviceSharding
+    from cs744_ddp_tpu.ops import gdn
+    one = SingleDeviceSharding(v5e8_mesh.devices.flat[0])
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
+    x, w = sds(8192, 12288), sds(4, 8192)
+    front = lambda x, w: gdn.front(x, w, 2048, 128, kernels=True)
+    grads = jax.grad(lambda x, w: sum(jnp.sum(jnp.sin(a))
+                                      for a in front(x, w)), argnums=(0, 1))
+    for f, kernels in ((front, ["gdn_front_fwd"]),
+                       (grads, ["gdn_front_fwd", "gdn_front_bwd"])):
+        text = jax.jit(f).lower(x, w).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if "custom-call(" in line and "tpu_custom_call" in line]
+        assert len(calls) == len(kernels)
+        for line, name in zip(calls, kernels):
+            assert re.search(r"%?" + name + r"[.\d]* = ", line), line
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "gdn_conv" in op_name and name in op_name
+        assert " concatenate(" not in text
+    assert "dynamic-update-slice" in text
+
+
 def test_hyper_connection_kernels_compile_for_a_v5e_under_their_scope(
         v5e8_mesh):
     """ops/hyper.py's four Pallas kernels at the latent decoder's widths

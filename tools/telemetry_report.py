@@ -526,18 +526,22 @@ def _attn_lines(events) -> list:
 def _gdn_lines(events) -> list:
     """A hybrid decoder's linear-attention layers, as built (ops/gdn.py):
     which form of the gated delta rule runs, the chunk it is computed in
-    and the chunks the state is carried through a sequence."""
+    and the chunks the state is carried through a sequence; which form of
+    the front before it (`gdn_front_kernel`, PR 38; absent before)."""
     g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
          and e.get("name") in ("gdn_kernel", "gdn_chunk",
-                               "gdn_chunks_per_sequence")}
+                               "gdn_chunks_per_sequence", "gdn_front_kernel")}
     if not g:
         return []
-    form = "the Pallas kernels" if g.get("gdn_kernel") else "jax.numpy"
-    return ["== linear attention ==",
-            f"  the gated delta rule ({form}) in chunks of "
-            f"{g.get('gdn_chunk', 0):,} positions, "
-            f"{g.get('gdn_chunks_per_sequence', 0):,} a sequence",
-            ""]
+    form = lambda on: "the Pallas kernels" if on else "jax.numpy"
+    lines = ["== linear attention ==",
+             f"  the gated delta rule ({form(g.get('gdn_kernel'))}) in "
+             f"chunks of {g.get('gdn_chunk', 0):,} positions, "
+             f"{g.get('gdn_chunks_per_sequence', 0):,} a sequence"]
+    if "gdn_front_kernel" in g:
+        lines.append("  the front (convolution, silu, q / k norms): "
+                     + form(g["gdn_front_kernel"]))
+    return lines + [""]
 
 
 def _mla_lines(events) -> list:
