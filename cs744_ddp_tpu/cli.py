@@ -59,8 +59,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "MoE; qwen3-next-80b-a3b: linear + full attention, "
                         "MoE with a shared expert; xing4.0-29b-a4b: latent "
                         "attention, a 4-stream hyper-connected residual, "
-                        "sigmoid-routed MoE; sdar-tiny / qwen3-next-tiny / "
-                        "xing4-tiny: their CPU test sizes; see "
+                        "sigmoid-routed MoE; mellum2-12b-a2.5b: sliding-"
+                        "window attention three layers in four beside YaRN "
+                        "full attention, MoE; sdar-tiny / qwen3-next-tiny / "
+                        "xing4-tiny / mellum2-tiny: their CPU test sizes; see "
                         "'decoder share'), or any name "
                         "registered via models.register_model (validated "
                         "by the model zoo, not argparse, so plugged-in "
@@ -75,7 +77,9 @@ def build_parser() -> argparse.ArgumentParser:
         "of a layer comes from the configuration), experts 0-31 of 512, "
         "8192 tokens; both 18,992 rows of the vocabulary; xing4.0-29b-a4b: "
         "5 of 40 layers of which the first is dense, experts 0-7 of 64, "
-        "4096 tokens, 16,384 rows.  Data: "
+        "4096 tokens, 16,384 rows; mellum2-12b-a2.5b: 8 of 28 layers (whole "
+        "periods of sliding, sliding, sliding, full), experts 0-7 of 64, "
+        "8192 tokens, 12,288 rows.  Data: "
         "<data-dir>/tokens/train.npy + heldout.npy ([N, L] int32) or a "
         "synthetic stream; an epoch is --limit-train-batches steps of the "
         "stream")

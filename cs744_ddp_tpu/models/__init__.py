@@ -14,6 +14,8 @@ DECODERS = {
     "qwen3-next-tiny": ("qwen3next", True),
     "xing4.0-29b-a4b": ("xing4", False),    # latent attention, 4 streams
     "xing4-tiny": ("xing4", True),
+    "mellum2-12b-a2.5b": ("mellum2", False),    # window + full attention
+    "mellum2-tiny": ("mellum2", True),
 }
 
 # User-registered factories (name -> () -> (init_fn, apply_fn)); lets tests

@@ -1,10 +1,10 @@
-"""Softmax attention under the two rules the decoders train with: the
-block-diffusion training mask (`blockdiff_attention`, models/sdar.py) and
-the plain causal rule at any head size (`causal_attention`,
-models/qwen3next.py: a query sees the keys at and before its own position,
-P (P + 1) / 2 of the P^2 scores; the library's own `CausalMask`).  One tile
-table (`KERNEL_TILES`) and, a rule each, a tally of what the tiles cost,
-emitted as the same gauges.
+"""Softmax attention under the three rules the decoders train with: the
+block-diffusion training mask (`blockdiff_attention`, models/sdar.py), the
+causal rule at any head size (`causal_attention`, models/qwen3next.py: a
+query sees the keys at and before its own position; `CausalMask`) and the
+sliding window (`window_attention`, models/mellum2.py: the `window` keys
+that end at the query's position; `LocalMask`).  One tile table
+(`KERNEL_TILES`) and, a rule each, a tally of what the tiles cost (gauges).
 
 A block-diffusion decoder (Arriola et al. 2025, arXiv:2503.09573) trains on
 ONE pass over 2L positions ``[xt ; x0]``: the noisy copy of a sequence
@@ -33,7 +33,7 @@ Two ways of computing either, one result:
   * elsewhere (the CPU tests) a blocked ``jax.numpy`` formulation over
     the same tiles: query tile ``t`` (its noisy and its clean rows) meets
     the clean keys of tiles ``0..t`` and the noisy keys of tile ``t``;
-    under the causal rule, the keys of tiles ``0..t``.
+    causal, the keys of tiles ``0..t``; windowed, those in reach of its rows.
 """
 
 from __future__ import annotations
@@ -134,11 +134,11 @@ def tile_tally(seq_len: int, block: int, bq: int, bkv: int):
             visited * bq * bkv / (int(cells.sum()) * block * block))
 
 
-def _tile_gauges(tiles: Tiles, tally):
-    """(name, value, {"kernel": ...}) of `tally(bq, bkv)` a kernel."""
+def _tile_gauges(rule: str, tiles: Tiles, tally):
+    """(name, value, {"rule": rule, "kernel": ...}) of `tally(bq, bkv)`."""
     names = ("attn_tiles_visited", "attn_tiles_partial",
              "attn_visited_over_allowed")
-    return [(name, value, {"kernel": kernel})
+    return [(name, value, {"rule": rule, "kernel": kernel})
             for kernel, (bq, bkv, *_) in tiles._asdict().items()
             for name, value in zip(names, tally(bq, bkv))]
 
@@ -146,7 +146,7 @@ def _tile_gauges(tiles: Tiles, tally):
 def kernel_tile_gauges(seq_len: int, block: int):
     """`tile_tally` for each of the kernels as `blockdiff_attention` builds
     them at this shape."""
-    return _tile_gauges(_fit(seq_len, block, KERNEL_TILES, None),
+    return _tile_gauges("blockdiff", _fit(seq_len, block, KERNEL_TILES, None),
                         functools.partial(tile_tally, seq_len, block))
 
 
@@ -278,7 +278,7 @@ def causal_tile_tally(n: int, bq: int, bkv: int):
 
 def causal_tile_gauges(n: int):
     """`kernel_tile_gauges` for the kernels `causal_attention` builds."""
-    return _tile_gauges(_fit(n, 1, KERNEL_TILES, None),
+    return _tile_gauges("causal", _fit(n, 1, KERNEL_TILES, None),
                         functools.partial(causal_tile_tally, n))
 
 
@@ -326,4 +326,89 @@ def causal_attention(q, k, v, *, kernels: bool, interpret: bool = False,
                 qg, k, v)
         else:
             out = _blocked_causal(qg, k, v, _tile(n, 1, tile or 128))
+    return out.reshape(s, hq, n, v.shape[-1])
+
+
+# -- the sliding window -------------------------------------------------------
+
+# Query i sees key j iff i - window < j <= i: `window` keys, its own among
+# them (transformers' `kv_idx > q_idx - sliding_window`).  The kernels take
+# `KERNEL_TILES`, unswept at this rule.
+
+def window_pairs(n: int, window: int) -> int:
+    """(query, key) pairs the window allows over n positions."""
+    w = min(window, n)
+    return w * (w + 1) // 2 + (n - w) * w
+
+
+def window_tile_tally(n: int, window: int, bq: int, bkv: int):
+    """`tile_tally` under the window over n positions.  A tile's query
+    minus key ids run over one interval: it is visited if that interval
+    meets [0, window), wholly allowed if it lies inside."""
+    rows, keys = np.arange(0, n, bq)[:, None], np.arange(0, n, bkv)[None, :]
+    least, most = rows - (keys + bkv - 1), rows + bq - 1 - keys
+    some = (most >= 0) & (least <= window - 1)
+    every = (least >= 0) & (most <= window - 1)
+    visited = int(some.sum())
+    return (visited, int((some & ~every).sum()),
+            visited * bq * bkv / window_pairs(n, window))
+
+
+def window_tile_gauges(n: int, window: int):
+    """`kernel_tile_gauges` for the kernels `window_attention` builds."""
+    return _tile_gauges("window", _fit(n, 1, KERNEL_TILES, None),
+                        functools.partial(window_tile_tally, n, window))
+
+
+def _blocked_window(q, k, v, window: int, tile: int):
+    """q [S, HKV, G, P, D]; k, v [S, HKV, P, D] -> like q.  Query tile
+    [lo, hi) meets the keys [lo - window + 1, hi) and no others."""
+    out = []
+    for lo in range(0, q.shape[-2], tile):
+        hi, first = lo + tile, max(0, lo - window + 1)
+        gap = np.arange(lo, hi)[:, None] - np.arange(first, hi)[None, :]
+        allowed = (gap >= 0) & (gap < window)
+        s = jnp.einsum("shgqd,shkd->shgqk", q[..., lo:hi, :],
+                       k[..., first:hi, :],
+                       preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(jnp.where(allowed, s, -jnp.inf), axis=-1)
+        out.append(jnp.einsum(
+            "shgqk,shkd->shgqd", p.astype(v.dtype), v[..., first:hi, :],
+            preferred_element_type=jnp.float32).astype(q.dtype))
+    return jnp.concatenate(out, axis=-2)
+
+
+@functools.lru_cache(maxsize=8)
+def _window_kernel(n: int, window: int, group: int, tiles: Tiles,
+                   interpret: bool):
+    """The splash MQA kernel under the library's `LocalMask`: `window - 1`
+    keys to the left of the diagonal, none to its right.  Its tile tables
+    hold the band's tiles alone, so the kernels visit nothing outside it."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mqa(
+            sm.MultiHeadMask([sm.LocalMask((n, n), (window - 1, 0), 0)
+                              for _ in range(group)]),
+            block_sizes=_block_sizes(sk, tiles), head_shards=1,
+            q_seq_shards=1, interpret=interpret)
+
+
+def window_attention(q, k, v, *, window: int, kernels: bool,
+                     interpret: bool = False, tile: int | None = None):
+    """softmax(q k^T + window) v over the P positions of each sequence.
+
+    q [S, HQ, P, D] ALREADY scaled; k, v [S, HKV, P, D], HQ/HKV query heads
+    a key/value head.  Returns [S, HQ, P, D].  `kernels`, `tile`: as the
+    other rules'.  A window of P or more is the causal rule."""
+    s, hq, n, d = q.shape
+    hkv = k.shape[1]
+    qg = q.reshape(s, hkv, hq // hkv, n, d)
+    with jax.named_scope("window_core"):
+        if kernels:
+            out = _splash(_window_kernel(
+                n, window, qg.shape[2], _fit(n, 1, KERNEL_TILES, tile),
+                interpret), qg, k, v)
+        else:
+            out = _blocked_window(qg, k, v, window, _tile(n, 1, tile or 128))
     return out.reshape(s, hq, n, v.shape[-1])

@@ -437,6 +437,8 @@ def test_three_sgd_steps_and_test_model_through_trainer_match_the_reference(
     assert gauges["gdn_chunks_per_sequence", None] == 4
     assert all(gauges["attn_tiles_visited", k] == 1
                for k in ("fwd", "dkv", "dq"))
+    assert {r["rule"] for r in tel.records if r["kind"] == "gauge"
+            and r["name"].startswith("attn_")} == {"causal"}
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     import telemetry_report
     text = "\n".join(telemetry_report._gdn_lines(tel.records)
@@ -472,7 +474,8 @@ def test_every_decoder_of_the_table_resolves_at_its_published_share(name):
     n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
     want = {"sdar-30b-a3b": (645_623_296, 18992),
             "qwen3-next-80b-a3b": (625_667_136, 18992),
-            "xing4.0-29b-a4b": (759_346_446, 16384)}
+            "xing4.0-29b-a4b": (759_346_446, 16384),
+            "mellum2-12b-a2.5b": (624_072_960, 12288)}
     if name in want:
         assert (n, shape.vocab) == want[name]
     else:

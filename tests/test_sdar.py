@@ -183,6 +183,7 @@ def test_tile_gauges_name_each_kernel_at_the_cells_sizes():
         (n, k) for n in ("attn_tiles_visited", "attn_tiles_partial",
                          "attn_visited_over_allowed")
         for k in ("fwd", "dkv", "dq")}
+    assert {attrs["rule"] for _, _, attrs in rows} == {"blockdiff"}
     over = {a["kernel"]: v for n, v, a in rows
             if n == "attn_visited_over_allowed"}
     assert all(1.0 < v < 2.01 for v in over.values()), over
@@ -456,6 +457,8 @@ def test_three_sgd_steps_and_test_model_through_trainer_match_the_reference(
     assert all(gauges["attn_tiles_visited", k] == 3
                and gauges["attn_tiles_partial", k] == 3
                for k in ("fwd", "dkv", "dq"))
+    assert {r["rule"] for r in tel.records if r["kind"] == "gauge"
+            and r["name"].startswith("attn_")} == {"blockdiff"}
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     import telemetry_report
     assert "fwd  visits 3 tiles, 3 partly allowed" in "\n".join(

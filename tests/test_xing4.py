@@ -703,6 +703,8 @@ def test_sgd_steps_and_test_model_through_trainer_match_the_reference(
     assert gauges["mhc_kernel", None] == 0 and gauges["mhc_tile", None] == 0
     assert all(gauges["attn_tiles_visited", k] == 1
                for k in ("fwd", "dkv", "dq"))
+    assert {r["rule"] for r in tel.records if r["kind"] == "gauge"
+            and r["name"].startswith("attn_")} == {"causal"}
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     import telemetry_report
     text = "\n".join(telemetry_report._mla_lines(tel.records)

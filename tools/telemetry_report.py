@@ -502,20 +502,24 @@ def _moe_lines(events) -> list:
 
 def _attn_lines(events) -> list:
     """A decoder's attention kernels, as built (ops/attention.py
-    `tile_tally`): the tiles each visits, those of them partly allowed, and
-    the scores it computes over the scores the mask allows."""
+    `tile_tally` and its siblings), grouped by the rule they compute (a
+    model with two rules emits both): the tiles each visits, those of them
+    partly allowed, and the scores it computes over the scores the mask
+    allows."""
     per = {}
     for e in events:
         if e.get("kind") == "gauge" and e.get("name") in (
                 "attn_tiles_visited", "attn_tiles_partial",
                 "attn_visited_over_allowed"):
-            per.setdefault(e.get("kernel"), {})[e["name"]] = e["value"]
+            per.setdefault((e.get("rule"), e.get("kernel")), {})[
+                e["name"]] = e["value"]
     if not per:
         return []
-    lines = ["== attention tiles (per kernel) =="]
-    for kernel, row in per.items():
+    lines = ["== attention tiles (per rule and kernel) =="]
+    for (rule, kernel), row in per.items():
         lines.append(
-            f"  {kernel:<4} visits {row.get('attn_tiles_visited', 0):,} "
+            f"  {rule or '':<9} {kernel:<4} visits "
+            f"{row.get('attn_tiles_visited', 0):,} "
             f"tiles, {row.get('attn_tiles_partial', 0):,} partly allowed; "
             f"visited / allowed scores "
             f"{row.get('attn_visited_over_allowed', 0.0):.3f}")
@@ -542,6 +546,20 @@ def _gdn_lines(events) -> list:
         lines.append("  the front (convolution, silu, q / k norms): "
                      + form(g["gdn_front_kernel"]))
     return lines + [""]
+
+
+def _window_lines(events) -> list:
+    """A window-and-full decoder's sliding layers, as built (ops/attention.py
+    `window_attention`): the form that runs and the keys a query sees."""
+    g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
+         and e.get("name") in ("window_kernel", "attn_window")}
+    if not g:
+        return []
+    form = "the Pallas kernels" if g.get("window_kernel") else "jax.numpy"
+    return ["== window attention ==",
+            f"  sliding-window attention ({form}): a query sees the "
+            f"{g.get('attn_window', 0):,} keys that end at its own position",
+            ""]
 
 
 def _mla_lines(events) -> list:
@@ -711,6 +729,7 @@ def render(out_dir: str) -> str:
     lines.extend(_moe_lines(events))
     lines.extend(_attn_lines(events))
     lines.extend(_gdn_lines(events))
+    lines.extend(_window_lines(events))
     lines.extend(_mla_lines(events))
     lines.extend(_mhc_lines(events))
     lines.extend(_wire_ext_lines(events))
