@@ -30,7 +30,8 @@ as for the other decoders.  The parameters of the two kinds sit in two
 subtrees, stacked by period (and, the sliding ones, by their place in it):
 the forward pass scans the periods, inside a period the sliding layers, then
 runs the period's full layer (models/qwen3next.py's structure).  Inside a
-layer the sequences go one at a time, each recomputed in the backward pass.
+layer the sequences go one at a time, each recomputed in the backward pass
+but for the attention kernels' output and logsumexp, which are kept.
 Training is next-token prediction (models/qwen3next.py `NextToken`).
 """
 
@@ -165,6 +166,16 @@ def _layer(shape: Shape, kernels: bool, full: bool, rotary, x, p):
         rows, x.shape[0] * s.top_k, kernels)
 
 
+def _remat_layer(shape: Shape, kernels: bool, full: bool, rotary, p):
+    """`_layer` of one sequence under the layer's checkpoint: the backward
+    pass recomputes all of it but the attention kernels' output and
+    logsumexp, which it keeps (`attention.KEEP_ATTN_RESIDUALS`), so the
+    forward kernel runs once."""
+    return jax.checkpoint(
+        lambda x: _layer(shape, kernels, full, rotary, x, p),
+        policy=attention.KEEP_ATTN_RESIDUALS)
+
+
 def make(shape: Shape = Shape(), kernels=None):
     """(init_fn, apply_fn) for one share.  `kernels`: the Pallas attention
     and grouped-matmul kernels; None = wherever the default backend is a
@@ -204,10 +215,10 @@ def make(shape: Shape = Shape(), kernels=None):
 
             def run(x, p):
                 # one sequence at a time, each recomputed in the backward
-                # pass; ONE checkpoint a layer (models/qwen3next.py)
-                x, rows, fullest, touched = lax.map(jax.checkpoint(
-                    lambda x_seq: _layer(s, on_tpu, full, rotary, x_seq, p)),
-                    x)
+                # pass but for its attention kernels' output; ONE
+                # checkpoint a layer (models/qwen3next.py)
+                x, rows, fullest, touched = lax.map(
+                    _remat_layer(s, on_tpu, full, rotary, p), x)
                 return x, jnp.stack([jnp.sum(rows), jnp.max(fullest),
                                      jnp.sum(touched)])
             return run
@@ -234,10 +245,15 @@ class NextToken(qwen3next.NextToken):
     @functools.cached_property
     def gauges(self):
         """Whether the attention runs as the Pallas kernels (1) or as
-        jax.numpy (0), the window, and what the tiles of each rule's
-        kernels cost at this length (the TPU's path): a recorder's, once."""
+        jax.numpy (0), the window, whether the layers' checkpoint keeps the
+        kernels' output (1: the backward pass runs no forward kernel; 0 on
+        the jax.numpy path, which names nothing to keep), and what the tiles
+        of each rule's kernels cost at this length (the TPU's path): a
+        recorder's, once."""
         s = self.shape
-        return [("window_kernel", int(self.kernels()), {}),
-                ("attn_window", s.window, {})] \
+        on = int(self.kernels())
+        return [("window_kernel", on, {}),
+                ("attn_window", s.window, {}),
+                ("attn_residuals_kept", on, {})] \
             + attention.window_tile_gauges(self.seq_len, s.window) \
             + attention.causal_tile_gauges(self.seq_len)

@@ -65,6 +65,15 @@ KERNEL_TILES = Tiles(
                                 # edges of 1024 x 1024; (512, 512, 512) 6.23
     dq=(512, 512))              # 4.29 of 24; (1024, 1024) 4.44
 
+# Every splash kernel names its forward pass's output and logsumexp
+# (`residual_checkpoint_name`).  A layer's `jax.checkpoint` under
+# `KEEP_ATTN_RESIDUALS` keeps those two and recomputes the rest, so that the
+# backward pass does not run the forward kernel again; under the default
+# policy the name is inert.  The blocked jax.numpy forms name nothing.
+ATTN_RESIDUALS = "attn_residuals"
+KEEP_ATTN_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    ATTN_RESIDUALS)
+
 
 def _twice_block(ids, seq_len: int, block: int):
     """2 * ((ids mod L) // block).  Ids are never negative, so the floor
@@ -207,7 +216,8 @@ def _splash_kernel(seq_len: int, block: int, group: int, tiles: Tiles,
         return sk.make_splash_mqa(
             sm.MultiHeadMask([BlockDiffusionMask() for _ in range(group)]),
             block_sizes=_block_sizes(sk, tiles), head_shards=1,
-            q_seq_shards=1, interpret=interpret)
+            q_seq_shards=1, residual_checkpoint_name=ATTN_RESIDUALS,
+            interpret=interpret)
 
 
 def _block_sizes(sk, tiles: Tiles):
@@ -306,7 +316,8 @@ def _causal_kernel(n: int, group: int, tiles: Tiles, interpret: bool):
         return sk.make_splash_mqa(
             sm.MultiHeadMask([sm.CausalMask((n, n)) for _ in range(group)]),
             block_sizes=_block_sizes(sk, tiles), head_shards=1,
-            q_seq_shards=1, interpret=interpret)
+            q_seq_shards=1, residual_checkpoint_name=ATTN_RESIDUALS,
+            interpret=interpret)
 
 
 def causal_attention(q, k, v, *, kernels: bool, interpret: bool = False,
@@ -391,7 +402,8 @@ def _window_kernel(n: int, window: int, group: int, tiles: Tiles,
             sm.MultiHeadMask([sm.LocalMask((n, n), (window - 1, 0), 0)
                               for _ in range(group)]),
             block_sizes=_block_sizes(sk, tiles), head_shards=1,
-            q_seq_shards=1, interpret=interpret)
+            q_seq_shards=1, residual_checkpoint_name=ATTN_RESIDUALS,
+            interpret=interpret)
 
 
 def window_attention(q, k, v, *, window: int, kernels: bool,

@@ -111,6 +111,96 @@ def test_pallas_window_kernels_interpreted_are_the_dense_masked_softmax():
         assert close(a, b, 2.0 ** -5)
 
 
+# -- the attention kernels' output kept across a checkpoint -------------------
+
+# Each rule's splash kernels in Pallas' interpreter at tiles of 128, head 128,
+# two query heads a key/value head; the positions they take.
+RULES = {
+    "blockdiff": (lambda *a: attention.blockdiff_attention(
+        *a, seq_len=256, block=4, kernels=True, interpret=True, tile=128),
+        512),
+    "causal": (lambda *a: attention.causal_attention(
+        *a, kernels=True, interpret=True, tile=128), 384),
+    "window": (lambda *a: attention.window_attention(
+        *a, window=160, kernels=True, interpret=True, tile=128), 384)}
+
+
+def checkpointed_grad(rule, policy):
+    """(the gradient of sum(sin(attention)) with the rule's kernels under
+    `jax.checkpoint(policy=policy)`, its q, k, v)."""
+    f, n = RULES[rule]
+    q, k, v = (a[:1] for a in qkv(n, 128, hq=2, hkv=1))
+    grad = jax.grad(lambda *a: jnp.sum(jnp.sin(
+        jax.checkpoint(f, policy=policy)(*a))), argnums=(0, 1, 2))
+    return grad, (q, k, v)
+
+
+def pallas_calls(jaxpr):
+    """The kernel names of the `pallas_call`s in a jaxpr and in the jaxprs
+    its equations hold, in order."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    names += pallas_calls(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    names += pallas_calls(sub)
+    return names
+
+
+@pytest.mark.parametrize("rule", RULES)
+def test_keeping_the_kernels_residuals_leaves_the_gradient_as_it_was(rule):
+    """Under `KEEP_ATTN_RESIDUALS` the backward kernels take the output and
+    logsumexp the forward kernel gave, where the default policy recomputes
+    them from the same q, k, v: the same three gradients (bit for bit as
+    run; asserted to 1e-6 of a gradient's largest entry)."""
+    grad, args = checkpointed_grad(rule, attention.KEEP_ATTN_RESIDUALS)
+    redone, _ = checkpointed_grad(rule, None)
+    for a, b in zip(jax.jit(grad)(*args), jax.jit(redone)(*args)):
+        assert near(a, b, 1e-6)
+
+
+@pytest.mark.parametrize("rule", RULES)
+def test_keeping_the_kernels_residuals_drops_the_recomputed_forward(rule):
+    """The gradient under the default policy calls the forward kernel twice
+    (the forward pass and its recomputation); under `KEEP_ATTN_RESIDUALS`
+    once; dq and dkv once either way."""
+    def calls(policy):
+        grad, args = checkpointed_grad(rule, policy)
+        names = pallas_calls(jax.make_jaxpr(grad)(*args).jaxpr)
+        return {k: names.count(k) for k in set(names)}
+    both = {"splash_mqa_dq_no_residuals": 1, "splash_mqa_dkv_no_residuals": 1}
+    assert calls(None) == dict(both, splash_mqa_fwd_residuals=2)
+    assert calls(attention.KEEP_ATTN_RESIDUALS) == dict(
+        both, splash_mqa_fwd_residuals=1)
+
+
+def test_the_models_gradient_runs_each_layers_forward_kernel_once():
+    """The decoder's train gradient with the kernels on, traced (not run)
+    at head 128 over 512 positions: each of the four layers of a period
+    calls its rule's forward kernel once, as it calls dq and dkv (under
+    the default policy the recomputation would call it a second time)."""
+    shape = TINY._replace(hidden=256, heads=2, kv_heads=1, head_dim=128,
+                          window=160, seq_len=512, vocab=256)
+    init_fn, apply_fn = mellum2.make(shape, kernels=True)
+    params = jax.eval_shape(lambda k: init_fn(k)[0], jax.random.PRNGKey(0))
+    obj = apply_fn.objective
+    toks = jax.ShapeDtypeStruct((2, shape.seq_len), jnp.int32)
+    grad = jax.grad(lambda p, t: obj.loss(apply_fn, p, {}, t)[0])
+    names = [n for n in pallas_calls(jax.make_jaxpr(grad)(params, toks).jaxpr)
+             if n and n.startswith("splash_")]
+    # one scan body for the sliding layers, one full layer: a call site a
+    # kind of layer
+    assert sorted(names) == sorted(
+        ["splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+         "splash_mqa_dkv_no_residuals"] * 2)
+
+
 @pytest.mark.parametrize("n,window,bq,bkv", [
     (64, 10, 16, 16), (64, 10, 8, 16), (64, 64, 16, 8), (96, 20, 32, 16),
     (96, 1, 16, 16), (128, 33, 32, 32)])
@@ -377,6 +467,7 @@ def test_sgd_steps_and_test_model_through_trainer_match_the_reference(
               for r in tel.records if r["kind"] == "gauge"}
     assert gauges["window_kernel", None, None] == 0     # the CPU: jax.numpy
     assert gauges["attn_window", None, None] == 8
+    assert gauges["attn_residuals_kept", None, None] == 0
     for rule in ("window", "causal"):
         assert all(gauges["attn_tiles_visited", rule, k] == 1
                    for k in ("fwd", "dkv", "dq"))
@@ -388,6 +479,8 @@ def test_sgd_steps_and_test_model_through_trainer_match_the_reference(
     assert "== window attention ==" in text
     assert "(jax.numpy): a query sees the 8 keys that end at its own " \
         "position" in text
+    assert "output and logsumexp kept across the layers' checkpoint: no" \
+        in text
     assert "window    fwd  visits 1 tiles" in text
     assert "causal    dq   visits 1 tiles" in text
     assert f"predicted tokens {3 * b * 31:,}" in text
@@ -409,9 +502,30 @@ def test_the_gauges_say_which_attention_runs_and_what_its_tiles_cost():
             pytest.approx(1.99987, abs=1e-5)
         assert real["attn_tiles_visited", "causal", k] == 36
     assert real["attn_tiles_visited", "window", "dq"] == 45
-    assert len(real) == 2 + 2 * 9
+    assert len(real) == 3 + 2 * 9
     assert gauges(mellum2.Shape(), False)["window_kernel", None, None] == 0
     assert gauges(TINY, None)["window_kernel", None, None] == 0  # the CPU
+
+
+def test_the_gauge_says_whether_the_kernels_output_is_kept():
+    """`attn_residuals_kept`: 1 where the kernels run (their output and
+    logsumexp carry the name the layers' checkpoint keeps), 0 on the
+    jax.numpy path, which names nothing; the CPU resolves to the latter."""
+    def kept(shape, kernels):
+        _, apply_fn = mellum2.make(shape, kernels=kernels)
+        return {name: value for name, value, _ in apply_fn.objective.gauges
+                }["attn_residuals_kept"]
+    assert kept(mellum2.Shape(), True) == 1
+    assert kept(mellum2.Shape(), False) == 0
+    assert kept(TINY, None) == 0
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    import telemetry_report
+    _, apply_fn = mellum2.make(mellum2.Shape(), kernels=True)
+    text = "\n".join(telemetry_report._window_lines(
+        [{"kind": "gauge", "name": name, "value": value}
+         for name, value, _ in apply_fn.objective.gauges]))
+    assert "(the Pallas kernels): a query sees the 1,024 keys" in text
+    assert "logsumexp kept across the layers' checkpoint: yes" in text
 
 
 def test_a_share_of_whole_periods_only_and_of_its_own_fields():

@@ -228,6 +228,57 @@ def test_front_kernels_compile_for_a_v5e_under_their_scope(v5e8_mesh):
     assert "dynamic-update-slice" in text
 
 
+def test_window_and_full_layers_run_each_forward_kernel_once_on_a_v5e(
+        v5e8_mesh):
+    """models/mellum2.py's two kinds of layer at the published widths (8192
+    positions, 32 query / 4 key-value heads of 128, a window of 1024, the 8
+    held experts), each mapped over two sequences under the layer's
+    checkpoint as the model maps it: the gradient compiles with ONE
+    `splash_mqa_fwd_residuals` custom-call a rule (the default policy
+    leaves a second in the backward loop), the backward pass taking the
+    output it kept, beside one dq and one dkv.  The kernels' lines carry no
+    op_name; each is classed into its rule's scope (`window_core`,
+    `attn_causal`) by its mask table, as the benchmark's reader classes
+    it."""
+    from jax.sharding import SingleDeviceSharding
+    from benchmark.readers import lm_window
+    from cs744_ddp_tpu.models import mellum2
+    from cs744_ddp_tpu.ops import mla
+    from jax import lax
+    one = SingleDeviceSharding(v5e8_mesh.devices.flat[0])
+    s = mellum2.Shape()
+    params = jax.eval_shape(lambda k: mellum2.init_params(k, s),
+                            jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((2, s.seq_len, s.hidden), jnp.float32,
+                             sharding=one)
+    positions = jnp.arange(s.seq_len)
+    for full, scope in ((False, "window_core"), (True, "attn_causal")):
+        group = params["periods"]["full" if full else "sliding"]
+        p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape[1 if full else 2:], a.dtype, sharding=one), group)
+        inv_freq = (mla.yarn_inv_freq(
+            s.head_dim, s.rope_theta, s.yarn_factor, s.yarn_original,
+            *s.yarn_beta), s.attn_factor) if full else (
+            mellum2.default_inv_freq(s.head_dim, s.rope_theta), 1.0)
+        rotary = (positions,) + inv_freq
+        # the forward loop's output is read (sin), so it stays: the
+        # recomputation lies in the backward loop
+        grad = jax.grad(lambda x, p: jnp.sum(jnp.sin(lax.map(
+            mellum2._remat_layer(s, True, full, rotary, p), x)[0])),
+            argnums=(0, 1))
+        text = jax.jit(grad).lower(x, p).compile().as_text()
+        splash = [line for line in text.splitlines()
+                  if "custom-call(" in line and "tpu_custom_call" in line
+                  and re.search(r"%?splash_mqa_\w+[.\d]* = ", line)]
+        kinds = sorted(re.search(r"%?(splash_mqa_[a-z_]+)", line).group(1)
+                       for line in splash)
+        assert kinds == ["splash_mqa_dkv_no_residuals",
+                         "splash_mqa_dq_no_residuals",
+                         "splash_mqa_fwd_residuals"], kinds
+        for line in splash:
+            assert lm_window.splash_rule(line) == scope, line
+
+
 def test_hyper_connection_kernels_compile_for_a_v5e_under_their_scope(
         v5e8_mesh):
     """ops/hyper.py's four Pallas kernels at the latent decoder's widths

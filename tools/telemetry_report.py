@@ -550,16 +550,24 @@ def _gdn_lines(events) -> list:
 
 def _window_lines(events) -> list:
     """A window-and-full decoder's sliding layers, as built (ops/attention.py
-    `window_attention`): the form that runs and the keys a query sees."""
+    `window_attention`): the form that runs, the keys a query sees and
+    whether the layers' checkpoint keeps the kernels' output."""
     g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
-         and e.get("name") in ("window_kernel", "attn_window")}
+         and e.get("name") in ("window_kernel", "attn_window",
+                               "attn_residuals_kept")}
     if not g:
         return []
     form = "the Pallas kernels" if g.get("window_kernel") else "jax.numpy"
-    return ["== window attention ==",
-            f"  sliding-window attention ({form}): a query sees the "
-            f"{g.get('attn_window', 0):,} keys that end at its own position",
-            ""]
+    lines = ["== window attention ==",
+             f"  sliding-window attention ({form}): a query sees the "
+             f"{g.get('attn_window', 0):,} keys that end at its own position"]
+    if "attn_residuals_kept" in g:
+        lines.append(
+            "  the kernels' output and logsumexp kept across the layers' "
+            "checkpoint: " + ("yes (the backward pass does not run the "
+                              "forward kernel again)"
+                              if g["attn_residuals_kept"] else "no"))
+    return lines + [""]
 
 
 def _mla_lines(events) -> list:
