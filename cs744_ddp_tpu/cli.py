@@ -61,8 +61,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "attention, a 4-stream hyper-connected residual, "
                         "sigmoid-routed MoE; mellum2-12b-a2.5b: sliding-"
                         "window attention three layers in four beside YaRN "
-                        "full attention, MoE; sdar-tiny / qwen3-next-tiny / "
-                        "xing4-tiny / mellum2-tiny: their CPU test sizes; see "
+                        "full attention, MoE; olmo-hybrid-7b: dense, linear "
+                        "attention with negative eigenvalues beside NoPE full "
+                        "attention; sdar-tiny / qwen3-next-tiny / xing4-tiny "
+                        "/ mellum2-tiny / olmo-hybrid-tiny: their CPU test "
+                        "sizes; see "
                         "'decoder share'), or any name "
                         "registered via models.register_model (validated "
                         "by the model zoo, not argparse, so plugged-in "
@@ -79,7 +82,9 @@ def build_parser() -> argparse.ArgumentParser:
         "5 of 40 layers of which the first is dense, experts 0-7 of 64, "
         "4096 tokens, 16,384 rows; mellum2-12b-a2.5b: 8 of 28 layers (whole "
         "periods of sliding, sliding, sliding, full), experts 0-7 of 64, "
-        "8192 tokens, 12,288 rows.  Data: "
+        "8192 tokens, 12,288 rows; olmo-hybrid-7b: 4 of 32 layers (whole "
+        "periods of linear, linear, linear, full), no experts, 8192 "
+        "tokens, 12,544 rows.  Data: "
         "<data-dir>/tokens/train.npy + heldout.npy ([N, L] int32) or a "
         "synthetic stream; an epoch is --limit-train-batches steps of the "
         "stream")

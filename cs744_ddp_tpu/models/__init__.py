@@ -16,6 +16,8 @@ DECODERS = {
     "xing4-tiny": ("xing4", True),
     "mellum2-12b-a2.5b": ("mellum2", False),    # window + full attention
     "mellum2-tiny": ("mellum2", True),
+    "olmo-hybrid-7b": ("olmo_hybrid", False),   # dense, negative eigenvalues
+    "olmo-hybrid-tiny": ("olmo_hybrid", True),
 }
 
 # User-registered factories (name -> () -> (init_fn, apply_fn)); lets tests

@@ -149,27 +149,13 @@ def _norm(x, gain, eps):
 
 
 def _gdn_mixer(shape: Shape, kernels: bool, h, p):
-    """h [P, H] (after ln1) -> the linear-attention mixer's output [P, H]."""
+    """h [P, H] (after ln1) -> the linear-attention mixer's output [P, H]
+    (ops/gdn.py `mixer`, beta in (0, 1))."""
     s = shape
-    P = h.shape[0]
-    hk, hv, dk, dv = (s.lin_key_heads, s.lin_value_heads, s.lin_key_dim,
-                      s.lin_value_dim)
-    kd, vd = hk * dk, hv * dv
-    f32 = jnp.float32
-    with jax.named_scope("attn_gdn"):
-        qkvz = jnp.dot(h, p["w_qkvz"].astype(h.dtype))
-        ba = jnp.dot(h, p["w_ba"].astype(h.dtype)).astype(f32)
-        q, k, v, z = gdn.front(qkvz, p["conv"], kd, dk, kernels=kernels)
-        beta = jax.nn.sigmoid(ba[:, :hv])
-        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[:, hv:] + p["dt_bias"])
-        # a key head serves hv / hk value heads in a row (ops/gdn.py)
-        o = gdn.delta_rule(q.reshape(P, hk, dk), k.reshape(P, hk, dk),
-                           v.reshape(P, hv, dv), g, beta, gdn.chunk_for(P),
-                           kernels=kernels)                     # [P, hv, dv]
-        y = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True) + s.eps)
-        y = y * p["gdn_norm"] * jax.nn.silu(z.reshape(P, hv, dv).astype(f32))
-        return jnp.dot(y.reshape(P, vd).astype(h.dtype),
-                       p["w_out"].astype(h.dtype))
+    return gdn.mixer(h, p["w_qkvz"], p["w_ba"], p,
+                     key_heads=s.lin_key_heads, value_heads=s.lin_value_heads,
+                     dk=s.lin_key_dim, dv=s.lin_value_dim, eps=s.eps,
+                     kernels=kernels)[0]
 
 
 def _full_mixer(shape: Shape, kernels: bool, h, p, positions):

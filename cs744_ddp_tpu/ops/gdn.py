@@ -9,7 +9,9 @@ new key, and is read by the query:
     S = S + k_t (beta_t (v_t - r))^T          o_t = q_t^T S
 
 with a data-dependent log-decay g_t <= 0 and write strength beta_t in
-(0, 1).  That loop as written is the benchmark reference's
+(0, 1), or in (0, 2) where the model allows the transition I - beta k k^T
+negative eigenvalues (models/olmo_hybrid.py; nothing below assumes beta <
+1).  That loop as written is the benchmark reference's
 (benchmark/reference/hybrid_causal.py `recurrence`), which the tests hold
 `delta_rule` to.
 
@@ -33,12 +35,17 @@ Two forms of that, chosen in `delta_rule` from what the caller resolved
 from the backend (`kernels`) and from the shapes, as ops/attention.py
 chooses its kernels:
 
-ON THE TPU, key and value sizes in whole lanes (multiples of 128): two
+ON THE TPU, key and value sizes in whole lanes (multiples of 128), or in
+whole quarters of them (keys of 96 and values of 192, `narrow_fits`): two
 Pallas kernels under one `jax.custom_vjp`, reading q, k [P, key heads x dk]
 and v [P, heads x dv] as the mixer's arrays lie (heads side by side in the
-lanes; a key head's block serves its value heads).  `gdn_chunks_fwd`: a
-grid of (blocks of heads, chunks), chunks in order, the state [dk, dv] of
-each head in a VMEM scratch that lives across the chunk axis; a grid step
+lanes; a key head's block serves its value heads; a grid step takes the
+fewest heads whose lanes are whole, or all of them, a head a static slice
+of the block's lanes that Mosaic pads to whole lanes in VMEM, and a last
+block that reaches past the heads skips those it does not have).
+`gdn_chunks_fwd`: a grid of (blocks of heads, chunks), chunks in order,
+the state [dk, dv] of each head in a VMEM scratch that lives across the
+chunk axis; a grid step
 loads the chunk's q, k, v, g, beta, builds G, D, k k^T, T, U0, W, Q K^T *
 D, U in VMEM (`_chunk`) and writes o [C, dv] — and, where a backward pass
 will follow, the state the chunk started from and its inverted system T
@@ -75,13 +82,15 @@ recurrence, the causal convolution of 4 taps over the first 2 kd + vd
 channels of the projection, `silu`, the split into q, k, v, the l2 norm of
 every key head of q and of k and q's scale dk^-1/2.  On the TPU with key
 heads and value channels in whole lanes and the length in whole tiles of
-`FRONT_TILE` (`front_plan`), two Pallas kernels under one
+`FRONT_TILE` (`front_plan`; key heads of 96 too, their parts starting on a
+lane, `key_stride`), two Pallas kernels under one
 `jax.custom_vjp`, both under the scope `gdn_conv`: `gdn_front_fwd` walks
 tiles of positions, reads the projection's channels in place (the block's
 channel index; the 8 positions before the tile as a second small block,
 for the taps' halo), convolves, applies `silu` and the norms, and writes
 q, k, v as the recurrence's kernels read them: a loop over each part's
-groups of `FRONT_LANES` lanes (whole key heads), `FRONT_STRIPS` strips of 8
+groups of `FRONT_LANES` lanes (whole key heads; for heads of 96, groups of
+4 heads and a last of the rest), `FRONT_STRIPS` strips of 8
 positions a turn (the taps as sublane rolls; independent strips for the
 scheduler: PERF.md section 6, PR 38); `gdn_front_bwd` walks the tiles in
 reverse, recomputes all that from the same input, pulls dq, dk, dv back
@@ -91,11 +100,16 @@ resident output block, and writes the projection's cotangent once: its
 first channels from the kernel, the rest (z's) by an update in place.  Float32
 elementwise, in the jax.numpy form's order of terms; nothing kept but the
 projection and the weight.  Everywhere else that jax.numpy form.
+
+THE MIXER (`mixer`): the projections, the front, the recurrence and the
+gated norm, as both hybrid decoders call them, beta's scale and the head
+sizes from the caller.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -124,6 +138,15 @@ def kernel_fits(dk: int, dv: int) -> bool:
     return dk % 128 == 0 and dv % 128 == 0
 
 
+def narrow_fits(dk: int, dv: int) -> bool:
+    """Heads narrower than whole lanes that the kernels still take: key and
+    value sizes in whole quarters of the lanes (keys of 96 and values of
+    192), so that at most four heads fill whole lanes.  A grid step then
+    takes such groups of heads (`_heads_a_step`) and a head is a static
+    slice of their lanes, which Mosaic pads to whole lanes in VMEM."""
+    return not kernel_fits(dk, dv) and dk % 32 == 0 and dv % 32 == 0
+
+
 def chunk_for(positions: int) -> int:
     """`CHUNK`, or a quarter of a sequence shorter than four of them, so
     that a short sequence (the CPU test size) still carries its state from
@@ -135,7 +158,7 @@ def plan(positions: int, dk: int, dv: int, kernels: bool) -> tuple:
     """(whether the Pallas kernels run, the chunk of the form that runs)
     for a sequence of `positions` and these key and value sizes; `kernels`
     as models/qwen3next.py `make` resolves it from the backend."""
-    if kernels and kernel_fits(dk, dv):
+    if kernels and (kernel_fits(dk, dv) or narrow_fits(dk, dv)):
         return True, KERNEL_CHUNK
     return False, chunk_for(positions)
 
@@ -288,14 +311,27 @@ def _lanes_of(h: int, group: int, dk: int, dv: int):
             slice(h * dv, (h + 1) * dv))
 
 
-def _forward_kernel(q, k, v, g, beta, o, *rest):
+def _each_head(heads: int, total: int, body):
+    """body(h) for the grid step's `heads` value heads; where the last
+    step's block reaches past the `total` heads (a ragged grid: 30 heads
+    of 96 lanes in blocks of 4), only those that exist."""
+    for h in range(heads):
+        if total % heads:
+            pl.when(pl.program_id(0) * heads + h < total)(
+                functools.partial(body, h))
+        else:
+            body(h)
+
+
+def _forward_kernel(q, k, v, g, beta, o, *rest, total: int):
     """A grid step = a chunk of `g.shape[0]` value heads, side by side in
     the lanes as they lie in the mixer's arrays: q, k [C, key heads x dk],
     v, o [C, heads x dv]; g, beta [heads, 1, C].  A key head serves
     heads / key heads value heads in a row.  rest = (the states the chunks
     start from [heads, dk, dv] and their inverted systems [heads, C, C],
     only where a backward pass will want them,) the running state (VMEM
-    scratch, alive across the chunk axis)."""
+    scratch, alive across the chunk axis).  `total`: the value heads of
+    the whole grid."""
     state = rest[-1]
     heads, dk, dv = state.shape
     group = heads * dk // q.shape[1]            # value heads a key head
@@ -303,17 +339,19 @@ def _forward_kernel(q, k, v, g, beta, o, *rest):
     @pl.when(pl.program_id(1) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
-    for h in range(heads):
+
+    def head(h):
         key, value = _lanes_of(h, group, dk, dv)
         S0 = state[h]
         (o[:, value], state[h]), T = _chunk(
             S0, q[:, key], k[:, key], v[:, value], g[h], beta[h])
         if len(rest) == 3:
             rest[0][h], rest[1][h] = S0, T
+    _each_head(heads, total, head)
 
 
 def _backward_kernel(q, k, v, g, beta, S0, T, do,
-                     dq, dk, dv, dg, dbeta, dstate):
+                     dq, dk, dv, dg, dbeta, dstate, *, total: int):
     """The chunks in reverse, laid out as `_forward_kernel`'s: a grid step
     rebuilds its chunk from the saved state, the saved inverse and its
     inputs, and pulls (do, the state's cotangent from the chunk after) back
@@ -326,7 +364,8 @@ def _backward_kernel(q, k, v, g, beta, S0, T, do,
     @pl.when(pl.program_id(1) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
-    for h in range(heads):
+
+    def head(h):
         key, value = _lanes_of(h, group, d_k, d_v)
         _, pull, _ = jax.vjp(
             functools.partial(_chunk, T=T[h]), S0[h], q[:, key], k[:, key],
@@ -338,14 +377,29 @@ def _backward_kernel(q, k, v, g, beta, S0, T, do,
             dk[:, key] += dk_h
         else:
             dq[:, key], dk[:, key] = dq_h, dk_h
+    _each_head(heads, total, head)
 
 
-def _heads_a_step(heads: int, group: int) -> int:
-    """Whole key heads' value heads, `KERNEL_HEADS` at most if that
-    divides the heads, else one key head's."""
-    hb = max(group, KERNEL_HEADS // group * group)
-    while heads % hb:
-        hb -= group
+def _lane_unit(group: int, dk: int, dv: int) -> int:
+    """The fewest value heads, whole key heads' of them, whose key and
+    value lanes are both whole: one key head's for heads in whole lanes,
+    four for keys of 96 and values of 192."""
+    unit = group
+    while (unit // group * dk) % 128 or (unit * dv) % 128:
+        unit += group
+    return unit
+
+
+def _heads_a_step(heads: int, group: int, dk: int, dv: int) -> int:
+    """Value heads a grid step: whole `_lane_unit`s, `KERNEL_HEADS` at most
+    if that divides the heads, else one unit, whose last block is then
+    ragged (`_each_head`); all of them where one unit is more."""
+    unit = _lane_unit(group, dk, dv)
+    if unit >= heads:
+        return heads
+    hb = max(unit, KERNEL_HEADS // unit * unit)
+    while heads % hb and hb > unit:
+        hb -= unit
     return hb
 
 
@@ -372,12 +426,13 @@ def _run_forward(q, k, v, g, beta, dk: int, interpret: bool, keep: bool):
     C])."""
     heads, n, _, c = g.shape
     dv, group = v.shape[1] // heads, heads * dk // q.shape[1]
-    hb = _heads_a_step(heads, group)
+    hb = _heads_a_step(heads, group, dk, dv)
     keys, values, rows, state, system = _specs(
         hb, group, c, dk, dv, lambda i: i)
     shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
     return pl.pallas_call(
-        _forward_kernel, grid=(heads // hb, n),
+        functools.partial(_forward_kernel, total=heads),
+        grid=(-(-heads // hb), n),
         in_specs=[keys, keys, values, rows, rows],
         out_specs=[values] + [state, system] * keep,
         out_shape=[shape(*v.shape)]
@@ -391,12 +446,13 @@ def _run_backward(q, k, v, g, beta, S0, T, do, interpret: bool):
     heads, n, dk, dv = S0.shape
     c = g.shape[-1]
     group = heads * dk // q.shape[1]
-    hb = _heads_a_step(heads, group)
+    hb = _heads_a_step(heads, group, dk, dv)
     keys, values, rows, state, system = _specs(
         hb, group, c, dk, dv, lambda i: n - 1 - i)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32)
     return pl.pallas_call(
-        _backward_kernel, grid=(heads // hb, n),
+        functools.partial(_backward_kernel, total=heads),
+        grid=(-(-heads // hb), n),
         in_specs=[keys, keys, values, rows, rows, state, system, values],
         out_specs=[keys, keys, values, rows, rows],
         out_shape=[like(q), like(k), like(v), like(g), like(beta)],
@@ -474,18 +530,26 @@ _STRIP = 8
 _L2_EPS = 1e-6
 
 
+def key_stride(key_width: int) -> int:
+    """q's width in whole lanes: where k's channels start in a projection
+    laid out [q | zeros | k | zeros | v | z], and v's at twice that, the
+    zeros filling q and k to whole lanes so that every part starts on a
+    lane (`front`'s `stride`; none where the key heads are whole lanes)."""
+    return -(-key_width // _LANES) * _LANES
+
+
 def front_plan(positions: int, dk: int, value_width: int, dtype,
                kernels: bool) -> int:
     """The positions a grid step of the front's kernels, or 0 where the
-    jax.numpy front runs: `kernels` as `plan` takes it, key heads (`dk`)
-    and the value channels in whole lanes, the length in whole tiles, a
-    float32 projection."""
-    fits = (kernels and kernel_fits(dk, value_width) and dtype == jnp.float32
-            and positions % FRONT_TILE == 0)
+    jax.numpy front runs: `kernels` as `plan` takes it, key heads (`dk`) in
+    whole quarters of the lanes (whole lanes, or 96) and the value channels
+    in whole lanes, the length in whole tiles, a float32 projection."""
+    fits = (kernels and dk % 32 == 0 and value_width % _LANES == 0
+            and dtype == jnp.float32 and positions % FRONT_TILE == 0)
     return FRONT_TILE if fits else 0
 
 
-def _front_plain(x, w, key_width: int, dk: int):
+def _front_plain(x, w, key_width: int, dk: int, stride: int):
     """The jax.numpy front (`front`)."""
     positions, channels = x.shape[0], w.shape[1]
     u = jax.nn.silu(causal_conv(x[:, :channels], w))
@@ -493,9 +557,9 @@ def _front_plain(x, w, key_width: int, dk: int):
     l2 = lambda a: a * lax.rsqrt(
         jnp.sum(jnp.square(a), -1, keepdims=True) + _L2_EPS)
     q = l2(heads(u[:, :key_width])) * dk ** -0.5
-    k = l2(heads(u[:, key_width:2 * key_width]))
+    k = l2(heads(u[:, stride:stride + key_width]))
     return (q.reshape(positions, key_width), k.reshape(positions, key_width),
-            u[:, 2 * key_width:].astype(jnp.float32), x[:, channels:])
+            u[:, 2 * stride:].astype(jnp.float32), x[:, channels:])
 
 
 class _Front(NamedTuple):
@@ -503,6 +567,7 @@ class _Front(NamedTuple):
     shapes."""
     key_width: int
     dk: int
+    stride: int
     tile: int
     interpret: bool
 
@@ -510,32 +575,40 @@ class _Front(NamedTuple):
 def _width(total: int, unit: int) -> int:
     """Whole `unit`s, `FRONT_LANES` at most if that divides `total`."""
     width = max(unit, FRONT_LANES // unit * unit)
-    while total % width:
+    while total % width and width > unit:
         width -= unit
     return width
 
 
 def _parts(st: _Front, value_width: int):
-    """(part, its first lane in the projection, the lanes a turn of the
-    loop takes: whole key heads of q, of k, whole lanes of v, how many
-    such groups, the scale after the l2 norm: None for no norm)."""
-    kd, dk = st.key_width, st.dk
+    """(part, its first lane in the projection and in its own array, the
+    lanes a turn of the loop takes: whole key heads in whole lanes of q, of
+    k, whole lanes of v, how many such groups, the scale after the l2 norm:
+    None for no norm).  Where a part is not whole groups (30 key heads of
+    96: 7 groups of 4 and 2 heads), the rest is a last group of its own."""
+    kd, dk, stride = st.key_width, st.dk, st.stride
     for part, base, total, unit, scale in (
-            (0, 0, kd, dk, dk ** -0.5), (1, kd, kd, dk, 1.0),
-            (2, 2 * kd, value_width, _LANES, None)):
-        width = _width(total, unit)
-        yield part, base, width, total // width, scale
+            (0, 0, kd, dk * _LANES // math.gcd(dk, _LANES), dk ** -0.5),
+            (1, stride, kd, dk * _LANES // math.gcd(dk, _LANES), 1.0),
+            (2, 2 * stride, value_width, _LANES, None)):
+        width = _width(total, unit) if total >= unit else total
+        count = total // width
+        yield part, base, 0, width, count, scale
+        if total % width:
+            yield part, base + count * width, count * width, \
+                total % width, 1, scale
 
 
 def _over_groups(st: _Front, value_width: int, body):
     """body(part, lanes in the projection, lanes in the part's own array,
     width, scale) for every group of lanes, a loop a part: the kernel's
     code is a part's, not a group's, long."""
-    for part, base, width, count, scale in _parts(st, value_width):
-        def group(g, carry, part=part, base=base, width=width, scale=scale):
+    for part, base, own, width, count, scale in _parts(st, value_width):
+        def group(g, carry, part=part, base=base, own=own, width=width,
+                  scale=scale):
             at = lambda offset: pl.ds(
                 pl.multiple_of(offset + g * width, _LANES), width)
-            body(part, at(base), at(0), width, scale)
+            body(part, at(base), at(own), width, scale)
             return carry
         lax.fori_loop(0, count, group, 0)
 
@@ -699,6 +772,11 @@ def _front_bwd_kernel(st: _Front, x, halo, w, dq, dk, dv, dx, dw, dnext):
         for j, a in enumerate(acc):
             dw[j:j + 1, lanes] += jnp.sum(a, 0, keepdims=True)
     _over_groups(st, dv.shape[1], group)
+    kd, stride = st.key_width, st.stride
+    if stride > kd:             # the zeros' lanes after q and after k
+        for start in (kd, stride + kd):
+            dx[:, start:start + stride - kd] = jnp.zeros(
+                (dx.shape[0], stride - kd), dx.dtype)
 
 
 def _front_specs(st: _Front, taps: int, channels: int, tile_of):
@@ -729,7 +807,7 @@ def _front_call(kernel, name, st, grid, in_specs, out_specs, out_shape,
 
 def _run_front_fwd(st: _Front, x, w):
     positions, channels = x.shape[0], w.shape[1]
-    kd, vd = st.key_width, w.shape[1] - 2 * st.key_width
+    kd, vd = st.key_width, w.shape[1] - 2 * st.stride
     block, halo, weight, lanes = _front_specs(st, *w.shape, lambda i: i)
     f32 = lambda width: jax.ShapeDtypeStruct((positions, width), jnp.float32)
     return _front_call(
@@ -784,20 +862,67 @@ def _front_kernels_bwd(st: _Front, kept, cotangents):
 _front_kernels.defvjp(_front_kernels_fwd, _front_kernels_bwd)
 
 
-def front(x, w, key_width: int, dk: int, *, kernels: bool = False,
-          interpret: bool = False):
+def front(x, w, key_width: int, dk: int, *, stride: int = None,
+          kernels: bool = False, interpret: bool = False):
     """The mixer's front (the module's docstring): the projection x [P, 2
-    kd + vd + z's width] and the convolution's weight w [4, 2 kd + vd] ->
-    q, k [P, kd] (l2-normed a key head of `dk`, q scaled by dk^-1/2), v
-    [P, vd], float32, heads side by side in the lanes, and z = the rest of
-    x as it is.  `kernels` (the TPU, as models/qwen3next.py `make`
-    resolves it) and shapes `front_plan` takes, at most 9 taps (a strip's
-    rows and its halo): the Pallas kernels; anything else the jax.numpy
-    form.  `interpret` runs the kernels in Pallas' interpreter (the CPU
-    tests)."""
-    tile = front_plan(x.shape[0], dk, w.shape[1] - 2 * key_width, x.dtype,
+    stride + vd + z's width] and the convolution's weight w [4, 2 stride +
+    vd] -> q, k [P, kd] (l2-normed a key head of `dk`, q scaled by
+    dk^-1/2), v [P, vd], float32, heads side by side in the lanes, and z =
+    the rest of x as it is.  q is x's first kd channels, k the kd from
+    `stride` (default kd; `key_stride` where zeros fill q and k to whole
+    lanes), v the rest of w's.  `kernels` (the TPU, as models/qwen3next.py
+    `make` resolves it), shapes `front_plan` takes, parts that start on a
+    lane and at most 9 taps (a strip's rows and its halo): the Pallas
+    kernels; anything else the jax.numpy form.  `interpret` runs the
+    kernels in Pallas' interpreter (the CPU tests)."""
+    stride = key_width if stride is None else stride
+    tile = front_plan(x.shape[0], dk, w.shape[1] - 2 * stride, x.dtype,
                       kernels)
-    if not tile or w.shape[0] > _STRIP + 1:
-        return _front_plain(x, w, key_width, dk)
+    if not tile or w.shape[0] > _STRIP + 1 or stride % _LANES:
+        return _front_plain(x, w, key_width, dk, stride)
     return _front_kernels(x, w.astype(jnp.float32),
-                          _Front(key_width, dk, tile, interpret))
+                          _Front(key_width, dk, stride, tile, interpret))
+
+
+# -- the mixer: projections, front, recurrence, gated norm --------------------
+
+def mixer(h, w_qkvz, w_ba, p, *, key_heads: int, value_heads: int, dk: int,
+          dv: int, eps: float, beta_scale: float = 1.0,
+          kernels: bool = False):
+    """Gated DeltaNet's sequence mixer, as both hybrid decoders run it
+    (models/qwen3next.py, models/olmo_hybrid.py): h [P, H] -> (its output
+    [P, H], beta [P, value heads]).
+
+        [q | k | v | z] = h w_qkvz  (q and k each followed by zeros to
+                                    `key_stride`, or by none: w_qkvz's
+                                    width says which)
+        [b | a] = h w_ba            (one each a value head)
+        q, k, v = `front` of the first three (convolution p["conv"], silu,
+                  the l2 norms, q's scale)
+        beta = beta_scale sigmoid(b)      g = -exp(A_log) softplus(a + dt_bias)
+        o = `delta_rule`, a key head serving value / key heads value heads
+        y = (o rsqrt(mean(o^2) + eps) gdn_norm) silu(z), per head; y w_out
+
+    `beta_scale` 2 lets the transition I - beta k k^T have eigenvalues in
+    (-1, 1) instead of [0, 1) (negative eigenvalues)."""
+    P = h.shape[0]
+    hk, hv = key_heads, value_heads
+    kd, vd = hk * dk, hv * dv
+    f32 = jnp.float32
+    with jax.named_scope("attn_gdn"):
+        qkvz = jnp.dot(h, w_qkvz.astype(h.dtype))
+        ba = jnp.dot(h, w_ba.astype(h.dtype)).astype(f32)
+        q, k, v, z = front(qkvz, p["conv"], kd, dk,
+                           stride=(w_qkvz.shape[1] - 2 * vd) // 2,
+                           kernels=kernels)
+        beta = jax.nn.sigmoid(ba[:, :hv])
+        if beta_scale != 1:
+            beta = beta_scale * beta
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[:, hv:] + p["dt_bias"])
+        o = delta_rule(q.reshape(P, hk, dk), k.reshape(P, hk, dk),
+                       v.reshape(P, hv, dv), g, beta, chunk_for(P),
+                       kernels=kernels)                     # [P, hv, dv]
+        y = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True) + eps)
+        y = y * p["gdn_norm"] * jax.nn.silu(z.reshape(P, hv, dv).astype(f32))
+        return jnp.dot(y.reshape(P, vd).astype(h.dtype),
+                       p["w_out"].astype(h.dtype)), beta

@@ -475,7 +475,8 @@ def test_every_decoder_of_the_table_resolves_at_its_published_share(name):
     want = {"sdar-30b-a3b": (645_623_296, 18992),
             "qwen3-next-80b-a3b": (625_667_136, 18992),
             "xing4.0-29b-a4b": (759_346_446, 16384),
-            "mellum2-12b-a2.5b": (624_072_960, 12288)}
+            "mellum2-12b-a2.5b": (624_072_960, 12288),
+            "olmo-hybrid-7b": (928_862_196, 12544)}
     if name in want:
         assert (n, shape.vocab) == want[name]
     else:
