@@ -26,7 +26,8 @@ Training runs ONCE over the 2L positions ``[xt ; x0]`` of every sequence
 noisy half goes on to the head (`BlockDiffusion`, below, is the objective
 the compiled steps call).  The layers are stacked on a leading axis and
 scanned; inside a layer the sequences go one at a time, each recomputed in
-the backward pass.
+the backward pass but for the attention kernel's output and logsumexp, which
+are kept.
 """
 
 from __future__ import annotations
@@ -140,6 +141,16 @@ def _layer(shape: Shape, kernels: bool, x, p, positions):
     return x + y, rows, fullest, moe.prefix_rows(rows, P * s.top_k, kernels)
 
 
+def _remat_layer(shape: Shape, kernels: bool, p, positions):
+    """`_layer` of one sequence under the layer's checkpoint: the backward
+    pass recomputes all of it but the attention kernel's output and
+    logsumexp, which it keeps (`attention.KEEP_ATTN_RESIDUALS`), so the
+    forward kernel runs once."""
+    return jax.checkpoint(
+        lambda x: _layer(shape, kernels, x, p, positions),
+        policy=attention.KEEP_ATTN_RESIDUALS)
+
+
 def make(shape: Shape = Shape(), kernels=None):
     """(init_fn, apply_fn) for one share.  `kernels`: the Pallas attention
     and grouped-matmul kernels; None = wherever the default backend is a
@@ -152,14 +163,16 @@ def make(shape: Shape = Shape(), kernels=None):
     def init_fn(key):
         return init_params(key, s), {}
 
+    def resolved() -> bool:
+        return jax.default_backend() == "tpu" if kernels is None else kernels
+
     def apply_fn(params, bn_state, x, train=True, compute_dtype=None):
         """x: (xt [S, L], x0 [S, L]) token ids.  Returns (hidden [S, L, H]
         of the noisy half after the final norm, {}, (rows computed by this
         chip's experts summed over layers, rows of the fullest held expert
         of any layer, rows of the dropless buffers touched for them))."""
         del train                       # no dropout, no batch statistics
-        on_tpu = jax.default_backend() == "tpu" if kernels is None \
-            else kernels
+        on_tpu = resolved()
         xt, x0 = x
         ids = jnp.concatenate([xt, x0], axis=1)
         h = params["embed"][ids]
@@ -168,20 +181,20 @@ def make(shape: Shape = Shape(), kernels=None):
         positions = jnp.concatenate([jnp.arange(s.seq_len)] * 2)
 
         def layer(x, p):
-            # One sequence at a time, each recomputed in the backward pass:
-            # what a layer keeps is its input, and what it holds while it
-            # works (q at 32 heads, the dropless buffer at top_k times its
-            # input) is one sequence's, not the step's.
-            one = jax.checkpoint(
-                lambda x_seq: _layer(s, on_tpu, x_seq, p, positions))
-            x, rows, fullest, touched = lax.map(one, x)
+            # One sequence at a time, each recomputed in the backward pass
+            # but for its attention kernel's output and logsumexp: what a
+            # layer keeps is its input and those two, and what it holds
+            # while it works (q at 32 heads, the dropless buffer at top_k
+            # times its input) is one sequence's, not the step's.
+            x, rows, fullest, touched = lax.map(
+                _remat_layer(s, on_tpu, p, positions), x)
             return x, (jnp.sum(rows), jnp.max(fullest), jnp.sum(touched))
         h, (rows, fullest, touched) = lax.scan(layer, h, params["layers"])
         hidden = rmsnorm(h[:, :s.seq_len], params["final_norm"], s.eps)
         return hidden, bn_state, (jnp.sum(rows), jnp.max(fullest),
                                   jnp.sum(touched))
 
-    apply_fn.objective = BlockDiffusion(s)
+    apply_fn.objective = BlockDiffusion(s, resolved)
     return init_fn, apply_fn
 
 
@@ -204,8 +217,8 @@ class BlockDiffusion:
     stream = True           # every epoch its own sequences (data/tokens.py)
     checks_vma = False      # train/step.py `_vary`
 
-    def __init__(self, shape: Shape):
-        self.shape = shape
+    def __init__(self, shape: Shape, kernels=lambda: False):
+        self.shape, self.kernels = shape, kernels
         self.seq_len, self.vocab = shape.seq_len, shape.vocab
         self.example_shape = (shape.seq_len,)
         # constants a sequence, tallied beside the extras: the rows this
@@ -216,10 +229,14 @@ class BlockDiffusion:
 
     @functools.cached_property
     def gauges(self):
-        """What the attention kernels' tiles cost at this shape (the TPU's
-        path; elsewhere the blocked formulation runs in their place): a
-        recorder's, worked out when one asks."""
-        return attention.kernel_tile_gauges(self.seq_len, self.shape.block)
+        """Whether the layers' checkpoint keeps the attention kernel's
+        output (1: the backward pass runs no forward kernel; 0 on the
+        jax.numpy path, which names nothing to keep), and what the kernels'
+        tiles cost at this shape (the TPU's path; elsewhere the blocked
+        formulation runs in their place): a recorder's, worked out when one
+        asks."""
+        return [("attn_residuals_kept", int(self.kernels()), {})] \
+            + attention.kernel_tile_gauges(self.seq_len, self.shape.block)
 
     def prepare(self, key, tokens, augment=None, compute_dtype=None):
         s = self.shape

@@ -521,9 +521,10 @@ def test_the_gauge_says_whether_the_kernels_output_is_kept():
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     import telemetry_report
     _, apply_fn = mellum2.make(mellum2.Shape(), kernels=True)
-    text = "\n".join(telemetry_report._window_lines(
-        [{"kind": "gauge", "name": name, "value": value}
-         for name, value, _ in apply_fn.objective.gauges]))
+    events = [dict(attrs, kind="gauge", name=name, value=value)
+              for name, value, attrs in apply_fn.objective.gauges]
+    text = "\n".join(telemetry_report._window_lines(events)
+                     + telemetry_report._attn_lines(events))
     assert "(the Pallas kernels): a query sees the 1,024 keys" in text
     assert "logsumexp kept across the layers' checkpoint: yes" in text
 

@@ -5,9 +5,11 @@ B = 4, 2 layers, vocabulary 64.  The program against the benchmark's plain
 reference (benchmark/reference/blockdiff.py, which imports nothing of the
 program) on seeded weights: loss, every gradient leaf, three SGD steps
 through `Trainer`, `test_model`; the mask against its four rules; the share
-test; the dropless layer; the attention paths against the dense mask.
+test; the dropless layer; the attention paths against the dense mask; the
+layers' checkpoint, which keeps the attention kernel's output.
 """
 
+import functools
 import json
 import os
 import sys
@@ -28,6 +30,7 @@ from cs744_ddp_tpu.obs import Telemetry                    # noqa: E402
 from cs744_ddp_tpu.ops import attention, moe, sgd          # noqa: E402
 from cs744_ddp_tpu.ops import loss as losslib              # noqa: E402
 from cs744_ddp_tpu.train.loop import Trainer               # noqa: E402
+from test_mellum2 import near, pallas_calls                # noqa: E402
 
 CONFIG = json.load(open(os.path.join(
     ROOT, "benchmark", "tests", "tiny-sdar-f32.json")))
@@ -401,6 +404,98 @@ def test_loss_and_every_gradient_leaf_match_the_reference():
     assert count == float(jnp.sum(masked)) and 0 < fullest <= rows <= touched
 
 
+# -- the attention kernel's output kept across the layer's checkpoint ---------
+
+# The kernels' smallest shape: heads of 128, a key/value head for two query
+# heads, tiles of 128; L = 256, so 512 positions a sequence.
+KERNEL_SHAPE = TINY._replace(hidden=128, heads=2, kv_heads=1, head_dim=128,
+                             expert_width=128, seq_len=256)
+
+
+def redone_layer(shape, kernels, p, positions):
+    """`sdar._remat_layer` under the default policy: the backward pass
+    recomputes the whole layer, the attention kernel's output too."""
+    return jax.checkpoint(
+        lambda x: sdar._layer(shape, kernels, x, p, positions))
+
+
+def model_grad(shape, kernels):
+    """(the gradient of the model's training loss, its parameters, a batch
+    of two sequences)."""
+    init_fn, apply_fn = sdar.make(shape, kernels=kernels)
+    params, _ = init_fn(jax.random.PRNGKey(0))
+    obj = apply_fn.objective
+    toks = jax.random.randint(jax.random.PRNGKey(9), (2, shape.seq_len), 0,
+                              shape.vocab - 1)
+    x = obj.prepare(jax.random.PRNGKey(4), toks)
+    grad = jax.value_and_grad(lambda p: obj.loss(apply_fn, p, {}, x)[0])
+    return grad, params
+
+
+@pytest.mark.parametrize("policy", ["kept", "default"])
+def test_the_models_gradient_runs_the_forward_kernel_once(policy,
+                                                          monkeypatch):
+    """The decoder's train gradient with the kernels on, traced (not run)
+    at head 128 over 512 positions, two sequences: the layer scan's one
+    body calls the forward kernel once, as it calls dq and dkv; under the
+    default policy the recomputation calls it a second time."""
+    if policy == "default":
+        monkeypatch.setattr(sdar, "_remat_layer", redone_layer)
+    grad, params = model_grad(KERNEL_SHAPE, True)
+    names = [n for n in pallas_calls(jax.make_jaxpr(grad)(params).jaxpr)
+             if n and n.startswith("splash_")]
+    forward = 1 if policy == "kept" else 2
+    assert sorted(names) == sorted(
+        ["splash_mqa_fwd_residuals"] * forward
+        + ["splash_mqa_dq_no_residuals", "splash_mqa_dkv_no_residuals"])
+
+
+def test_keeping_the_kernels_output_leaves_loss_and_gradients_as_they_were(
+        monkeypatch):
+    """The model at L = 128 with both kinds of kernel in Pallas' interpreter
+    (tiles of 128): the loss and every gradient leaf under the layer's
+    checkpoint as shipped, which keeps the attention kernel's output and
+    logsumexp, and under the default policy, which recomputes them from the
+    same q, k, v, agree to 1e-6 of a leaf's largest entry."""
+    monkeypatch.setattr(attention, "blockdiff_attention", functools.partial(
+        attention.blockdiff_attention, interpret=True, tile=128))
+    monkeypatch.setattr(moe, "expert_layer", functools.partial(
+        moe.expert_layer, interpret=True))
+    monkeypatch.setattr(moe, "GMM_TILING", (128, 128, 128))
+    grad, params = model_grad(KERNEL_SHAPE._replace(seq_len=128), True)
+    loss, kept = jax.jit(grad)(params)
+    monkeypatch.setattr(sdar, "_remat_layer", redone_layer)
+    rloss, redone = jax.jit(grad)(params)
+    assert abs(float(loss) - float(rloss)) <= 1e-6 * abs(float(rloss))
+    flat = jax.tree_util.tree_flatten_with_path(kept)[0]
+    rflat = dict(jax.tree_util.tree_flatten_with_path(redone)[0])
+    for k, g in flat:
+        assert np.all(np.isfinite(g)), jax.tree_util.keystr(k)
+        assert near(g, rflat[k], 1e-6), jax.tree_util.keystr(k)
+
+
+def test_the_gauge_says_whether_the_kernels_output_is_kept():
+    """`attn_residuals_kept`: 1 where the kernels run (their output and
+    logsumexp carry the name the layer's checkpoint keeps), 0 on the
+    jax.numpy path, which names nothing; the CPU resolves to the latter.
+    The report prints it under the attention tiles."""
+    def gauges(shape, kernels):
+        _, apply_fn = sdar.make(shape, kernels=kernels)
+        return apply_fn.objective.gauges
+    kept = lambda *a: {name: value for name, value, _ in gauges(*a)
+                       }["attn_residuals_kept"]
+    assert kept(sdar.Shape(), True) == 1
+    assert kept(sdar.Shape(), False) == 0
+    assert kept(TINY, None) == 0
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    import telemetry_report
+    events = [dict(attrs, kind="gauge", name=name, value=value)
+              for name, value, attrs in gauges(sdar.Shape(), True)]
+    text = "\n".join(telemetry_report._attn_lines(events))
+    assert "logsumexp kept across the layers' checkpoint: yes" in text
+    assert telemetry_report._window_lines(events) == []
+
+
 def write_tokens(root, train, heldout):
     os.makedirs(os.path.join(root, "tokens"))
     np.save(os.path.join(root, "tokens", "train.npy"), train)
@@ -451,18 +546,22 @@ def test_three_sgd_steps_and_test_model_through_trainer_match_the_reference(
     assert len(steps) == 3 and all("moe_rows_max_expert" in s for s in steps)
     # the attention kernels' tile tally, once a kernel: at L = 32 one tile
     # an edge, so 4 tiles, the clean -> noisy one never visited
-    gauges = {(r["name"], r["kernel"]): r["value"] for r in tel.records
+    gauges = {(r["name"], r.get("kernel")): r["value"] for r in tel.records
               if r["kind"] == "gauge" and r["name"].startswith("attn_")}
-    assert len(gauges) == 9
+    assert len(gauges) == 9 + 1
     assert all(gauges["attn_tiles_visited", k] == 3
                and gauges["attn_tiles_partial", k] == 3
                for k in ("fwd", "dkv", "dq"))
     assert {r["rule"] for r in tel.records if r["kind"] == "gauge"
-            and r["name"].startswith("attn_")} == {"blockdiff"}
+            and r["name"].startswith("attn_")
+            and r["name"] != "attn_residuals_kept"} == {"blockdiff"}
+    # the CPU runs the blocked jax.numpy form, which names nothing to keep
+    assert gauges["attn_residuals_kept", None] == 0
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     import telemetry_report
-    assert "fwd  visits 3 tiles, 3 partly allowed" in "\n".join(
-        telemetry_report._attn_lines(tel.records))
+    text = "\n".join(telemetry_report._attn_lines(tel.records))
+    assert "fwd  visits 3 tiles, 3 partly allowed" in text
+    assert "logsumexp kept across the layers' checkpoint: no" in text
     # the host spans of the default path are this path's too: one window,
     # no ragged tail, the evaluation (tests/test_loop_spans.py's tree), and
     # the first epoch's collective statistics under a second obs_emit

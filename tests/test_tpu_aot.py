@@ -279,6 +279,48 @@ def test_window_and_full_layers_run_each_forward_kernel_once_on_a_v5e(
             assert lm_window.splash_rule(line) == scope, line
 
 
+def test_block_diffusion_layer_runs_its_forward_kernel_once_on_a_v5e(
+        v5e8_mesh):
+    """models/sdar.py's layer at the published widths (4096 tokens, so 8192
+    positions; 32 query / 4 key-value heads of 128, the 16 held experts),
+    mapped over four sequences under the layer's checkpoint as the model
+    maps it: the gradient compiles with ONE `splash_mqa_fwd_residuals`
+    custom-call (the default policy leaves a second in the backward loop),
+    the backward pass taking the output it kept, beside one dq and one
+    dkv.  The benchmark's reader classes each into `attn_blockdiff` by its
+    name."""
+    from jax.sharding import SingleDeviceSharding
+    from benchmark.readers import lm
+    from cs744_ddp_tpu.models import sdar
+    from jax import lax
+    one = SingleDeviceSharding(v5e8_mesh.devices.flat[0])
+    s = sdar.Shape()
+    params = jax.eval_shape(lambda k: sdar.init_params(k, s),
+                            jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape[1:], a.dtype, sharding=one), params["layers"])
+    x = jax.ShapeDtypeStruct((4, 2 * s.seq_len, s.hidden), jnp.float32,
+                             sharding=one)
+    positions = jnp.concatenate([jnp.arange(s.seq_len)] * 2)
+    # the forward loop's output is read (sin), so it stays: the
+    # recomputation lies in the backward loop
+    grad = jax.grad(lambda x, p: jnp.sum(jnp.sin(lax.map(
+        sdar._remat_layer(s, True, p, positions), x)[0])), argnums=(0, 1))
+    text = jax.jit(grad).lower(x, p).compile().as_text()
+    splash = [line for line in text.splitlines()
+              if "custom-call(" in line and "tpu_custom_call" in line
+              and re.search(r"%?splash_mqa_\w+[.\d]* = ", line)]
+    kinds = sorted(re.search(r"%?(splash_mqa_[a-z_]+)", line).group(1)
+                   for line in splash)
+    assert kinds == ["splash_mqa_dkv_no_residuals",
+                     "splash_mqa_dq_no_residuals",
+                     "splash_mqa_fwd_residuals"], kinds
+    scopes = lm.scope_instructions(text, lm.KERNEL_SCOPES)
+    names = [re.search(r"%?(splash_mqa_[\w.]+) = ", line).group(1)
+             for line in splash]
+    assert all(scopes.get(n) == "attn_blockdiff" for n in names), names
+
+
 def test_hyper_connection_kernels_compile_for_a_v5e_under_their_scope(
         v5e8_mesh):
     """ops/hyper.py's four Pallas kernels at the latent decoder's widths

@@ -505,14 +505,18 @@ def _attn_lines(events) -> list:
     `tile_tally` and its siblings), grouped by the rule they compute (a
     model with two rules emits both): the tiles each visits, those of them
     partly allowed, and the scores it computes over the scores the mask
-    allows."""
-    per = {}
+    allows; whether the layers' checkpoint keeps the kernels' output
+    (`attn_residuals_kept`; older runs lack it)."""
+    per, kept = {}, None
     for e in events:
-        if e.get("kind") == "gauge" and e.get("name") in (
-                "attn_tiles_visited", "attn_tiles_partial",
-                "attn_visited_over_allowed"):
+        if e.get("kind") != "gauge":
+            continue
+        if e.get("name") in ("attn_tiles_visited", "attn_tiles_partial",
+                             "attn_visited_over_allowed"):
             per.setdefault((e.get("rule"), e.get("kernel")), {})[
                 e["name"]] = e["value"]
+        elif e.get("name") == "attn_residuals_kept":
+            kept = e["value"]
     if not per:
         return []
     lines = ["== attention tiles (per rule and kernel) =="]
@@ -523,6 +527,11 @@ def _attn_lines(events) -> list:
             f"tiles, {row.get('attn_tiles_partial', 0):,} partly allowed; "
             f"visited / allowed scores "
             f"{row.get('attn_visited_over_allowed', 0.0):.3f}")
+    if kept is not None:
+        lines.append(
+            "  the kernels' output and logsumexp kept across the layers' "
+            "checkpoint: " + ("yes (the backward pass does not run the "
+                              "forward kernel again)" if kept else "no"))
     lines.append("")
     return lines
 
@@ -550,24 +559,16 @@ def _gdn_lines(events) -> list:
 
 def _window_lines(events) -> list:
     """A window-and-full decoder's sliding layers, as built (ops/attention.py
-    `window_attention`): the form that runs, the keys a query sees and
-    whether the layers' checkpoint keeps the kernels' output."""
+    `window_attention`): the form that runs and the keys a query sees."""
     g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
-         and e.get("name") in ("window_kernel", "attn_window",
-                               "attn_residuals_kept")}
+         and e.get("name") in ("window_kernel", "attn_window")}
     if not g:
         return []
     form = "the Pallas kernels" if g.get("window_kernel") else "jax.numpy"
-    lines = ["== window attention ==",
-             f"  sliding-window attention ({form}): a query sees the "
-             f"{g.get('attn_window', 0):,} keys that end at its own position"]
-    if "attn_residuals_kept" in g:
-        lines.append(
-            "  the kernels' output and logsumexp kept across the layers' "
-            "checkpoint: " + ("yes (the backward pass does not run the "
-                              "forward kernel again)"
-                              if g["attn_residuals_kept"] else "no"))
-    return lines + [""]
+    return ["== window attention ==",
+            f"  sliding-window attention ({form}): a query sees the "
+            f"{g.get('attn_window', 0):,} keys that end at its own position",
+            ""]
 
 
 def _mla_lines(events) -> list:
