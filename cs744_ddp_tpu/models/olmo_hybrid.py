@@ -275,8 +275,9 @@ class NextToken(qwen3next.NextToken):
         """Which form of the recurrence runs (1 the Pallas kernels, 0 the
         jax.numpy one) and its chunking; which form of the front, and its
         kernels' tile of positions (0: the jax.numpy front), for a float32
-        projection; what the causal kernels' tiles cost at this length (the
-        TPU's path): a recorder's, once."""
+        projection; the diagonal block the chunks' inversion starts from
+        (both forms'); what the causal kernels' tiles cost at this length
+        (the TPU's path): a recorder's, once."""
         s = self.shape
         fused, c = gdn.plan(self.seq_len, s.lin_key_dim, s.lin_value_dim,
                             self.kernels())
@@ -286,7 +287,8 @@ class NextToken(qwen3next.NextToken):
         return [("gdn_kernel", int(fused), {}), ("gdn_chunk", c, {}),
                 ("gdn_chunks_per_sequence", -(-self.seq_len // c), {}),
                 ("gdn_front_kernel", int(tile > 0), {}),
-                ("gdn_front_tile", tile, {})] \
+                ("gdn_front_tile", tile, {}),
+                ("gdn_inverse_block", gdn.INVERSE_BLOCK, {})] \
             + attention.causal_tile_gauges(self.seq_len)
 
     def loss(self, apply_fn, params, bn_state, x, labels=None,

@@ -282,8 +282,9 @@ class NextToken:
         """Which form of the recurrence runs (1 the Pallas kernels, 0 the
         jax.numpy one) and its chunking; which form of the mixer's front
         (the same, for a float32 projection: what `apply_fn` computes
-        unless told a compute dtype); what the causal kernels' tiles cost
-        at this length (the TPU's path): a recorder's, once."""
+        unless told a compute dtype); the diagonal block the chunks'
+        inversion starts from (both forms'); what the causal kernels' tiles
+        cost at this length (the TPU's path): a recorder's, once."""
         s = self.shape
         fused, c = gdn.plan(self.seq_len, s.lin_key_dim, s.lin_value_dim,
                             self.kernels())
@@ -292,7 +293,8 @@ class NextToken:
                                jnp.float32, self.kernels())
         return [("gdn_kernel", int(fused), {}), ("gdn_chunk", c, {}),
                 ("gdn_chunks_per_sequence", -(-self.seq_len // c), {}),
-                ("gdn_front_kernel", int(front > 0), {})] \
+                ("gdn_front_kernel", int(front > 0), {}),
+                ("gdn_inverse_block", gdn.INVERSE_BLOCK, {})] \
             + attention.causal_tile_gauges(self.seq_len)
 
     def prepare(self, key, tokens, augment=None, compute_dtype=None):

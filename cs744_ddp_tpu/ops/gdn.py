@@ -23,9 +23,9 @@ from, the value written at t is
           - beta_t sum_{s<t} exp(G_t - G_s) (k_t . k_s) u_s
 
 a unit lower-triangular system (I + A) U = beta (V - exp(G) K S_0), solved
-ONCE a chunk for both right-hand sides (T = (I + A)^-1 by the nilpotent
-product (I + X)(I + X^2)(I + X^4)... with X = -A, log2(C) squarings: A is
-strictly lower-triangular, so X^C = 0), and then
+ONCE a chunk for both right-hand sides (T = (I + A)^-1: its diagonal blocks
+of `INVERSE_BLOCK` positions by substitution, then by doubling, each step
+a true inverse of blocks twice the size: `_unit_lower_inverse`), and then
 
     U = T beta V - (T beta exp(G) K) S_0
     O = exp(G) Q S_0 + (Q K^T * D) U           D[t, s] = exp(G_t - G_s), s <= t
@@ -129,6 +129,7 @@ SEGMENT = 4         # chunks recomputed together: 256 positions
 # the chip, `tools/gdn_alone.py`).
 KERNEL_CHUNK = 128  # positions a chunk
 KERNEL_HEADS = 4    # value heads a grid step (`_heads_a_step`)
+INVERSE_BLOCK = 16  # positions a diagonal block of `_unit_lower_inverse`
 _HI = lax.Precision.HIGHEST
 
 
@@ -173,18 +174,100 @@ def causal_conv(x, w):
                    for j in range(taps))
 
 
-def _unit_lower_inverse(a):
-    """(I + a)^-1 for strictly lower-triangular a [..., C, C]."""
+_hi = functools.partial(jnp.matmul, precision=_HI)
+# a lane rotation in a kernel, jnp.roll elsewhere (pltpu.roll has no rule to
+# run outside a jitted function)
+_roll = jax.jit(pltpu.roll, static_argnums=(1, 2))
+
+
+def _diagonal_inverse(a, b: int):
+    """(I + a)^-1 on the diagonal blocks of b positions of a strictly
+    lower-triangular a [..., C, C] (C whole blocks, b a power of 2), zero
+    off them: forward substitution on the VPU, b - 1 steps of
+    multiply-adds.  The blocks are folded into one slab [..., b, C], row i
+    of the slab holding row i of every block, so that a step takes every
+    block at once.  Step j's multipliers, the slab's column j of each block
+    spread over the block's lanes, are selected lane by lane from the
+    slab's rolls, which all steps share: none of them waits for a step."""
     c = a.shape[-1]
-    eye = jnp.eye(c, dtype=a.dtype)
-    x = -a
-    t = eye + x
-    power = 2
-    while power < c:
-        x = jnp.matmul(x, x, precision=_HI)
-        t = t + jnp.matmul(t, x, precision=_HI)
-        power *= 2
-    return t
+    lead = a.shape[:-2]
+    t = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    s = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    same = (t & -b) == (s & -b)
+    slab = jnp.sum(jnp.where(same, a, 0.0).reshape(lead + (c // b, b, c)),
+                   axis=-3)
+    axis = slab.ndim - 1
+    offset = lax.broadcasted_iota(jnp.int32, slab.shape, axis) & (b - 1)
+    rolled = {d: _roll(slab, d % c, axis) if d else slab
+              for d in range(2 - b, b)}     # lane l: the slab's lane l - d
+
+    def column(j):
+        out = rolled[-j]
+        for o in range(1, b):
+            out = jnp.where(offset == o, rolled[o - j], out)
+        return out
+    row = lax.broadcasted_iota(jnp.int32, slab.shape, axis - 1)
+    inv = jnp.where(row == offset, 1.0, 0.0)
+    for j in range(b - 1):                      # row j is final: eliminate
+        inv = inv - column(j) * inv[..., j:j + 1, :]
+    full = jnp.broadcast_to(inv[..., None, :, :], lead + (c // b, b, c))
+    return jnp.where(same, full.reshape(lead + (c, c)), 0.0)
+
+
+def _halves(x, b: int):
+    """x's rows [..., N, W] by blocks of b: (the first block of every pair,
+    the second), [..., N / 2, W] each."""
+    *lead, n, w = x.shape
+    y = x.reshape(tuple(lead) + (n // (2 * b), 2, b, w))
+    return tuple(y[..., h, :, :].reshape(tuple(lead) + (n // 2, w))
+                 for h in (0, 1))
+
+
+def _joined(first, second, b: int):
+    """`_halves`' inverse."""
+    *lead, n, w = first.shape
+    y = jnp.stack([a.reshape(tuple(lead) + (n // b, b, w))
+                   for a in (first, second)], axis=-3)
+    return y.reshape(tuple(lead) + (2 * n, w))
+
+
+@jax.custom_jvp
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for strictly lower-triangular a [..., C, C]: the diagonal
+    blocks of `INVERSE_BLOCK` positions by substitution
+    (`_diagonal_inverse`), then doubling, each step exact.  With T the
+    inverse of the blocks of b, the inverse of a block of 2b, [[L1, 0],
+    [X, L2]], is T less T X T, X the part of a inside the blocks of 2b and
+    outside those of b; only the rows of each pair's second block change,
+    so a step's two products are taken on those rows alone.  Three steps
+    from 16 to a chunk of 128; each is bounded, where the powers of a grow
+    like binomials.  C not a power of 2 times the block is padded with
+    positions that touch nothing."""
+    c = a.shape[-1]
+    b = INVERSE_BLOCK
+    n = b
+    while n < c:
+        n *= 2
+    if n > c:
+        a = jnp.pad(a, ((0, 0),) * (a.ndim - 2) + ((0, n - c), (0, n - c)))
+    inv = _diagonal_inverse(a, b)
+    t = lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    s = lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    while b < n:
+        x = jnp.where(((t & -2 * b) == (s & -2 * b)) & ((t & -b) != (s & -b)),
+                      a, 0.0)
+        kept, changed = _halves(inv, b)
+        inv = _joined(kept, changed - _hi(_hi(changed, x), inv), b)
+        b *= 2
+    return inv[..., :c, :c]
+
+
+@_unit_lower_inverse.defjvp
+def _unit_lower_inverse_jvp(primals, tangents):
+    """dT = -T da T: two products, where autodiff would have to pull the
+    tangent through every step (and the rolls have no rule for it)."""
+    T = _unit_lower_inverse(*primals)
+    return T, -_hi(T, _hi(tangents[0], T))
 
 
 def _segment(S0, q, k, v, g, beta):
@@ -256,7 +339,7 @@ def _mm(a, b, contract=(1, 0)):
 def _solved(a, T):
     """T = (I + a)^-1 as a forward kernel left it, with the transpose of
     the inversion written down: da = -T^T dT T^T, two products in place
-    of the 2 log2(C) of `_unit_lower_inverse` and of their transposes."""
+    of pulling dT back through `_unit_lower_inverse`."""
     return T
 
 

@@ -111,6 +111,75 @@ def test_a_beta_of_two_reflects_the_state_along_the_key():
         assert close(o[0, 0], 2 * v[0, 0]) and close(o[1, 0], 0 * v[0, 0])
 
 
+def shared_direction_inputs(heads, length, dk, dv, beta0, seed=0):
+    """Keys that share a direction (noise of norm 0.3 about a common unit
+    key), betas within 0.01 of `beta0`, a decay of at most 0.01 a
+    position: the powers of a chunk's system grow like binomials here."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    common = unit(jax.random.normal(ks[0], (1, heads, dk)))
+    k = unit(common + 0.3 * unit(jax.random.normal(ks[1],
+                                                   (length, heads, dk))))
+    q = unit(jax.random.normal(ks[2], (length, heads, dk))) / np.sqrt(dk)
+    v = jax.random.normal(ks[3], (length, heads, dv))
+    g = -0.01 * jax.random.uniform(ks[4], (length, heads))
+    beta = beta0 + 0.02 * jax.random.uniform(ks[5], (length, heads)) - 0.01
+    return q, k, v, g, beta
+
+
+def chunk_system(c, keys):
+    """A chunk's strictly lower-triangular system beta D (k k^T), as
+    `_chunk` builds it, for one head of keys of 128: random unit keys and
+    betas over (0, 2), or keys that share a direction with betas near 1 or
+    near 2."""
+    if keys == "random":
+        q, k, v, g, beta = negative_inputs(1, c, dk=128, dv=128)
+    else:
+        q, k, v, g, beta = shared_direction_inputs(
+            1, c, 128, 128, {"shared-1": 1.0, "shared-2": 1.99}[keys])
+    k, G = k[:, 0], jnp.cumsum(g[:, 0])
+    D = jnp.exp(jnp.tril(G[:, None] - G[None, :]))
+    return jnp.tril(beta[:, 0, None] * D * (k @ k.T), -1)
+
+
+@pytest.mark.parametrize("keys", ["random", "shared-1", "shared-2"])
+@pytest.mark.parametrize("c", [64, 128])
+def test_the_chunk_inversion_is_the_float64_triangular_solve(c, keys):
+    """`_unit_lower_inverse` (diagonal blocks by substitution, then
+    doubling), which both forms call, against a float64 triangular solve
+    at the chunks of 64 and 128: finite and within 2e-5 of the solve's
+    largest entry on random keys and on keys that share a direction, with
+    beta near 1 and near 2, where the squarings' powers left float32."""
+    from scipy.linalg import solve_triangular
+    a = chunk_system(c, keys)
+    want = solve_triangular(np.eye(c) + np.asarray(a, np.float64),
+                            np.eye(c), lower=True, unit_diagonal=True)
+    got = np.asarray(jax.jit(gdn._unit_lower_inverse)(a), np.float64)
+    assert got.shape == (c, c) and np.all(np.isfinite(got))
+    assert np.max(np.abs(got - want)) <= 2e-5 * np.max(np.abs(want))
+
+
+@pytest.mark.parametrize("dk,dv,beta0", [(128, 128, 1.0), (96, 192, 1.99)],
+                         ids=["128-beta-near-1", "96-192-beta-near-2"])
+def test_kernels_hold_on_keys_that_share_a_direction(dk, dv, beta0):
+    """Two chunks of 128 of keys that share a direction: the interpreted
+    kernels and the chunked form, forward and the five gradients, against
+    the token loop (the squarings left float32 on these: nan from both
+    forms)."""
+    x = shared_direction_inputs(2, 256, dk, dv, beta0)
+    fused = lambda *a: gdn.delta_rule(*a, kernels=True, interpret=True)
+    chunked = lambda *a: gdn.delta_rule(*a, 64, 4)
+    want = hybrid_causal.recurrence(*x)
+    got = fused(*x)
+    assert np.all(np.isfinite(np.asarray(got)))
+    assert close(got, want) and close(chunked(*x), want)
+    grad = lambda f: jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))),
+                              argnums=(0, 1, 2, 3, 4))(*x)
+    for a, b, c in zip(grad(fused), grad(hybrid_causal.recurrence),
+                       grad(chunked)):
+        assert near(a, b, 1e-4) and near(c, b, 1e-4)
+
+
 def test_the_narrow_rule_and_the_whole_lane_tiling():
     """Keys of 96 and values of 192 take the kernels (four heads fill whole
     lanes: 30 heads in blocks of 4, the last of 2); heads in whole lanes
@@ -355,8 +424,9 @@ def test_sgd_steps_and_test_model_through_trainer_match_the_reference(
 
 def test_the_gauges_say_which_form_runs_and_its_front_tile():
     """At the published shape with the kernels: the delta rule's kernels in
-    chunks of 128 and the front's on tiles of `FRONT_TILE` positions; off
-    the TPU neither."""
+    chunks of 128, each chunk's inversion from diagonal blocks of
+    `INVERSE_BLOCK`, and the front's on tiles of `FRONT_TILE` positions;
+    off the TPU neither kernel."""
     def gauges(kernels):
         _, apply_fn = olmo_hybrid.make(olmo_hybrid.Shape(), kernels=kernels)
         return {name: value for name, value, _ in apply_fn.objective.gauges
@@ -364,9 +434,11 @@ def test_the_gauges_say_which_form_runs_and_its_front_tile():
     assert gauges(True) == {
         "gdn_kernel": 1, "gdn_chunk": gdn.KERNEL_CHUNK,
         "gdn_chunks_per_sequence": 8192 // gdn.KERNEL_CHUNK,
+        "gdn_inverse_block": gdn.INVERSE_BLOCK,
         "gdn_front_kernel": 1, "gdn_front_tile": gdn.FRONT_TILE}
     assert gauges(False)["gdn_kernel"] == gauges(None)["gdn_kernel"] == 0
     assert gauges(False)["gdn_front_tile"] == 0
+    assert gauges(False)["gdn_inverse_block"] == gdn.INVERSE_BLOCK
 
 
 def test_a_share_of_whole_periods_only_and_of_its_own_fields():

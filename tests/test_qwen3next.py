@@ -174,29 +174,32 @@ def test_the_path_is_chosen_from_the_backend_and_the_shapes(monkeypatch):
 def test_the_gauge_says_which_form_runs():
     """`gdn_kernel` 1 and the kernels' chunk where the model is built with
     the kernels and they fit its key and value sizes; 0 and the jax.numpy
-    chunk where either fails (the tiny size: keys of 8); printed by
+    chunk where either fails (the tiny size: keys of 8); the inversion's
+    diagonal block, the same on both forms; printed by
     tools/telemetry_report.py."""
     def gauges(shape, kernels):
         _, apply_fn = qwen3next.make(shape, kernels=kernels)
         return {name: value for name, value, _ in apply_fn.objective.gauges
                 if name.startswith("gdn_")}
     wide = TINY._replace(lin_key_dim=128, lin_value_dim=128, seq_len=256)
+    block = gdn.INVERSE_BLOCK
     assert gauges(wide, True) == {
         "gdn_kernel": 1, "gdn_chunk": gdn.KERNEL_CHUNK,
         "gdn_chunks_per_sequence": 256 // gdn.KERNEL_CHUNK,
-        "gdn_front_kernel": 1}
+        "gdn_inverse_block": block, "gdn_front_kernel": 1}
     assert gauges(wide, False) == gauges(wide, None) == {
         "gdn_kernel": 0, "gdn_chunk": 64, "gdn_chunks_per_sequence": 4,
-        "gdn_front_kernel": 0}
+        "gdn_inverse_block": block, "gdn_front_kernel": 0}
     assert gauges(TINY, True) == {
         "gdn_kernel": 0, "gdn_chunk": 8, "gdn_chunks_per_sequence": 4,
-        "gdn_front_kernel": 0}
+        "gdn_inverse_block": block, "gdn_front_kernel": 0}
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     import telemetry_report
     events = [dict(kind="gauge", name=n, value=v)
               for n, v in gauges(wide, True).items()]
     text = "\n".join(telemetry_report._gdn_lines(events))
     assert f"(the Pallas kernels) in chunks of {gdn.KERNEL_CHUNK} " in text
+    assert f"from diagonal blocks of {block} positions" in text
     assert "the front (convolution, silu, q / k norms): the Pallas kernels" \
         in text
 

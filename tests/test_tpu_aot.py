@@ -173,18 +173,23 @@ def test_large_zoo_models_compile_for_v5e8(v5e8_mesh):
     assert " all-reduce(" in txt
 
 
-def test_delta_rule_kernels_compile_for_a_v5e_under_their_scope(v5e8_mesh):
-    """ops/gdn.py's two Pallas kernels at the hybrid decoder's widths (8192
-    positions x 32 heads on 16 key heads x 128): Mosaic takes the forward kernel and, in the
-    gradient, the state-keeping forward and the backward one; each
+@pytest.mark.parametrize("heads,key_heads,dk,dv", [
+    (32, 16, 128, 128), (30, 30, 96, 192)], ids=["qwen3next", "olmohybrid"])
+def test_delta_rule_kernels_compile_for_a_v5e_under_their_scope(
+        v5e8_mesh, heads, key_heads, dk, dv):
+    """ops/gdn.py's two Pallas kernels at both hybrid decoders' widths (8192
+    positions x 32 heads on 16 key heads x 128; 30 heads of keys of 96 and
+    values of 192, the last block of heads ragged): Mosaic takes the
+    forward kernel, the inversion's substitution on the VPU among it, and,
+    in the gradient, the state-keeping forward and the backward one; each
     custom-call is named `gdn_chunks_*` and keeps `gdn_recurrence` in its
     op_name, which is how the benchmark's reader finds its time."""
     from jax.sharding import SingleDeviceSharding
     from cs744_ddp_tpu.ops import gdn
     one = SingleDeviceSharding(v5e8_mesh.devices.flat[0])
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
-    x = (sds(8192, 16, 128),) * 2 + (sds(8192, 32, 128),) \
-        + (sds(8192, 32),) * 2
+    x = (sds(8192, key_heads, dk),) * 2 + (sds(8192, heads, dv),) \
+        + (sds(8192, heads),) * 2
     rule = lambda *a: gdn.delta_rule(*a, kernels=True)
     grads = jax.grad(lambda *a: jnp.sum(rule(*a)), argnums=(0, 1, 2, 3, 4))
     for f, kernels in ((rule, ["gdn_chunks_fwd"]),

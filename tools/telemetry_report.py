@@ -539,11 +539,14 @@ def _attn_lines(events) -> list:
 def _gdn_lines(events) -> list:
     """A hybrid decoder's linear-attention layers, as built (ops/gdn.py):
     which form of the gated delta rule runs, the chunk it is computed in
-    and the chunks the state is carried through a sequence; which form of
-    the front before it (`gdn_front_kernel`, PR 38; absent before)."""
+    and the chunks the state is carried through a sequence; the diagonal
+    block a chunk's inversion starts from (`gdn_inverse_block`) and which
+    form of the front runs before it (`gdn_front_kernel`), each where the
+    run recorded it."""
     g = {e["name"]: e["value"] for e in events if e.get("kind") == "gauge"
          and e.get("name") in ("gdn_kernel", "gdn_chunk",
-                               "gdn_chunks_per_sequence", "gdn_front_kernel")}
+                               "gdn_chunks_per_sequence", "gdn_inverse_block",
+                               "gdn_front_kernel")}
     if not g:
         return []
     form = lambda on: "the Pallas kernels" if on else "jax.numpy"
@@ -551,6 +554,10 @@ def _gdn_lines(events) -> list:
              f"  the gated delta rule ({form(g.get('gdn_kernel'))}) in "
              f"chunks of {g.get('gdn_chunk', 0):,} positions, "
              f"{g.get('gdn_chunks_per_sequence', 0):,} a sequence"]
+    if "gdn_inverse_block" in g:
+        lines.append("  a chunk's triangular system inverted from diagonal "
+                     f"blocks of {g['gdn_inverse_block']:,} positions "
+                     "(substitution), then by doubling")
     if "gdn_front_kernel" in g:
         lines.append("  the front (convolution, silu, q / k norms): "
                      + form(g["gdn_front_kernel"]))
